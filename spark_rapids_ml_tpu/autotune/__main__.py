@@ -78,8 +78,11 @@ def main(argv: List[str]) -> int:
     )
 
     from ..observability import fit_run
+    from ..utils import enable_compile_cache
 
     from .search import run_search
+
+    enable_compile_cache()
 
     with fit_run(algo="autotune_search", site="autotune"):
         summary = run_search(

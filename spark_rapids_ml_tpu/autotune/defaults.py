@@ -54,6 +54,19 @@ MIN_ITEM_TILE = 128
 FUSED_ASSIGN_MIN_K = 128
 LLOYD_FUSED_MIN_K = 128
 
+# k <= this is where `auto` may hand a top-k scan to the fused running-pool
+# kernel. NOT a tunable: it is the largest k Mosaic compiled on a v5e (PR 21,
+# jax 0.9.0 / libtpu 0.0.34; k=10 and k=32 agree with XLA and numpy). The
+# kernel unrolls its k-step extraction and every step leaves three lane-padded
+# (q_block, 1) columns on the VMEM stack, which `topk_fits_vmem` does not
+# model: at the default (256, 1024) geometry k=64 is refused with
+# "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem ... Scoped
+# allocation with size 24.84M and limit 16.00M exceeded scoped vmem limit by
+# 8.84M" (k=128: 48.57M, k=256: 96.09M after a 335 s compile). Above the bound
+# `auto` keeps the XLA strategies; an explicit `pallas_fused` still reaches
+# the kernel and fails loudly. ROADMAP D9 owns the real fix.
+FUSED_TOPK_MAX_K = 32
+
 # ----------------------------------------------------- other pallas kernels
 # segment-reduce histogram (ops/pallas_histogram.py)
 PALLAS_HISTOGRAM_BLOCK_ROWS = 512

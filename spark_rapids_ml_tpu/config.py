@@ -88,7 +88,7 @@ _DEFAULTS: Dict[str, Any] = {
     "cache.hbm_budget_bytes": 2 << 30,
     # reliability subsystem (reliability/): retry/backoff policy, deterministic
     # fault injection, streamed-fit checkpoint-resume, and the
-    # barrier->collect->CPU degradation ladder (docs/design.md "Reliability")
+    # barrier->collect degradation ladder (docs/design.md "Reliability")
     "reliability.enabled": True,
     "reliability.max_attempts": 3,          # total attempts per retried unit
     "reliability.backoff_base_s": 0.05,     # exponential backoff base

@@ -453,8 +453,8 @@ class _TpuEstimator(_TpuCaller):
         self._validate_param_bounds()
         from ..observability import fit_run
 
-        # one FitRun spans the whole degradation ladder (barrier -> collect ->
-        # CPU): every span/counter/event fired anywhere below — including
+        # one FitRun spans the whole degradation ladder (barrier -> collect):
+        # every span/counter/event fired anywhere below — including
         # barrier-worker snapshots merged by fit_on_spark — lands in one
         # structured report, attached to the trained model as
         # `model.fit_report_` (docs/design.md §6d)
@@ -519,44 +519,7 @@ class _TpuEstimator(_TpuCaller):
                     type(e).__name__,
                     e,
                 )
-        return self._fit_device_or_cpu(dataset)
-
-    def _fit_device_or_cpu(self, dataset: Any) -> "_TpuModel":
-        """Last rungs of the degradation ladder: run the local (or collect-mode)
-        device fit; an UNRECOVERABLE device error — never retried, see
-        reliability.faults.is_device_error — routes into the existing
-        fallback.enabled CPU path instead of raising."""
-        from .. import config as _config
-        from .. import profiling
-        from ..reliability import is_device_error
-
-        try:
-            return self._fit_internal(dataset, None)[0]
-        except Exception as e:
-            if not (
-                is_device_error(e)
-                and bool(_config.get("reliability.enabled"))
-                and self._fallback_enabled
-                and self._fallback_class() is not None
-            ):
-                raise
-            profiling.count("reliability.degrade.device_to_cpu")
-            from ..observability import current_run, event as _obs_event
-            from ..observability.flight import dump_postmortem
-
-            _obs_event("degrade", rung="device_to_cpu", error=type(e).__name__)
-            # same forensics contract as the barrier→collect rung (§6g)
-            dump_postmortem(current_run(), reason="degrade:device_to_cpu")
-            self.logger.warning(
-                "unrecoverable device error (%s: %s); degrading to the CPU "
-                "fallback path (config fallback.enabled)",
-                type(e).__name__,
-                e,
-            )
-            try:
-                return self._fallback_fit(dataset)
-            except NotImplementedError:
-                raise e from None
+        return self._fit_internal(dataset, None)[0]
 
     def _spark_fit_wanted(self, dataset: Any) -> bool:
         """Whether a Spark-DataFrame fit should fan out as barrier tasks

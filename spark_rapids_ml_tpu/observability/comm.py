@@ -83,8 +83,12 @@ _DTYPE_BYTES: Dict[str, int] = {
 # start's result and must NOT match (it would double-count the payload).
 # Operand USES of a collective's result (`fusion(... %all-reduce.8 ...)`)
 # never match: the opcode must sit between the result shape and its `(`.
+# A tuple shape may nest parentheses — TPU tiled layouts spell them
+# (`f32[20,128]{1,0:T(8,128)}`), and XLA's all-reduce combiner on TPU merges a
+# fit's reductions into ONE tuple-shaped op — so the tuple alternative runs
+# lazily to the `)` that is followed by the opcode, not to the first `)`.
 _OP_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(?P<shape>\([^)]*\)|\S+)\s+"
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(?P<shape>\(.*?\)|\S+)\s+"
     r"(?P<op>" + "|".join(re.escape(k) for k in _HLO_KINDS) + r")"
     r"(?P<start>-start)?\(",
     re.MULTILINE,

@@ -18,8 +18,10 @@
 #     executable. Calls then run the cached executable directly and ATTRIBUTE
 #     the analyzed flops/bytes to the innermost open trace span, so FitRun /
 #     TransformRun span nodes carry real device work, not just wall time.
-#     Degrades to the plain jitted call under tracing (vmap/grad/nested jit),
-#     on any AOT API failure, or when `observability.device_enabled` is off.
+#     Inlines through the plain jitted call under tracing (vmap/grad/nested
+#     jit) or when `observability.device_enabled` is off. An AOT compile or
+#     executable-call failure RAISES: there is no silent jit fallback, so the
+#     compile accounting always describes what actually runs.
 #
 #   * HBM telemetry — `local_devices()[*].memory_stats()` sampled at span
 #     boundaries (rate-limited) into the `device.hbm_bytes_in_use` gauge plus a
@@ -139,7 +141,6 @@ _PEAK_TABLE: Tuple[Tuple[str, Tuple[float, float, float]], ...] = (
     ("v6", (459e12, 1640e9, 448e9)),
     ("v4", (137e12, 1228e9, 300e9)),
     ("v3", (61e12, 900e9, 100e9)),
-    ("tpu", (98e12, 819e9, 200e9)),
     ("gpu", (19.5e12, 1555e9, 600e9)),
     ("cpu", (2e11, 5e10, 1e10)),
 )
@@ -181,28 +182,32 @@ def reset_device_plane() -> None:
 
 def _platform_row() -> Tuple[float, float, float, str]:
     """(peak_flops, peak_bw, peak_ici_bw, platform) of the local device kind —
-    the raw table row (cached), before any config override."""
+    the raw table row (cached), before any config override. A device no row
+    matches is an error, not a default: a roofline share against another
+    chip's peaks is a wrong number, so asking for peaks on an unknown
+    `device_kind` raises."""
     global _peaks_cache
     with _lock:
         cached = _peaks_cache
     if cached is None:
-        platform, kind = "unknown", ""
-        if "jax" in sys.modules:
-            try:
-                import jax
+        import jax
 
-                dev = jax.local_devices()[0]
-                platform = str(dev.platform)
-                kind = str(getattr(dev, "device_kind", "") or "")
-            except Exception as e:
-                _log_once("peaks", "device probe for peak table failed: %s", e)
-        flops, bw, ici = 2e11, 5e10, 1e10  # unknown-platform fallback = cpu row
-        hay = f"{kind} {platform}".lower()
+        dev = jax.local_devices()[0]
+        platform = str(dev.platform)
+        kind = str(getattr(dev, "device_kind", "") or "")
+        # a TPU is matched by its device_kind only ("tpu" is in every kind);
+        # cpu/gpu kinds are host/vendor strings, so those match by platform
+        hay = (kind if platform == "tpu" else platform).lower()
         for key, (f, b, i) in _PEAK_TABLE:
             if key in hay:
-                flops, bw, ici = f, b, i
+                cached = (f, b, i, platform)
                 break
-        cached = (flops, bw, ici, platform)
+        else:
+            raise ValueError(
+                f"no peak-table row for device_kind {kind!r} on platform "
+                f"{platform!r}; add one to observability/device.py::_PEAK_TABLE"
+                " (with its source) — peaks are never defaulted"
+            )
         with _lock:
             _peaks_cache = cached
     return cached
@@ -211,7 +216,8 @@ def _platform_row() -> Tuple[float, float, float, str]:
 def platform_peaks() -> Tuple[float, float, str]:
     """(peak_flops_per_chip, peak_bw_per_chip, platform). Config overrides win;
     otherwise the first _PEAK_TABLE row whose key substring-matches the local
-    device kind (then platform)."""
+    TPU's device kind (cpu/gpu match by platform). Raises ValueError for a
+    device no row matches."""
     over_f = float(_config.get("observability.peak_flops") or 0.0)
     over_b = float(_config.get("observability.peak_bw") or 0.0)
     flops, bw, _, platform = _platform_row()
@@ -495,60 +501,37 @@ class CompiledKernel:
     def __call__(self, *args: Any, **kwargs: Any):
         if not _enabled():
             return self._jit(*args, **kwargs)
-        try:
-            canon = self._canonicalize(args, kwargs)
-            if canon is not None:
-                call_args, statics = canon
-                call_kwargs: Dict[str, Any] = {}
-                dyn_args = tuple(
-                    a for i, a in enumerate(call_args)
-                    if i not in self._static_idx
-                )
-                dyn_kwargs: Dict[str, Any] = {}
-            else:
-                call_args, call_kwargs = args, kwargs
-                dyn_args, dyn_kwargs, statics = self._split(args, kwargs)
-            sig = self._signature(dyn_args, dyn_kwargs, statics)
-        except Exception as e:
-            _log_once(f"sig:{self.name}",
-                      "kernel %s: signature capture failed (%s); "
-                      "running uninstrumented", self.name, e)
-            sig = None
-        if sig is None:
+        canon = self._canonicalize(args, kwargs)
+        if canon is not None:
+            call_args, statics = canon
+            call_kwargs: Dict[str, Any] = {}
+            dyn_args = tuple(
+                a for i, a in enumerate(call_args)
+                if i not in self._static_idx
+            )
+            dyn_kwargs: Dict[str, Any] = {}
+        else:
+            call_args, call_kwargs = args, kwargs
+            dyn_args, dyn_kwargs, statics = self._split(args, kwargs)
+        sig = self._signature(dyn_args, dyn_kwargs, statics)
+        if sig is None:  # tracer inputs: inline through the enclosing trace
             return self._jit(*args, **kwargs)
         entry = self._cache.get(sig)
         if entry is None:
             with self._klock:
                 entry = self._cache.get(sig)
                 if entry is None:
-                    try:
-                        entry = self._compile_and_capture(
-                            sig, call_args, call_kwargs
-                        )
-                    except Exception as e:
-                        _log_once(f"aot:{self.name}",
-                                  "kernel %s: AOT compile/capture failed (%s); "
-                                  "falling back to plain jit", self.name, e)
-                        entry = {"exe": None, "record": None}
+                    # a compile refusal (Mosaic, VMEM, HBM) raises to the
+                    # caller: `device.compile{kernel=}` must describe what runs
+                    entry = self._compile_and_capture(
+                        sig, call_args, call_kwargs
+                    )
                     self._cache[sig] = entry
-        exe, record = entry["exe"], entry["record"]
-        if exe is None:
-            out = self._jit(*args, **kwargs)
-        else:
-            try:
-                out = exe(*dyn_args, **dyn_kwargs)
-            except Exception as e:
-                # pytree/static drift between lower() and the call contract of
-                # this jax version: disable the AOT path for this signature
-                _log_once(f"call:{self.name}",
-                          "kernel %s: AOT executable call failed (%s); "
-                          "using plain jit for this signature", self.name, e)
-                entry["exe"] = None
-                out = self._jit(*args, **kwargs)
-        if record is not None:
-            with _lock:
-                record["calls"] += 1
-            _attribute_call(self.name, record)
+        record = entry["record"]
+        out = entry["exe"](*dyn_args, **dyn_kwargs)
+        with _lock:
+            record["calls"] += 1
+        _attribute_call(self.name, record)
         return out
 
 

@@ -18,8 +18,8 @@
 #       open_spans: [...], config: {...}, device: {...} }
 #
 # PR 1's deterministic fault sites make the dump path testable end to end: an
-# injected DeviceError at `ingest` drives the device→CPU rung and the resulting
-# bundle must contain both the `fault` and `degrade` ring entries (ci/test.sh
+# injected DeviceError at `ingest` raises out of the streamed fit and the
+# failure's bundle must contain the `fault` ring entry (ci/test.sh
 # live-telemetry smoke). Writes are tmp-file + os.replace, so a concurrent
 # reader only ever sees a whole bundle.
 #

@@ -21,7 +21,12 @@
 # device array) a staging buffer must not be reused either — a later batch
 # would overwrite the HBM-cache-resident tensor of an earlier one. The pool
 # therefore only reuses buffers where device_put copies (TPU/GPU); on CPU it
-# allocates per block, which is exactly what the pre-§6k path did.
+# allocates per block, which is exactly what the pre-§6k path did. Where it
+# does copy, the copy is ASYNCHRONOUS: device_put returns before the runtime
+# has read the host buffer, so a buffer may be refilled only after the
+# transfer that reads it has finished. The upload site owns that fence
+# (ops/streaming.py::_batch_stream blocks on each batch's transfer before the
+# slicer runs again), which is why ONE buffer per key is enough.
 #
 # Telemetry (docs/metrics.md): `ingest.bytes_zero_copy` / `ingest.bytes_copied`
 # / `ingest.copies_avoided` / `ingest.host_convert_s` / `ingest.rows_staged`,
@@ -56,12 +61,9 @@ def _device_put_copies() -> bool:
     than aliasing it (CPU). Gates staging-buffer reuse — see module header."""
     global _device_put_copies_cache
     if _device_put_copies_cache is None:
-        try:
-            import jax
+        import jax
 
-            _device_put_copies_cache = jax.default_backend() != "cpu"
-        except Exception:  # conservative: unknown backend -> no reuse
-            _device_put_copies_cache = False
+        _device_put_copies_cache = jax.default_backend() != "cpu"
     return _device_put_copies_cache
 
 
@@ -84,21 +86,18 @@ def resolve_staging_pool_rows(n: Optional[int] = None,
 
 class StagingPool:
     """Reusable host staging buffers for the counted copy fallback: per
-    (slot, dtype, trailing-shape) key, a ring of TWO buffers sized
-    `resolve_staging_pool_rows()` rows (growing to the largest block seen),
-    alternated per call — the double-buffer discipline of
-    ops/ann_streaming._pipelined_run, so with prefetch depth 1 the buffer a
-    block is DMA-ing from is never the one the next block stages into. Reuse
-    is disabled entirely where device_put aliases host memory (CPU) — there
-    every `buffer()` call allocates fresh, preserving the pre-pool semantics
-    HBM batch caching depends on."""
-
-    _RING = 2
+    (slot, dtype, trailing-shape) key, ONE buffer sized
+    `resolve_staging_pool_rows()` rows (growing to the largest block seen).
+    Contract with the caller: a block handed out here is device_put and its
+    transfer WAITED FOR before `buffer()` is asked for the same key again
+    (`_batch_stream` does; device_put alone returns before the host buffer is
+    read). Reuse is disabled entirely where device_put aliases host memory
+    (CPU) — there every `buffer()` call allocates fresh, preserving the
+    pre-pool semantics HBM batch caching depends on."""
 
     def __init__(self, pool_rows: Optional[int] = None) -> None:
         self._pool_rows = pool_rows
-        self._bufs: Dict[Tuple, list] = {}
-        self._turn: Dict[Tuple, int] = {}
+        self._bufs: Dict[Tuple, np.ndarray] = {}
 
     def buffer(self, shape: Tuple[int, ...], dtype: Any,
                slot: Any = None) -> np.ndarray:
@@ -109,13 +108,10 @@ class StagingPool:
         if self._pool_rows is None:
             self._pool_rows = resolve_staging_pool_rows()
         key = (slot, np.dtype(dtype), tail)
-        ring = self._bufs.setdefault(key, [None] * self._RING)
-        turn = self._turn.get(key, 0)
-        self._turn[key] = (turn + 1) % self._RING
-        buf = ring[turn]
+        buf = self._bufs.get(key)
         if buf is None or buf.shape[0] < rows:
             buf = np.empty((max(rows, self._pool_rows),) + tail, dtype)
-            ring[turn] = buf
+            self._bufs[key] = buf
         return buf[:rows]
 
 

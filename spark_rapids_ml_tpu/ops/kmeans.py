@@ -190,7 +190,7 @@ def _oversample_rounds(
     X: jax.Array, w: jax.Array, first: jax.Array, key: jax.Array, l: int, steps: int
 ) -> jax.Array:
     """All k-means|| oversampling rounds in ONE dispatch: the former host loop
-    synced candidates to host every round (2 relay round trips per step) and
+    synced candidates to host every round (2 host round trips per step) and
     recomputed distances against the WHOLE candidate set each time; here the
     min-distance vector updates incrementally against only the new candidates
     (O(steps·l·n·d) instead of O(steps²·l·n·d)). Returns (1 + steps·l, d)

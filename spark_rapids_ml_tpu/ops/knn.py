@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
-from ..utils.jax_compat import pvary, shard_map
+from jax import shard_map
 
 from ._precision import FAST
 from ..parallel.mesh import DATA_AXIS
@@ -1042,11 +1042,14 @@ def exact_knn_ring(
             x_local,
             valid_local,
             x2_local,
-            pvary(
+            jax.lax.pcast(
                 jnp.full((nq_local, k_eff), INVALID_D2, q_local.dtype),
-                (DATA_AXIS,),
+                (DATA_AXIS,), to="varying",
             ),
-            pvary(jnp.full((nq_local, k_eff), -1, jnp.int32), (DATA_AXIS,)),
+            jax.lax.pcast(
+                jnp.full((nq_local, k_eff), -1, jnp.int32),
+                (DATA_AXIS,), to="varying",
+            ),
         )
         _, _, _, best_d2, best_idx = jax.lax.fori_loop(0, n_dev, hop, init)
         return best_d2, best_idx

@@ -91,11 +91,7 @@ def _run_lbfgs(loss, params0, max_iter: int, tol: float):
         new_params = optax.apply_updates(params, updates)
         new_value = optax.tree_utils.tree_get(opt_state, "value")
         delta = jnp.abs(value - new_value) / jnp.maximum(jnp.abs(new_value), 1.0)
-        # tree_norm arrived in optax 0.2.4; tree_l2_norm is the older spelling
-        _tree_norm = getattr(
-            optax.tree_utils, "tree_norm", optax.tree_utils.tree_l2_norm
-        )
-        gnorm = _tree_norm(grad)
+        gnorm = optax.tree_utils.tree_norm(grad)
         return new_params, opt_state, it + 1, delta, gnorm
 
     state0 = (

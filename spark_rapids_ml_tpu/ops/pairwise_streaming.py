@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.jax_compat import shard_map
+from jax import shard_map
 
 from ..observability import (
     convergence as obs_convergence,

@@ -110,7 +110,7 @@ def segment_histogram_pallas(
 def _shard_psum(mesh, in_specs, local_fn):
     """shard_map wrapper shared by both histogram entry points: run local_fn on
     each device's row shard, psum the partial histograms over the mesh."""
-    from ..utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DATA_AXIS

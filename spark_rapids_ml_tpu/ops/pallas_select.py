@@ -102,32 +102,20 @@ _VMEM_BUDGET_BYTES = 8 << 20
 
 def _interpret_default() -> bool:
     """Off-TPU the kernels run the Pallas interpreter: bit-exact, slow — the
-    correctness tier that makes CPU tier-1 parity tests real."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover - backend probe must never fail
-        return True
+    correctness tier that makes CPU tier-1 parity tests real. The platform is
+    the ONLY thing that selects it: a failing backend probe raises."""
+    return jax.default_backend() != "tpu"
 
 
-def _cost_estimate(flops: float, bytes_accessed: float):
-    """Seed XLA's cost model for the pallas custom call (pl.CostEstimate,
-    when this jax ships it): without it the device plane's cost_analysis
-    sees ~zero flops and the bench's measured-MFU keys read hollow."""
-    ce = getattr(pl, "CostEstimate", None)
-    if ce is None:  # pragma: no cover - older pallas: no estimate, still runs
-        return None
-    return ce(
+def _cost_estimate(flops: float, bytes_accessed: float) -> pl.CostEstimate:
+    """Seed XLA's cost model for the pallas custom call: without an estimate
+    the device plane's cost_analysis sees ~zero flops and the bench's
+    measured-MFU keys read hollow."""
+    return pl.CostEstimate(
         flops=int(max(flops, 0)),
         bytes_accessed=int(max(bytes_accessed, 0)),
         transcendentals=0,
     )
-
-
-def _maybe_cost(kwargs: dict, flops: float, bytes_accessed: float) -> dict:
-    est = _cost_estimate(flops, bytes_accessed)
-    if est is not None:
-        kwargs["cost_estimate"] = est
-    return kwargs
 
 
 def topk_fits_vmem(q_block: int, item_tile: int, d: int, k: int) -> bool:
@@ -359,8 +347,7 @@ def _fused_topk_scan(
             jax.ShapeDtypeStruct((n_qb * q_block, k), jnp.int32),
         ],
         interpret=interpret,
-        **_maybe_cost(
-            {},
+        cost_estimate=_cost_estimate(
             flops=2.0 * nq * n * d + 2.0 * nq * n * k,
             bytes_accessed=4.0 * (nq * d + n * d + n + 2 * nq * k),
         ),
@@ -526,8 +513,7 @@ def _fused_assign(
         out_specs=pl.BlockSpec((block, 1), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((n_b * block, 1), jnp.int32),
         interpret=interpret,
-        **_maybe_cost(
-            {},
+        cost_estimate=_cost_estimate(
             flops=2.0 * n * k * d * (max(1, n_split) * (max(1, n_split) + 1) // 2),
             bytes_accessed=4.0 * (n * d + k * d + n),
         ),
@@ -664,8 +650,7 @@ def _fused_count(
         out_specs=pl.BlockSpec((q_block, 1), lambda i, t: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_qb * q_block, 1), jnp.int32),
         interpret=interpret,
-        **_maybe_cost(
-            {},
+        cost_estimate=_cost_estimate(
             flops=2.0 * nq * n * d,
             bytes_accessed=4.0 * (nq * d + n * d + n + nq),
         ),

@@ -81,10 +81,7 @@ def mask_invalid(d2: jax.Array, valid: jax.Array) -> jax.Array:
 
 
 def _backend() -> str:
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - backend probe must never fail a fit
-        return "cpu"
+    return jax.default_backend()
 
 
 def _auto_tile(n: int, backend: str) -> int:
@@ -160,7 +157,8 @@ def resolve(
     `fusable=True` marks call sites that hold Q and X (not a materialized d2
     matrix) and can therefore run the fused pallas distance+select scan
     (ops/pallas_select.py): under `auto` on TPU such a site picks
-    `pallas_fused` once n >= knn.pallas_min_items. A NON-fusable site asked
+    `pallas_fused` once n >= knn.pallas_min_items, for k <= FUSED_TOPK_MAX_K
+    (the largest k Mosaic places — autotune/defaults.py). A NON-fusable site asked
     for `pallas_fused` (explicitly or via a threaded resolved value) degrades
     to exact_full — there is nothing left to fuse once d2 exists, and
     exact_full preserves the fused scan's bit-exact contract."""
@@ -173,7 +171,11 @@ def resolve(
             f"knn.selection must be one of {STRATEGIES}, got '{strategy}'"
         )
     if strategy == "auto":
-        if fusable and _fused_auto(n):
+        from ..autotune.defaults import FUSED_TOPK_MAX_K
+
+        # the k bound comes BEFORE the width probe (a table consult): past it
+        # Mosaic refuses the kernel's unrolled extraction (defaults module)
+        if fusable and k <= FUSED_TOPK_MAX_K and _fused_auto(n):
             strategy = "pallas_fused"
         else:
             # tuning table first (docs/design.md §6i): a measured per-bucket

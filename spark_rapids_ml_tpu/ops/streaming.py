@@ -178,8 +178,17 @@ def _batch_stream(n: int, batch_rows: int, mesh, slicer, start_row: int = 0,
                 out = [part.shard(Xp)]
                 out += [part.shard(a) for a in mid]
                 out.append(part.shard(pad_w * wv))
-                return tuple(out)
-            return tuple(jnp.asarray(a) for a in arrays)
+                out = tuple(out)
+            else:
+                out = tuple(jnp.asarray(a) for a in arrays)
+            # transfer fence: device_put returns BEFORE the runtime has read the
+            # host buffer (measured on a v5e: a 64 MiB buffer rewritten right
+            # after device_put arrived fully corrupted), and the slicer's
+            # staging buffers are reused by the next batch (ops/ingest.py).
+            # Waiting here costs no overlap that matters: the accumulate that
+            # consumes this batch needs the transfer anyway, and batch i's
+            # compute (already dispatched) still runs under it.
+            return jax.block_until_ready(out)
 
         yield cached_build(cache, cache_key, batch_index, site, build)
 

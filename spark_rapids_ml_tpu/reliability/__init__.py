@@ -2,8 +2,8 @@
 # Reliability subsystem: retry/backoff policy, deterministic fault injection,
 # and checkpoint-resume for the streamed out-of-core fits — plus the exception
 # taxonomy (transient vs stage-retryable vs unrecoverable device error) that
-# drives the barrier->collect->CPU degradation ladder in core/estimator.py and
-# spark/integration.py.
+# drives the barrier->collect degradation ladder in core/estimator.py and
+# spark/integration.py (a device error is never degraded: it raises).
 #
 # Observability: every retry/resume/degrade/fault-firing increments a
 # profiling counter (profiling.counter_totals()) so the behavior under faults

@@ -67,9 +67,10 @@ _logger = get_logger("reliability.faults")
 
 class DeviceError(RuntimeError):
     """Unrecoverable accelerator-side failure — the stand-in the fault harness
-    raises for XlaRuntimeError-class errors (which cannot be constructed
-    portably). `is_device_error` treats both identically: never retried, routed
-    to the CPU fallback rung of the degradation ladder."""
+    raises for the runtime's own error class (jax.errors.JaxRuntimeError, the
+    XlaRuntimeError of earlier releases). `is_device_error` treats both
+    identically: never retried, never degraded — a device error raises out of
+    the fit."""
 
 
 class StreamBatchError(RuntimeError):
@@ -239,15 +240,18 @@ def fault_point(site: str, batch: Optional[int] = None) -> None:
 
 
 def is_device_error(e: BaseException) -> bool:
-    """Unrecoverable accelerator failure: never retried; the degradation ladder
-    routes it into the fallback.enabled CPU path (core/estimator.py). A
+    """Unrecoverable accelerator failure (compile refusal, VMEM/HBM overflow,
+    a lost device): never retried and never answered by a host fit — it raises
+    out of `Estimator.fit`, so a model that exists did run on the device. A
     StreamBatchError is classified by the failure it wraps."""
     if isinstance(e, StreamBatchError) and e.__cause__ is not None:
         return is_device_error(e.__cause__)
     if isinstance(e, DeviceError):
         return True
-    mod = type(e).__module__ or ""
-    return type(e).__name__ == "XlaRuntimeError" or mod.startswith("jaxlib")
+    import sys
+
+    jax = sys.modules.get("jax")  # no jax imported -> no jax error to classify
+    return jax is not None and isinstance(e, jax.errors.JaxRuntimeError)
 
 
 def is_transient(e: BaseException) -> bool:

@@ -452,7 +452,7 @@ class MicroBatcher:
         try:
             # the mid-batch failure site: an injected raise here fails exactly
             # this batch's futures (retryably, for OSError-class faults) and
-            # the dispatcher loop carries on — the queue must never wedge
+            # the dispatcher loop carries on — the queue must never stall
             b_ord = self.batches_done
             self.batches_done = b_ord + 1
             fault_point("serving_execute", batch=b_ord)

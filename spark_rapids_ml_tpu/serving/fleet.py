@@ -3,7 +3,7 @@
 # failover (docs/design.md §7c).
 #
 # The single-dispatcher serving plane (batcher.py + registry.py) leaves one
-# failure domain per model: a wedged or killed dispatcher strands every
+# failure domain per model: a hung or killed dispatcher strands every
 # queued and in-flight request. This module replicates that domain N ways
 # (`serving.replicas`), Podracer-style (arXiv:2104.06272 — decoupled feed
 # threads fanning into replicated batched accelerator steps), and makes the

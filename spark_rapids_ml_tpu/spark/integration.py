@@ -90,7 +90,7 @@ def _collect_partition(pdf_iter):
 # python process — which only happens in local-mode simulation (the test
 # harness runs tasks as threads); production runs one task per TPU host
 # process, so the lock is uncontended there. Concurrent XLA dispatch from
-# many Python threads has been observed to wedge some jaxlib builds; the
+# many Python threads has been observed to hang some jaxlib builds; the
 # control plane (collect, allGather, init retry) stays fully concurrent.
 _DEVICE_PROGRAM_LOCK = threading.Lock()
 

@@ -7,6 +7,7 @@
 from __future__ import annotations
 
 import logging
+import os
 import sys
 from typing import Any, Iterator, List, Optional, Tuple, Union
 
@@ -26,6 +27,29 @@ def get_logger(cls: Any, level: Union[int, str] = logging.INFO) -> logging.Logge
         logger.addHandler(handler)
         logger.propagate = False
     return logger
+
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a FIXED directory before the
+    first compile, and return the directory in use. Entry points call this
+    (chip_smoke.py, bench.py, `python -m spark_rapids_ml_tpu.autotune`); library
+    code never does.
+
+    Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and nothing
+    is set here. Otherwise the cache lives at `<checkout>/.jax_cache` (git-
+    ignored): the directory is part of the cache key, so a path built from a
+    temporary name, a pid or the time would never hit."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    checkout = os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
+    cache_dir = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
 
 
 def _get_default_params_from_func(func: Any, unsupported_set: Optional[set] = None) -> dict:

@@ -101,6 +101,28 @@ def test_extract_collectives_from_synthetic_hlo():
     assert by_kind["all_gather"]["replica_groups"] == "{{0,1,2,3},{4,5,6,7}}"
 
 
+def test_extract_collectives_from_tpu_combined_tuple_with_tiled_layouts():
+    """Lines as a TPU v5e compiles them (PR 21, four chips): XLA's combiner
+    merges a fit's reductions into ONE tuple-shaped op whose element layouts
+    nest parentheses (`T(8,128)S(1)`). The tuple must be read to its closing
+    parenthesis — reading to the first `)` dropped the op, and the comm plane
+    saw 4 bytes of a 10,324-byte Lloyd exchange."""
+    hlo = """
+  %AR.5 = (f32[20,128]{1,0:T(8,128)S(1)}, f32[20]{0:T(128)S(1)}) OP_AR(%fusion.30, %select_reduce_fusion.2), channel_id=2, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add.clone
+  %get-tuple-element.168 = f32[20]{0:T(128)S(1)} get-tuple-element(%AR.5), index=1
+  %AR.2 = f32[]{:T(128)} OP_AR(%multiply_reduce_fusion.3), channel_id=3, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%region_10.11.clone
+  ROOT %tuple.18 = (f32[20,128]{1,0:T(8,128)}, f32[]{:T(128)}, s32[]{:T(128)}) tuple(%copy-done.1, %AR.2, %get-tuple-element.99)
+""".replace("OP_AR", "all" + "-reduce").replace("AR.", "all" + "-reduce.")
+    recs = comm.extract_collectives(hlo)
+    assert [r["kind"] for r in recs] == ["all_reduce", "all_reduce"]
+    assert recs[0]["bytes"] == 20 * 128 * 4 + 20 * 4
+    assert recs[1]["bytes"] == 4
+    assert recs[0]["replica_groups"] == "[1,4]<=[4]"
+    summary = comm.collective_summary(hlo)
+    assert summary["all_reduce"] == {
+        "ops": 2, "bytes": 10324, "replica_groups": ["[1,4]<=[4]"]}
+
+
 def test_collective_summary_aggregates_by_kind():
     summary = comm.collective_summary(_SYNTH_HLO + _SYNTH_HLO)
     assert summary["all_reduce"]["ops"] == 2

@@ -8,6 +8,7 @@ import importlib.util
 import json
 import logging
 import os
+import types
 from pathlib import Path
 
 import jax
@@ -75,6 +76,57 @@ def test_compiled_kernel_captures_cost_and_counts_signatures():
     totals = profiling.counter_totals()
     assert totals["device.compile{kernel=t.mm}"] == 3
     assert totals["device.kernel_calls{kernel=t.mm}"] == 7
+
+
+def test_compiled_kernel_raises_on_aot_compile_failure(monkeypatch):
+    """An AOT lower().compile() failure (on the chip: a Mosaic refusal, a VMEM
+    or HBM overflow) RAISES to the caller. It used to be logged once and
+    answered by plain jit, after which `device.compile{kernel=}` no longer
+    described what ran."""
+    @obs.compiled_kernel("t.refused")
+    def k(a):
+        return a * 2.0
+
+    class Refusing:
+        def lower(self, *a, **kw):
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        def __call__(self, *a, **kw):
+            pytest.fail("plain jit must not answer a failed AOT compile")
+
+    monkeypatch.setattr(k, "_jit", Refusing())
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        k(jnp.ones((8,)))
+    assert dev.compile_count("t.refused") == 0
+    assert "device.kernel_calls{kernel=t.refused}" not in profiling.counter_totals()
+    # nothing was cached for the signature: the next call compiles (and raises) again
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        k(jnp.ones((8,)))
+
+
+def test_compiled_kernel_raises_on_aot_call_failure(monkeypatch):
+    """A failing call of the cached AOT executable (wrong device, wrong
+    sharding, OOM) RAISES; the executable is neither dropped nor replaced by
+    plain jit for later calls."""
+    @obs.compiled_kernel("t.callfail")
+    def k(a):
+        return a + 1.0
+
+    x = jnp.ones((8,))
+    np.testing.assert_allclose(np.asarray(k(x)), 2.0)  # compiled + cached
+    (entry,) = k._cache.values()
+
+    def broken_exe(*a, **kw):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+    monkeypatch.setitem(entry, "exe", broken_exe)
+    monkeypatch.setattr(
+        k, "_jit",
+        lambda *a, **kw: pytest.fail("plain jit must not answer a failed call"))
+    for _ in range(2):  # the second call hits the same executable, not jit
+        with pytest.raises(RuntimeError, match="out of HBM"):
+            k(x)
+    assert profiling.counter_totals()["device.kernel_calls{kernel=t.callfail}"] == 1
 
 
 def test_trace_epoch_rekeys_cache_on_parity_precision_change():
@@ -189,6 +241,42 @@ def test_peak_overrides_and_platform_table():
     config.set("observability.peak_flops", 123.0)
     config.set("observability.peak_bw", 456.0)
     assert dev.platform_peaks()[:2] == (123.0, 456.0)
+
+
+def _FakeDevice(platform, device_kind):
+    return types.SimpleNamespace(platform=platform, device_kind=device_kind)
+
+
+@pytest.mark.parametrize("kind,flops,bw", [
+    ("TPU v5 lite", 98e12, 819e9),
+    ("TPU v5e", 98e12, 819e9),
+    ("TPU v4", 137e12, 1228e9),
+])
+def test_known_tpu_device_kinds_resolve_to_their_row(monkeypatch, kind, flops, bw):
+    monkeypatch.setattr(jax, "local_devices", lambda: [_FakeDevice("tpu", kind)])
+    assert dev.platform_peaks() == (flops, bw, "tpu")
+
+
+@pytest.mark.parametrize("platform,kind", [
+    ("tpu", "TPU v9 hyper"),  # a TPU no row names: there is no catch-all row
+    ("tpu", ""),
+    ("rocm", "MI300"),  # an unknown platform no longer gets the CPU row
+])
+def test_unknown_device_kind_raises_where_peaks_are_asked_for(
+        monkeypatch, platform, kind):
+    """A device that is not in the table is an error, not a default: a roofline
+    share against another chip's peaks is a wrong number."""
+    monkeypatch.setattr(
+        jax, "local_devices", lambda: [_FakeDevice(platform, kind)])
+    with pytest.raises(ValueError, match="no peak-table row"):
+        dev.platform_peaks()
+    with pytest.raises(ValueError, match="no peak-table row"):
+        dev.platform_ici_bw()
+    # an explicit override does not rescue it: the platform label itself
+    # comes from the row
+    config.set("observability.peak_flops", 1.0)
+    with pytest.raises(ValueError, match="no peak-table row"):
+        dev.platform_peaks()
 
 
 # ----------------------------------------- streamed fit end-to-end (satellite)

@@ -151,18 +151,55 @@ def test_staging_pool_cpu_never_reuses_buffers(monkeypatch):
     assert not np.shares_memory(a, b)
 
 
-def test_staging_pool_double_buffer_ring_on_copying_backends(monkeypatch):
+def test_staging_pool_reuses_one_buffer_on_copying_backends(monkeypatch):
+    """Where device_put copies (TPU/GPU) the pool hands the SAME buffer back
+    for a key: the upload site fences each batch's transfer before the slicer
+    runs again (test_batch_stream_fences_transfer_before_buffer_reuse), so one
+    buffer per key is enough."""
     monkeypatch.setattr(ingest, "_device_put_copies_cache", True)
     pool = ingest.StagingPool(pool_rows=16)
     a = pool.buffer((8, 4), np.float32)
     b = pool.buffer((8, 4), np.float32)
-    c = pool.buffer((8, 4), np.float32)
-    assert not np.shares_memory(a, b)  # consecutive calls alternate buffers
-    assert np.shares_memory(a, c)  # ring of two: third call rewraps the first
+    assert np.shares_memory(a, b)
     assert a.shape == (8, 4)
-    # distinct (dtype, tail) keys get distinct rings
-    d = pool.buffer((8, 4), np.float64)
-    assert not np.shares_memory(a, d)
+    # distinct slots and distinct (dtype, tail) keys get distinct buffers
+    assert not np.shares_memory(a, pool.buffer((8, 4), np.float32, slot="w"))
+    assert not np.shares_memory(a, pool.buffer((8, 4), np.float64))
+
+
+def test_batch_stream_fences_transfer_before_buffer_reuse(monkeypatch):
+    """device_put returns before the runtime has read the host buffer (seen on
+    a v5e: a buffer rewritten right after device_put arrived corrupted), so
+    `_batch_stream` must wait for each batch's transfer before the slicer may
+    refill the reused staging buffer. Order of events, with reuse forced on:
+    every batch is block_until_ready'd before the next slicer call."""
+    import jax
+
+    from spark_rapids_ml_tpu.ops import streaming
+
+    monkeypatch.setattr(ingest, "_device_put_copies_cache", True)
+    events = []
+    real_block = jax.block_until_ready
+
+    def spy_block(x):
+        events.append("fence")
+        return real_block(x)
+
+    monkeypatch.setattr(streaming.jax, "block_until_ready", spy_block)
+    X = np.asfortranarray(
+        np.arange(64 * 4, dtype=np.float32).reshape(64, 4))  # copy path
+    w = np.ones(64, np.float32)
+    pool = ingest.StagingPool(pool_rows=16)
+
+    def slicer(s, e):
+        events.append("slice")
+        return (ingest.stage_block(X, s, e, np.float32, pool, slot="X"),
+                ingest.stage_block(w, s, e, np.float32, pool, slot="w"))
+
+    n_batches = sum(1 for _ in streaming._prefetch(
+        streaming._batch_stream(64, 16, None, slicer)))
+    assert n_batches == 4
+    assert events == ["slice", "fence"] * 4
 
 
 def test_staging_pool_grows_past_pool_rows(monkeypatch):

@@ -479,6 +479,12 @@ def test_resolve_pallas_fused_semantics(monkeypatch):
     # auto on TPU: fusable sites fuse past the min-items threshold...
     monkeypatch.setattr(sel, "_backend", lambda: "tpu")
     assert sel.resolve(1 << 17, 10, "auto", fusable=True)[0] == "pallas_fused"
+    # the auto gate is closed above the largest k Mosaic places on a v5e (the
+    # unrolled extraction overflows scoped VMEM at k=64 — autotune/defaults.py);
+    # an explicit request still reaches the kernel and fails loudly there
+    assert sel.resolve(1 << 17, 32, "auto", fusable=True)[0] == "pallas_fused"
+    assert sel.resolve(1 << 17, 33, "auto", fusable=True)[0] == "approx"
+    assert sel.resolve(1 << 17, 64, "pallas_fused", fusable=True)[0] == "pallas_fused"
     # ...below it (or at a non-fusable site) auto keeps the PR-5 strategy
     assert sel.resolve(1 << 10, 10, "auto", fusable=True)[0] == "approx"
     assert sel.resolve(1 << 17, 10, "auto")[0] == "approx"

@@ -536,10 +536,10 @@ def test_streamed_dbscan_retries_identical(n_devices):
 # ------------------------------------------------ device-error degradation
 
 
-def test_device_error_degrades_to_cpu_fallback(tiny_stream):
+def test_device_error_raises_instead_of_a_host_fit(tiny_stream):
     """Unrecoverable device errors (DeviceError / XlaRuntimeError class) are
-    never retried: the fit routes into the fallback.enabled CPU path and still
-    returns a model, with the degrade counted."""
+    never retried AND never answered by the sklearn twin: with fallback.enabled
+    at its default the fit raises, so a model that exists ran on the device."""
     from spark_rapids_ml_tpu.regression import LinearRegression
 
     rng = np.random.default_rng(43)
@@ -547,16 +547,41 @@ def test_device_error_degrades_to_cpu_fallback(tiny_stream):
     y = (X @ rng.normal(size=6)).astype(np.float32)
     df = pd.DataFrame({"features": list(X), "label": y})
 
+    assert config.get("fallback.enabled") is True
     _inject("ingest:batch=1:raise=DeviceError")
-    model = LinearRegression(regParam=0.0).fit(df)
+    with pytest.raises(StreamBatchError) as ei:
+        LinearRegression(regParam=0.0).fit(df)
+    assert isinstance(ei.value.__cause__, DeviceError)
     totals = profiling.counter_totals()
-    assert totals.get("reliability.degrade.device_to_cpu", 0) == 1
+    assert not any(k.startswith("reliability.degrade") for k in totals), totals
     assert totals.get("reliability.resume.ingest", 0) == 0  # never retried
-    # the sklearn twin recovers the true coefficients on noiseless data
-    from sklearn.linear_model import LinearRegression as SkLR
 
-    sk = SkLR().fit(X.astype(np.float64), y)
-    np.testing.assert_allclose(model.coefficients, sk.coef_, rtol=1e-3, atol=1e-3)
+
+def test_xla_runtime_error_in_fit_raises_not_sklearn_model(monkeypatch):
+    """The installed JAX's own device-failure class (jax.errors.JaxRuntimeError
+    — what a Mosaic compile refusal, a VMEM overflow or an HBM OOM raises)
+    thrown from inside the in-core fit kernel propagates out of
+    `Estimator.fit`; the sklearn twin is never consulted."""
+    from jax.errors import JaxRuntimeError
+
+    from spark_rapids_ml_tpu.clustering import KMeans
+    from spark_rapids_ml_tpu.models import clustering as clustering_mod
+
+    assert is_device_error(JaxRuntimeError("RESOURCE_EXHAUSTED: HBM"))
+    assert not is_transient(JaxRuntimeError("INTERNAL: Mosaic failed"))
+
+    def refuse(*a, **k):
+        raise JaxRuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(clustering_mod, "kmeans_fit", refuse)
+    monkeypatch.setattr(
+        KMeans, "_fallback_fit",
+        lambda self, ds: pytest.fail("the sklearn twin must not run"))
+    X = np.random.default_rng(5).normal(size=(64, 4)).astype(np.float32)
+    with pytest.raises(JaxRuntimeError, match="Mosaic failed"):
+        KMeans(k=2, maxIter=2, seed=1).fit(X)
+    assert not any(
+        k.startswith("reliability.degrade") for k in profiling.counter_totals())
 
 
 def test_device_error_raises_when_reliability_disabled(tiny_stream):

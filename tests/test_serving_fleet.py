@@ -470,7 +470,7 @@ def test_single_dispatcher_execute_fault_fails_batch_without_wedging(km):
         with pytest.raises(OSError) as ei:  # batch 2 takes the injected fault
             registry.predict("km", X_BLOBS[:4], timeout=20.0)
         assert is_transient(ei.value)  # a client/fleet MAY replay it
-        for _ in range(3):  # the queue did not wedge
+        for _ in range(3):  # the queue did not stall
             out = registry.predict("km", X_BLOBS[:5], timeout=20.0)
             assert np.array_equal(out["prediction"], ref[:5])
     finally:

@@ -435,12 +435,13 @@ def test_unhandled_fit_failure_dumps_postmortem(tmp_path):
     assert load_run_reports(str(tmp_path))[-1]["status"] == "error"
 
 
-def test_degrade_ladder_entry_dumps_postmortem_with_fault_event(tmp_path):
+def test_device_error_fit_dumps_postmortem_with_fault_event(tmp_path):
     """PR 1's deterministic fault sites make the forensics path testable: a
-    DeviceError injected at `ingest` aborts the streamed fit, the estimator
-    degrades device->CPU, and the bundle written AT THE DEGRADE captures both
-    the fault and degrade transitions in its ring."""
+    DeviceError injected at `ingest` aborts the streamed fit. There is no CPU
+    rung to degrade to (a device error raises, docs/design.md §6b), so the
+    failure itself writes the bundle, and its ring carries the fault."""
     from spark_rapids_ml_tpu.clustering import KMeans
+    from spark_rapids_ml_tpu.reliability.faults import StreamBatchError
 
     config.set("observability.metrics_dir", str(tmp_path))
     config.set("stream_threshold_bytes", 1024)
@@ -448,18 +449,15 @@ def test_degrade_ladder_entry_dumps_postmortem_with_fault_event(tmp_path):
     config.set("reliability.fault_spec", "ingest:batch=1:raise=DeviceError")
     flight.reset_flight_recorder()
     reset_faults()
-    model = KMeans(k=2, maxIter=4, seed=3).fit(_blob_pdf(n=256))
-    # the fit SUCCEEDED via the CPU rung…
-    assert model.fit_report_["status"] == "ok"
+    with pytest.raises(StreamBatchError):
+        KMeans(k=2, maxIter=4, seed=3).fit(_blob_pdf(n=256))
     bundles = [p for p in os.listdir(tmp_path) if p.startswith("postmortem_")]
     assert len(bundles) == 1, bundles
     doc = flight.load_postmortem(str(tmp_path / bundles[0]))
-    assert doc["reason"] == "degrade:device_to_cpu"
-    assert doc["run_id"] == model.fit_report_["run_id"]
+    assert doc["reason"] == "fit_error:StreamBatchError"
     kinds = [e["kind"] for e in doc["ring"]]
     assert "fault" in kinds, kinds
-    degrade = [e for e in doc["ring"] if e["kind"] == "degrade"]
-    assert degrade and degrade[0]["rung"] == "device_to_cpu"
+    assert "degrade" not in kinds, kinds
 
 
 # ------------------------------------------------- satellite: prom escaping

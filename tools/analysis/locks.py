@@ -6,7 +6,7 @@
 #   * locks/order-cycle — build a lock-ORDER graph (edge A->B when B is
 #     acquired, directly or through a resolved call chain, while A is held)
 #     and report every cycle. A cycle is a deadlock waiting for the right
-#     thread interleaving — a wedged barrier at pod scale. Self-edges on
+#     thread interleaving — a hung barrier at pod scale. Self-edges on
 #     RLocks are legal re-entry and skipped; a self-edge on a plain Lock is a
 #     guaranteed self-deadlock and reported.
 #
