@@ -23,8 +23,10 @@ from abc import abstractmethod
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
+from ..observability import span as _obs_span
 from ..parallel.partition import PartitionDescriptor, pad_rows
 from ..parallel.partitioner import active_partitioner
 from ..utils import get_logger
@@ -65,6 +67,15 @@ class FitInputs:
     # kernels may then take prefix-mask fast paths (ops/pallas_xtwx.py) that avoid
     # streaming a weight vector entirely
     unit_weight: bool = False
+
+    def device_arrays(self) -> List[Any]:
+        """The arrays placed on the mesh for this fit."""
+        return [
+            a
+            for a in (self.features, self.sparse_values, self.sparse_indices,
+                      self.row_weight, self.label)
+            if a is not None
+        ]
 
 
 # type of the value returned by _get_tpu_fit_func
@@ -186,10 +197,10 @@ class _TpuCaller(_TpuClass, _TpuParams):
         )
         return FitInputs(
             features=None,
-            sparse_values=part.shard(values),
-            sparse_indices=part.shard(indices),
-            row_weight=part.shard(row_weight),
-            label=part.shard(label_p) if label_p is not None else None,
+            sparse_values=part.shard(values, site="fit"),
+            sparse_indices=part.shard(indices, site="fit"),
+            row_weight=part.shard(row_weight, site="fit"),
+            label=part.shard(label_p, site="fit") if label_p is not None else None,
             desc=desc,
             mesh=mesh,
             params=dict(self._tpu_params),
@@ -210,13 +221,16 @@ class _TpuCaller(_TpuClass, _TpuParams):
         # the Arrow fast path may defer dtype conversion (core/dataset.py); the
         # staged in-core plane materializes the whole matrix anyway, so the
         # counted host cast happens here (streamed fits cast in-program instead)
-        X = ensure_dtype(
-            densify(fd.features, float32=self._float32_inputs),
-            float32=self._float32_inputs,
-        )
-        X = np.asarray(X, order=self._fit_array_order())  # type: ignore[arg-type]
-        Xp, pad_weight, (label_p, sw_p) = pad_rows(X, num_workers, fd.label, fd.weight)
-        row_weight = pad_weight if sw_p is None else pad_weight * sw_p
+        with _obs_span("fit.stage"):
+            X = ensure_dtype(
+                densify(fd.features, float32=self._float32_inputs),
+                float32=self._float32_inputs,
+            )
+            X = np.asarray(X, order=self._fit_array_order())  # type: ignore[arg-type]
+            Xp, pad_weight, (label_p, sw_p) = pad_rows(
+                X, num_workers, fd.label, fd.weight
+            )
+            row_weight = pad_weight if sw_p is None else pad_weight * sw_p
 
         # real-row counts per rank under the actual contiguous equal-shard layout:
         # rank r owns padded rows [r*s, (r+1)*s); rows >= n_rows are padding
@@ -232,9 +246,9 @@ class _TpuCaller(_TpuClass, _TpuParams):
         )
 
         return FitInputs(
-            features=part.shard(Xp),
-            row_weight=part.shard(row_weight),
-            label=part.shard(label_p) if label_p is not None else None,
+            features=part.shard(Xp, site="fit"),
+            row_weight=part.shard(row_weight, site="fit"),
+            label=part.shard(label_p, site="fit") if label_p is not None else None,
             desc=desc,
             mesh=mesh,
             params=dict(self._tpu_params),
@@ -327,7 +341,8 @@ class _TpuCaller(_TpuClass, _TpuParams):
     ) -> List[Dict[str, Any]]:
         """Run the fit kernel over the mesh and return model-attribute dicts, one per
         fitted model (reference _call_cuml_fit_func, core.py:742-1011)."""
-        fd = self._pre_process_data(dataset)
+        with _obs_span("fit.ingest"):
+            fd = self._pre_process_data(dataset)
         if fd.n_rows == 0:
             raise RuntimeError(
                 "Fit input is empty. An empty partition would hang the reference's "
@@ -370,6 +385,11 @@ class _TpuCaller(_TpuClass, _TpuParams):
                 inputs = self._build_fit_inputs(fd)
             fit_func = self._get_tpu_fit_func(extra_params)
             with span(f"{type(self).__name__}.fit", verbose):
+                # the puts of `prepare` are asynchronous: the upload is waited
+                # for here, as a phase of its own, not at whatever the fit
+                # function happens to read first
+                with _obs_span("h2d.wait", {"site": "fit"}):
+                    jax.block_until_ready(inputs.device_arrays())
                 result = fit_func(inputs)
         if isinstance(result, list):
             return result
@@ -434,15 +454,16 @@ class _TpuEstimator(_TpuCaller):
     ) -> List["_TpuModel"]:
         attr_rows = self._call_tpu_fit_func(dataset, extra_params)
         models = []
-        for attrs in attr_rows:
-            model = self._create_pyspark_model(attrs)
-            model._num_workers = self._num_workers
-            model._float32_inputs = self._float32_inputs
-            # freshly-fit marker: training summaries exist only on fit() results,
-            # never after save/load (Spark semantics)
-            model._has_training_summary = True
-            self._copyValues(model)
-            models.append(model)
+        with _obs_span("fit.finish"):
+            for attrs in attr_rows:
+                model = self._create_pyspark_model(attrs)
+                model._num_workers = self._num_workers
+                model._float32_inputs = self._float32_inputs
+                # freshly-fit marker: training summaries exist only on fit()
+                # results, never after save/load (Spark semantics)
+                model._has_training_summary = True
+                self._copyValues(model)
+                models.append(model)
         return models
 
     def _fit(self, dataset: Any) -> "_TpuModel":
@@ -709,22 +730,26 @@ class _TpuModel(_TpuClass, _TpuParams):
             n_rows = 0
         with transform_run(type(self).__name__) as run:
             with transform_batch(self, n_rows):
-                input_col, input_cols = self._input_col_for_transform()
-                fd = extract_feature_data(
-                    dataset,
-                    input_col=input_col,
-                    input_cols=input_cols,
-                    float32=self._float32_inputs,
-                )
-                if fd.is_sparse and self._supports_sparse_transform():
-                    outputs = self._transform_sparse(fd.features)
-                else:
-                    X = ensure_dtype(
-                        densify(fd.features, float32=self._float32_inputs),
+                with _obs_span("transform.stage"):
+                    input_col, input_cols = self._input_col_for_transform()
+                    fd = extract_feature_data(
+                        dataset,
+                        input_col=input_col,
+                        input_cols=input_cols,
                         float32=self._float32_inputs,
                     )
+                    sparse = fd.is_sparse and self._supports_sparse_transform()
+                    if not sparse:
+                        X = ensure_dtype(
+                            densify(fd.features, float32=self._float32_inputs),
+                            float32=self._float32_inputs,
+                        )
+                if sparse:
+                    outputs = self._transform_sparse(fd.features)
+                else:
                     outputs = self._transform_arrays(X)
-                out = append_output_columns(dataset, outputs)
+                with _obs_span("transform.output"):
+                    out = append_output_columns(dataset, outputs)
         if run is not None:
             self.transform_report_ = run.report()
         return out

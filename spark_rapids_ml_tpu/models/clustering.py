@@ -213,18 +213,21 @@ class KMeans(_KMeansClass, _TpuEstimator, _KMeansParams):
                 # matching Spark's groupBy(prediction).count().
                 import jax.numpy as _jnp
 
+                from ..observability import counter_inc, span
                 from ..ops.kmeans import kmeans_predict
 
-                assign = np.asarray(
-                    kmeans_predict(
-                        inputs.features,
-                        _jnp.asarray(res["cluster_centers"]),
-                        cosine=str(p.get("metric", "euclidean")) == "cosine",
+                with span("kmeans.summary"):
+                    assign = np.asarray(
+                        kmeans_predict(
+                            inputs.features,
+                            _jnp.asarray(res["cluster_centers"]),
+                            cosine=str(p.get("metric", "euclidean")) == "cosine",
+                        )
                     )
-                )[: inputs.desc.m]
-                res["cluster_sizes"] = np.bincount(
-                    assign, minlength=int(p["n_clusters"])
-                ).astype(np.int64)
+                    counter_inc("d2h.bytes", int(assign.nbytes), site="fit")
+                    res["cluster_sizes"] = np.bincount(
+                        assign[: inputs.desc.m], minlength=int(p["n_clusters"])
+                    ).astype(np.int64)
                 results.append(res)
             return results if extra_params is not None else results[0]
 
@@ -408,16 +411,14 @@ class KMeansModel(_KMeansClass, _TpuModelWithPredictionCol, _KMeansParams):
         )
 
     def _transform_arrays(self, X: np.ndarray) -> Dict[str, np.ndarray]:
-        from ..observability.inference import predict_dispatch
+        from ..observability.inference import predict_to_host
 
         if self._cosine and not np.all(np.linalg.norm(X, axis=1) > 0):
             raise ValueError(
                 "Cosine distance is not defined for zero-length vectors; the input "
                 "contains an all-zero feature row."
             )
-        pred = np.asarray(
-            predict_dispatch(
-                self, kmeans_predict, X, self.cluster_centers_, self._cosine
-            )
+        pred = predict_to_host(
+            self, kmeans_predict, X, self.cluster_centers_, self._cosine
         )
         return {self.getOrDefault("predictionCol"): pred.astype(np.int32)}

@@ -119,13 +119,23 @@ class PCA(_PCAClass, _TpuEstimator, _PCAParams):
                     raise ValueError(
                         f"k={k} exceeds the number of features {inputs.desc.n}"
                     )
-            cov, mean, wsum = covariance_for_fit(
-                inputs.features,
-                inputs.row_weight,
-                mesh=inputs.mesh,
-                unit_weight=inputs.unit_weight,
-            )
-            results = [pca_attrs_from_cov(cov, mean, wsum, k) for k in ks]
+            import jax
+
+            from ..observability import span
+
+            with span("pca.cov"):
+                # waited for here, so that the span reads the covariance pass
+                # as the host sees it and `pca.eig` the eigensolve and fetch
+                cov, mean, wsum = jax.block_until_ready(
+                    covariance_for_fit(
+                        inputs.features,
+                        inputs.row_weight,
+                        mesh=inputs.mesh,
+                        unit_weight=inputs.unit_weight,
+                    )
+                )
+            with span("pca.eig"):
+                results = [pca_attrs_from_cov(cov, mean, wsum, k) for k in ks]
             return results if extra_params is not None else results[0]
 
         return _fit
@@ -230,12 +240,10 @@ class PCAModel(_PCAClass, _TpuModelWithColumns, _PCAParams):
         return self._model_attributes["singular_values"]
 
     def _transform_arrays(self, X: np.ndarray) -> Dict[str, np.ndarray]:
-        from ..observability.inference import predict_dispatch
+        from ..observability.inference import predict_to_host
 
-        out = np.asarray(
-            predict_dispatch(
-                self, pca_transform, X, self._model_attributes["components"]
-            )
+        out = predict_to_host(
+            self, pca_transform, X, self._model_attributes["components"]
         )
         return {self.getOrDefault("outputCol"): out}
 

@@ -234,12 +234,13 @@ def record_shape_signature(model_name: str, sig: Tuple[int, int, str]) -> bool:
 
 
 def predict_dispatch(model: Any, kernel: Any, *args: Any,
-                     shape_of: Any = None, **kwargs: Any) -> Any:
+                     shape_of: Any = None, put_query: bool = False,
+                     **kwargs: Any) -> Any:
     """Run one model family's predict kernel under the inference-plane
-    instrumentation. `args`/`kwargs` pass through to `kernel` untouched; the
-    shape signature is read from `shape_of` when the query block is not the
-    first positional (kNN ring kernels lead with the mesh), else from the first
-    array-like argument.
+    instrumentation. `args`/`kwargs` pass through to `kernel` untouched (but see
+    `put_query`); the shape signature is read from `shape_of` when the
+    query block is not the first positional (kNN ring kernels lead with the
+    mesh), else from the first array-like argument.
 
     Reported per call, uniformly across families:
       * `transform.predict_calls{model=}` / `transform.predict_rows{model=}`
@@ -248,9 +249,21 @@ def predict_dispatch(model: Any, kernel: Any, *args: Any,
         `observability.transform_sample_rate`)
       * shape-bucket registration + recompile sentinel (see module header)
 
-    The recorded latency covers the kernel call as issued from Python; jax
-    dispatch is asynchronous, so on accelerators it bounds dispatch+compile,
-    while the per-batch `transform.batch_s` histogram (which wraps the whole
+    `put_query=True` (the families whose kernel is one jitted program over the
+    whole query block: KMeans, PCA) makes the upload a phase instead of a side
+    effect of the call: a numpy query block (the operand the shape signature
+    is read from) is placed on the device through the partitioner's choke
+    point (span `h2d.put`, `h2d.bytes{site=transform}`) and the transfer is
+    waited for under `h2d.wait` before the kernel is called. Kernels that keep
+    the block on the host by design (streamed kNN/DBSCAN/UMAP, forests) leave
+    it off.
+
+    `transform.predict` covers the kernel call as issued from Python; jax
+    dispatch is asynchronous, so it reads dispatch (and a first call's compile),
+    not the kernel. With `put_query` the upload lies before it (`h2d.put`,
+    `h2d.wait`) and the kernel's run and the outputs' way back after it
+    (`transform.fetch`, see `fetch`); without, the operands' transfer is part
+    of the call and the per-batch `transform.batch_s` histogram (the whole
     batch including the host materialization) bounds end-to-end time.
     """
     mname = type(model).__name__
@@ -264,12 +277,57 @@ def predict_dispatch(model: Any, kernel: Any, *args: Any,
     record_shape_signature(mname, sig)
     counter_inc("transform.predict_calls", 1, model=mname)
     counter_inc("transform.predict_rows", sig[0], model=mname)
+    if put_query:
+        args = _put_query(args, ref)
     t0 = time.perf_counter()
     with span("transform.predict", {"model": mname, "rows": sig[0]}):
         out = kernel(*args, **kwargs)
     if _should_sample("predict:" + mname):
         observe("transform.predict_s", time.perf_counter() - t0, model=mname)
     return out
+
+
+def _put_query(args: Tuple[Any, ...], query: Any) -> Tuple[Any, ...]:
+    """`args` with the host query block placed on the device (`h2d.put`) and
+    resident (`h2d.wait`): on the default device, or beside the operands
+    already committed to one (the serving plane's HBM-resident weights). The
+    small weight operands stay arguments of the call."""
+    import jax
+    import numpy as np
+
+    from ..parallel.partitioner import put_device_local
+
+    if not isinstance(query, np.ndarray):
+        return args
+    device = None
+    for a in args:
+        if isinstance(a, jax.Array) and a.committed and len(a.devices()) == 1:
+            (device,) = a.devices()
+            break
+    placed = put_device_local(query, site="transform", device=device)
+    with span("h2d.wait", {"site": "transform"}):
+        jax.block_until_ready(placed)
+    return tuple(placed if a is query else a for a in args)
+
+
+def fetch(out: Any) -> Any:
+    """A kernel's result as a numpy array: span `transform.fetch` covers the
+    wait for the kernel and the outputs' way back to the host, and
+    `d2h.bytes{site=transform}` counts what came back."""
+    import numpy as np
+
+    with span("transform.fetch"):
+        host = np.asarray(out)
+    counter_inc("d2h.bytes", int(host.nbytes), site="transform")
+    return host
+
+
+def predict_to_host(model: Any, kernel: Any, *args: Any, **kwargs: Any) -> Any:
+    """`predict_dispatch` with the upload, the kernel's dispatch and the way
+    back as separate phases: the host table put and waited for, then the call,
+    then `fetch`. For the families whose transform is one jitted program over
+    a host table (KMeansModel, PCAModel)."""
+    return fetch(predict_dispatch(model, kernel, *args, put_query=True, **kwargs))
 
 
 @contextlib.contextmanager

@@ -38,6 +38,12 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 DEFAULT_TIME_BUCKETS: Tuple[float, ...] = tuple(1e-4 * 2.0 ** i for i in range(20))
 
 
+# the counter-form view of span totals (MetricsRegistry._span_counters)
+# srml-metric: span.seconds{span}
+# srml-metric: span.calls{span}
+SPAN_SECONDS = "span.seconds"
+SPAN_CALLS = "span.calls"
+
 # characters with structural meaning in a label key; sanitized out of label
 # names/values so split_label_key is a TRUE inverse of label_key — an
 # unescaped ','/'=' in a value (e.g. an exception message used as a label)
@@ -312,12 +318,38 @@ class MetricsRegistry:
                     out[key] = st
         return out
 
+    def _span_counters(self) -> Dict[str, Any]:
+        """Every span's seconds and calls in counter form, `span.seconds{span=}`
+        and `span.calls{span=}`: a read-time view of what the write path already
+        keeps (the span totals and the same-named latency histogram's counts),
+        so a reader that sees only counters (a fit report's `counters`, a
+        before/after pair of `counter_totals()`) can take a span's seconds per
+        operation. A name shows once both halves hold it, so `reset_counters()`
+        and `reset_spans()` each empty the view."""
+        out: Dict[str, Any] = {}
+        with self._lock:
+            for name, seconds in self._span_totals.items():
+                hist = self._metrics.get(name)
+                if not isinstance(hist, Histogram):
+                    continue
+                calls = sum(st["count"] for st in hist._values.values())
+                if calls:
+                    out[label_key(SPAN_SECONDS, {"span": name})] = seconds
+                    out[label_key(SPAN_CALLS, {"span": name})] = calls
+        return out
+
+    def _counters(self) -> Dict[str, Any]:
+        out = self._flat("counter")
+        out.update(self._span_counters())
+        return out
+
     def counter_totals(self) -> Dict[str, Any]:
         """Counters AND gauges flattened to one name -> value dict — the exact
         legacy `profiling.counter_totals()` surface (pre-observability code
         reported gauges through it as signed counter increments, and its tests
-        assert e.g. `totals['cache.bytes_resident'] == 0`)."""
-        out = self._flat("counter")
+        assert e.g. `totals['cache.bytes_resident'] == 0`) — plus the span
+        view of `_span_counters`."""
+        out = self._counters()
         out.update(self._flat("gauge"))
         return out
 
@@ -334,7 +366,7 @@ class MetricsRegistry:
         """JSON-serializable full state: the payload barrier workers ship to
         the driver and the `metrics` section of a fit report."""
         return {
-            "counters": self._flat("counter"),
+            "counters": self._counters(),
             "gauges": self._flat("gauge"),
             "histograms": self._flat("histogram"),
             "spans": self.span_totals(),
@@ -343,9 +375,14 @@ class MetricsRegistry:
     def merge_snapshot(self, snap: Mapping[str, Any]) -> None:
         """Fold another registry's snapshot into this one: counters, gauges and
         span totals ADD (a merged gauge is a sum over workers — total bytes
-        resident across the pod); histograms merge count/sum/bucket-wise."""
+        resident across the pod); histograms merge count/sum/bucket-wise. The
+        snapshot's `span.seconds` / `span.calls` are a view of its spans and
+        histograms, which merge below: stored as counters they would count
+        every span twice."""
         for key, v in (snap.get("counters") or {}).items():
             name, labels = split_label_key(key)
+            if name in (SPAN_SECONDS, SPAN_CALLS):
+                continue
             self.counter(name).inc(v, **labels)
         for key, v in (snap.get("gauges") or {}).items():
             name, labels = split_label_key(key)

@@ -31,6 +31,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import os
+import sys
 import threading
 import time
 import uuid
@@ -273,6 +274,31 @@ def convergence(algo: str, iteration: Any, **fields: Any) -> None:
 
 # ----------------------------------------------------------------- trace spans
 
+# jax.profiler, resolved lazily and once: False = not yet resolved, None =
+# unavailable (never retried). Resolution waits until something else has
+# imported jax, so this module keeps importing (and spans keep working)
+# without it.
+_jax_profiler: Any = False
+
+
+def _trace_annotation(name: str) -> Any:
+    """The `jax.profiler.TraceAnnotation` that puts a span on the profiler's
+    clock — the ONE construction site in the package. Outside a profiler
+    session the annotation is a no-op costing well under a microsecond."""
+    global _jax_profiler
+    jp = _jax_profiler
+    if jp is False:
+        if "jax" not in sys.modules:
+            return contextlib.nullcontext()
+        try:
+            import jax.profiler as jp
+        except Exception:  # pragma: no cover — jax is a hard dep everywhere else
+            jp = None
+        _jax_profiler = jp
+    if jp is None:
+        return contextlib.nullcontext()
+    return jp.TraceAnnotation(name)
+
 
 class SpanNode:
     """One node of a run's trace tree. Identity is process-unique so nodes from
@@ -310,11 +336,13 @@ class SpanNode:
 
 @contextlib.contextmanager
 def span(name: str, attrs: Optional[Mapping[str, Any]] = None) -> Iterator[SpanNode]:
-    """Cheap structured span: perf_counter + thread-local parent linkage, no
-    jax import anywhere near it. Failure-safe by construction (try/finally):
-    a span whose body raises records its elapsed time with status='error' and
-    counts toward `span.errors` — the exact timing the old profiling.span()
-    dropped on the floor when a pass failed."""
+    """The span primitive: perf_counter + thread-local parent linkage for the
+    run's trace tree, span totals and latency histogram, and a same-named
+    `TraceAnnotation` around the body so every span also lands in any active
+    profiler session, on the device trace's clock (no jax import of its own:
+    see _trace_annotation). Failure-safe by construction (try/finally): a span
+    whose body raises records its elapsed time with status='error' and counts
+    toward `span.errors`."""
     node = SpanNode(name, attrs, parent_id=(
         _span_stack()[-1].span_id if _span_stack() else None
     ))
@@ -328,7 +356,8 @@ def span(name: str, attrs: Optional[Mapping[str, Any]] = None) -> Iterator[SpanN
         run.note_span_open(node)
     _flight().note_span_open(node)
     try:
-        yield node
+        with _trace_annotation(name):
+            yield node
     except BaseException:
         node.status = "error"
         raise

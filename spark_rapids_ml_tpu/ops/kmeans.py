@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
 from ._precision import FAST, pdot
 from .selection import top_k_max
@@ -313,28 +314,36 @@ def kmeans_init(
 
     init == "random": k distinct real rows.
     init == "k-means||" (or "scalable-k-means++"): Gumbel-top-k oversampling rounds,
-    then weighted k-means++ on the ~(1 + steps·2k) candidates."""
+    then weighted k-means++ on the ~(1 + steps·2k) candidates.
+
+    The caller holds the span `kmeans.init` (kmeans_fit, ops/streaming.py);
+    the three phases of the k-means|| start, each ending in a read back to the
+    host, are its children here."""
     key = jax.random.PRNGKey(seed & 0x7FFFFFFF)
     if init == "random":
         return np.asarray(_random_real_rows(X, w, k, key))
 
     rng = np.random.default_rng(seed & 0x7FFFFFFF)
-    n_real = int(jnp.sum(w > 0))
-    l = max(2, min(2 * k, n_real))  # never oversample past the real rows (padding)
-    key, sub = jax.random.split(key)
-    first = _random_real_rows(X, w, 1, sub)[0]
-    key, sub = jax.random.split(key)
-    candidates = np.asarray(
-        _oversample_rounds(X, w, first, sub, l, max(init_steps, 1))
-    )
-    # weight candidates by how many points they attract (one cheap pass)
-    assign = np.asarray(kmeans_predict(X, jnp.asarray(candidates)))
-    wh = np.asarray(w)
-    weights = np.bincount(assign, weights=wh, minlength=candidates.shape[0]).astype(
-        candidates.dtype
-    )
-    weights = np.maximum(weights, 1e-12)
-    return _weighted_kmeans_pp(candidates, weights, k, rng)
+    with span("kmeans.init.oversample"):
+        n_real = int(jnp.sum(w > 0))
+        l = max(2, min(2 * k, n_real))  # never oversample past the real rows (padding)
+        key, sub = jax.random.split(key)
+        first = _random_real_rows(X, w, 1, sub)[0]
+        key, sub = jax.random.split(key)
+        candidates = np.asarray(
+            _oversample_rounds(X, w, first, sub, l, max(init_steps, 1))
+        )
+    with span("kmeans.init.weigh"):
+        # weight candidates by how many points they attract (one cheap pass)
+        assign = np.asarray(kmeans_predict(X, jnp.asarray(candidates)))
+        wh = np.asarray(w)
+        counter_inc("d2h.bytes", int(assign.nbytes + wh.nbytes), site="fit")
+        weights = np.bincount(
+            assign, weights=wh, minlength=candidates.shape[0]
+        ).astype(candidates.dtype)
+        weights = np.maximum(weights, 1e-12)
+    with span("kmeans.init.pp"):
+        return _weighted_kmeans_pp(candidates, weights, k, rng)
 
 
 def kmeans_fit(
@@ -360,7 +369,24 @@ def kmeans_fit(
                 "contains an all-zero feature row."
             )
         X = _normalize_rows(X)  # spherical kmeans operates on the unit sphere
-    init_centers = jnp.asarray(kmeans_init(X, w, k, init, init_steps, seed))
+    with span("kmeans.init"):
+        init_centers = jnp.asarray(kmeans_init(X, w, k, init, init_steps, seed))
+    with span("kmeans.lloyd"):
+        return _lloyd(X, w, init_centers, k, max_iter, tol, cosine, unit_weight)
+
+
+def _lloyd(
+    X: jax.Array,
+    w: jax.Array,
+    init_centers: jax.Array,
+    k: int,
+    max_iter: int,
+    tol: float,
+    cosine: bool,
+    unit_weight: bool,
+) -> Dict[str, object]:
+    """Route to the fused pallas or the XLA Lloyd program, run it and fetch
+    centres, inertia and `n_iter` (the span `kmeans.lloyd` of kmeans_fit)."""
     from .. import config as _config
 
     # Fused pallas Lloyd routing (SRML_TPU_PALLAS_KMEANS). Steady-state TPU

@@ -35,16 +35,35 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .. import config as _config
+from .. import observability as _obs
 from .mesh import DATA_AXIS, FEATURE_AXIS, get_mesh
 
 ROW_MULTIPLE = 8  # float32 sublane tile; keeps per-device shards MXU-friendly
+
+
+def _put(site: Optional[str], x: Any, place: Callable[[], jax.Array]) -> jax.Array:
+    """The one choke point of host->device placement. With a `site` ("fit",
+    "transform") the DISPATCH of the transfer is the span `h2d.put` (attrs
+    `bytes`, `site`) and `h2d.bytes{site=}` counts the `nbytes` of what is put;
+    the transfer itself is asynchronous and is waited for under `h2d.wait` by
+    whoever needs the array resident (core/estimator.py, observability/
+    inference.py). Without a site the placement is neither timed nor counted:
+    the streamed tier accounts for its batches itself (`stream.ingest`,
+    `stream.upload_bytes`), and nothing is counted twice."""
+    if site is None:
+        return place()
+    nbytes = int(getattr(x, "nbytes", 0))
+    with _obs.span("h2d.put", {"site": site, "bytes": nbytes}):
+        out = place()
+    _obs.counter_inc("h2d.bytes", nbytes, site=site)
+    return out
 
 
 class Partitioner:
@@ -111,22 +130,30 @@ class Partitioner:
 
     # ------------------------------------------------------------ placement
 
-    def shard(self, x: Any) -> jax.Array:
+    def shard(self, x: Any, site: Optional[str] = None) -> jax.Array:
         """Place a host array on the mesh with rows on the data axis
-        (single-process; for multi-process staging use `shard_inputs`)."""
-        return jax.device_put(x, self.data_sharding(np.ndim(x)))
+        (single-process; for multi-process staging use `shard_inputs`).
+        `site`: see `_put`."""
+        sharding = self.data_sharding(np.ndim(x))
+        return _put(site, x, lambda: jax.device_put(x, sharding))
 
     def replicate(self, x: Any) -> jax.Array:
         return jax.device_put(x, self.state_sharding())
 
-    def put_local(self, x: Any) -> jax.Array:
+    def put_local(self, x: Any, site: Optional[str] = None,
+                  device: Any = None) -> jax.Array:
         """Default-device placement for host-resident block scans that never
-        enter the SPMD program (the pairwise streaming device blocks)."""
+        enter the SPMD program (the pairwise streaming device blocks) and for
+        a transform's host operands (observability/inference.py), which go to
+        `device` where the weights they meet are committed to one."""
         import jax.numpy as jnp
 
-        return jax.device_put(jnp.asarray(x))
+        if device is not None:
+            return _put(site, x, lambda: jax.device_put(x, device))
+        return _put(site, x, lambda: jax.device_put(jnp.asarray(x)))
 
-    def shard_inputs(self, *local_arrays: Optional[np.ndarray]) -> List[Optional[jax.Array]]:
+    def shard_inputs(self, *local_arrays: Optional[np.ndarray],
+                     site: Optional[str] = None) -> List[Optional[jax.Array]]:
         """Assemble global row-sharded arrays from per-process LOCAL rows.
 
         Always via `jax.make_array_from_process_local_data`: each process
@@ -142,7 +169,9 @@ class Partitioner:
                 out.append(None)
                 continue
             sh = self.data_sharding(np.ndim(a))
-            out.append(jax.make_array_from_process_local_data(sh, a))
+            # called before the loop moves on, so the closure's late binding is safe
+            out.append(_put(
+                site, a, lambda: jax.make_array_from_process_local_data(sh, a)))
         return out
 
     # ------------------------------------------------------------ staging
@@ -235,10 +264,11 @@ class SPMDPartitioner(Partitioner):
     def feature_sharding(self, ndim: int = 2) -> NamedSharding:
         return NamedSharding(self.mesh, self.feature_spec(ndim))
 
-    def shard_features(self, x: Any) -> jax.Array:
+    def shard_features(self, x: Any, site: Optional[str] = None) -> jax.Array:
         """Place with rows on data AND columns on feature — the wide-k kNN /
         feature-sharded covariance layout."""
-        return jax.device_put(x, self.feature_sharding(np.ndim(x)))
+        sharding = self.feature_sharding(np.ndim(x))
+        return _put(site, x, lambda: jax.device_put(x, sharding))
 
 
 # --------------------------------------------------------------- active mgmt
@@ -341,9 +371,11 @@ def replicate_rows(x: Any, mesh: Optional[Mesh] = None) -> jax.Array:
     return partitioner_for(mesh).replicate(x)
 
 
-def put_device_local(x: Any) -> jax.Array:
-    """Default-device placement (host-resident pairwise block scans)."""
-    return active_partitioner().put_local(x)
+def put_device_local(x: Any, site: Optional[str] = None,
+                     device: Any = None) -> jax.Array:
+    """Default-device placement (host-resident pairwise block scans, a
+    transform's host operands)."""
+    return active_partitioner().put_local(x, site=site, device=device)
 
 
 # --------------------------------------------------------------- knobs

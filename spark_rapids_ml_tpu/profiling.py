@@ -8,15 +8,13 @@
 # test. New instrumentation should import `spark_rapids_ml_tpu.observability`
 # directly (Counter/Gauge/Histogram with labels, structured spans, events).
 #
-# Behavior fixes that ride the migration:
-#   * span() records its timing even when the body RAISES (try/finally; the old
-#     implementation updated the totals after the `with TraceAnnotation` block,
-#     so a failed pass — exactly when the timing matters — recorded nothing).
-#     A failed span lands with status=error in the run trace and increments the
-#     `span.errors` counter.
-#   * jax.profiler resolves ONCE through a module-level lazy cache instead of
-#     per call — span() is now cheap enough for per-batch paths (add_time()'s
-#     old excuse for existing).
+# There is ONE span primitive, `observability.span`: trace-tree node, span
+# totals, latency histogram, failure-safe timing (a span whose body raises
+# still records, with status=error and a `span.errors` count) and the
+# `jax.profiler.TraceAnnotation` that puts the span on the profiler's clock.
+# `profiling.span` is that primitive plus an optional log line; nothing here
+# times or annotates anything itself. `counter_totals()` also carries every
+# span's `span.seconds{span=}` / `span.calls{span=}` (observability/registry.py).
 #
 # Enable xplane capture with SRML_TPU_TRACE_DIR=/path (see config.py): every
 # fit is then traced automatically.
@@ -25,7 +23,6 @@
 from __future__ import annotations
 
 import contextlib
-import time as _time
 from typing import Dict, Iterator, Optional
 
 from . import observability as _obs
@@ -33,41 +30,22 @@ from .utils import get_logger
 
 _logger = get_logger("profiling")
 
-# lazy once-per-process jax.profiler resolution: False = not yet resolved,
-# None = unavailable (never retried), module otherwise
-_jax_profiler = False
-
-
-def _get_jax_profiler():
-    global _jax_profiler
-    if _jax_profiler is False:
-        try:
-            import jax.profiler as jp
-        except Exception:  # pragma: no cover — jax is a hard dep everywhere else
-            jp = None
-        _jax_profiler = jp
-    return _jax_profiler
-
 
 @contextlib.contextmanager
 def span(name: str, verbose: bool = False) -> Iterator[None]:
-    """Wall-clock + device-timeline span: the observability structured span
-    (trace-tree node + span totals + latency histogram) nested inside a
-    jax.profiler.TraceAnnotation so it still shows on xplane timelines."""
-    jp = _get_jax_profiler()
-    annotation = jp.TraceAnnotation(name) if jp is not None else contextlib.nullcontext()
-    with _obs.span(name):
-        t0 = _time.perf_counter()
-        try:
-            with annotation:
-                yield
-        finally:
-            if verbose:
-                _logger.info("%s: %.3fs", name, _time.perf_counter() - t0)
+    """`observability.span(name)`, plus one log line with the span's seconds
+    when `verbose`."""
+    node = None
+    try:
+        with _obs.span(name) as node:
+            yield
+    finally:
+        if verbose and node is not None:
+            _logger.info("%s: %.3fs", name, node.duration_s)
 
 
 def add_time(name: str, seconds: float) -> None:
-    """Accumulate seconds under a span name WITHOUT the TraceAnnotation or
+    """Accumulate seconds under a span name WITHOUT the profiler annotation or
     trace-node machinery — the per-batch fallback for call sites that already
     timed themselves. Also feeds the same-named latency histogram, so every
     add_time site gains a per-batch distribution for free."""

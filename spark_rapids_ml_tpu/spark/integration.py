@@ -314,7 +314,7 @@ def _barrier_task_body(est, ctx, rank, n_tasks, pdf_iter, init_process_group,
         v_local[: fd.n_rows, : ell_vals.shape[1]] = ell_vals
         i_local[: fd.n_rows, : ell_idx.shape[1]] = ell_idx
         w_global, label_global, values_global, indices_global = part.shard_inputs(
-            w_local, label_local, v_local, i_local
+            w_local, label_local, v_local, i_local, site="fit"
         )
         fit_inputs = est._build_sparse_fit_inputs_from_global(
             values_global, indices_global, w_global, label_global, total_rows,
@@ -327,7 +327,7 @@ def _barrier_task_body(est, ctx, rank, n_tasks, pdf_iter, init_process_group,
         X_local = np.zeros((pad_to, fd.n_cols), np.float32)
         X_local[: fd.n_rows] = np.asarray(fd.features, dtype=np.float32)
         w_global, label_global, X_global = part.shard_inputs(
-            w_local, label_local, X_local
+            w_local, label_local, X_local, site="fit"
         )
         fit_inputs = est._build_fit_inputs_from_global(
             X_global, w_global, label_global, total_rows, mesh,
