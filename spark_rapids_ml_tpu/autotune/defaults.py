@@ -54,6 +54,20 @@ MIN_ITEM_TILE = 128
 FUSED_ASSIGN_MIN_K = 128
 LLOYD_FUSED_MIN_K = 128
 
+# Rows per centre (ops/kmeans.py::assign_counts). NOT tunables, no knob reads
+# them. Up to COUNT_DEVICE_MAX_CENTERS centres the device compares every row
+# with every centre id and sums the hits in one fusion: work of n x centres,
+# 1.5 ms at 81 centres and 75 ms at 8,193 for 8.4M rows on a v5e, where
+# fetching the labels for np.bincount costs 0.09 s (0.19 s weighted) at any
+# count; above it (an IVF build of nlist > 2,048) the host's way is the faster
+# one and stays (tools/kmeans_count_bench.py; PERF.md §6, PR 27). Both sides
+# grow with n alike, so the boundary is a centre count. COUNT_BLOCK_SPLIT:
+# float32 weights are summed within this many row blocks a row shard and the
+# blocks added in float64 on the host; sums of 0/1 weights stay exact while a
+# block holds at most 2**24 rows.
+COUNT_DEVICE_MAX_CENTERS = 8192
+COUNT_BLOCK_SPLIT = 64
+
 # k <= this is where `auto` may hand a top-k scan to the fused running-pool
 # kernel. NOT a tunable: it is the largest k Mosaic compiled on a v5e (PR 21,
 # jax 0.9.0 / libtpu 0.0.34; k=10 and k=32 agree with XLA and numpy). The

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.dataset import densify
@@ -29,7 +30,8 @@ from ..core.params import (
     Param,
     TypeConverters,
 )
-from ..ops.kmeans import kmeans_fit, kmeans_predict
+from ..observability import span
+from ..ops.kmeans import assign_counts, kmeans_fit, kmeans_predict
 
 
 class _KMeansClass(_TpuClass):
@@ -211,23 +213,13 @@ class KMeans(_KMeansClass, _TpuEstimator, _KMeansParams):
                 # the op directly never pay it. Counts ALL real rows (padding is
                 # positional: rows beyond desc.m), including user weight-0 rows,
                 # matching Spark's groupBy(prediction).count().
-                import jax.numpy as _jnp
-
-                from ..observability import counter_inc, span
-                from ..ops.kmeans import kmeans_predict
-
                 with span("kmeans.summary"):
-                    assign = np.asarray(
-                        kmeans_predict(
-                            inputs.features,
-                            _jnp.asarray(res["cluster_centers"]),
-                            cosine=str(p.get("metric", "euclidean")) == "cosine",
-                        )
+                    res["cluster_sizes"] = assign_counts(
+                        inputs.features,
+                        jnp.asarray(res["cluster_centers"]),
+                        inputs.desc.m,
+                        cosine=str(p.get("metric", "euclidean")) == "cosine",
                     )
-                    counter_inc("d2h.bytes", int(assign.nbytes), site="fit")
-                    res["cluster_sizes"] = np.bincount(
-                        assign[: inputs.desc.m], minlength=int(p["n_clusters"])
-                    ).astype(np.int64)
                 results.append(res)
             return results if extra_params is not None else results[0]
 

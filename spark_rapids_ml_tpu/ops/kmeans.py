@@ -24,12 +24,14 @@
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..autotune.defaults import COUNT_BLOCK_SPLIT, COUNT_DEVICE_MAX_CENTERS
 from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
 from ._precision import FAST, pdot
@@ -156,6 +158,97 @@ def kmeans_predict(
 
         _obs.counter_inc("kmeans.assign_path", 1, path="xla")
     return _kmeans_predict_xla(X, centers, cosine)
+
+
+# Per-centre counts of an assignment, reduced on the device so that only the
+# (n_centers,) result crosses to the host: fetching the n labels (and the n
+# weights) for a host np.bincount was a quarter of an in-core fit.
+def _count_rows(
+    labels: jax.Array, w: jax.Array, n_centers: int, blocks: int
+) -> jax.Array:
+    """Reduce (n,) labels to per-centre counts, traced inside a kernel.
+
+    `blocks` 0: the rows with a non-zero weight are counted in int32,
+    (n_centers,): exact at any row count, where a float32 sum stops at 2**24.
+    `w` is the (n,) row weights, or a scalar row count m (rows at positions
+    >= m are padding). Otherwise the float32 weights are summed within each of
+    `blocks` row blocks, (blocks, n_centers), for the caller to add in float64."""
+    ids = jnp.arange(n_centers, dtype=labels.dtype)
+    if blocks:
+        hit = labels.reshape(blocks, -1, 1) == ids
+        return jnp.sum(jnp.where(hit, w.reshape(blocks, -1, 1), 0), axis=1)
+    real = jnp.arange(labels.shape[0]) < w if w.ndim == 0 else w > 0
+    hit = (labels[:, None] == ids) & real[:, None]
+    return jnp.sum(hit, axis=0, dtype=jnp.int32)
+
+
+@compiled_kernel("kmeans.assign_counts", static_argnames=("cosine", "blocks"))
+def _assign_counts_xla(
+    X: jax.Array, centers: jax.Array, w: jax.Array, cosine: bool = False,
+    blocks: int = 0,
+) -> jax.Array:
+    """`_kmeans_predict_xla`'s labels (the same expression, so the same
+    labels) reduced to per-centre counts in the program that computes them."""
+    labels = _kmeans_predict_xla.__wrapped__(X, centers, cosine)
+    return _count_rows(labels, w, centers.shape[0], blocks)
+
+
+@compiled_kernel("kmeans.label_counts", static_argnames=("n_centers", "blocks"))
+def _label_counts(
+    labels: jax.Array, w: jax.Array, n_centers: int, blocks: int = 0
+) -> jax.Array:
+    """The reduction alone, for labels another kernel wrote (the fused pallas
+    assignment, which kmeans_predict routes to at 128 centres and more on TPU)."""
+    return _count_rows(labels, w, n_centers, blocks)
+
+
+def assign_counts(
+    X: jax.Array, centers: jax.Array, w: jax.Array, cosine: bool = False,
+    exact: bool = False,
+) -> np.ndarray:
+    """Per-centre sum of `w` over the rows nearest each centre, on the host:
+    what `np.bincount(kmeans_predict(X, centers), weights=w)` gives, without
+    fetching anything of n rows. `w`: (n,) row weights, or a scalar row count
+    m (rows at positions >= m are padding; implies `exact`). `exact` (0/1
+    weights) returns int64 counts, bit-equal to the host's; otherwise float64
+    sums within 1e-6 of the float64 bincount. The assignment takes
+    kmeans_predict's route, so the labels are the ones it would return.
+    `kmeans.count_path{path=device|host}` says where the rows were counted."""
+    from ..parallel.partitioner import mesh_of, replicate_rows
+    from . import pallas_select as _ps
+
+    n, d = X.shape
+    n_centers = int(centers.shape[0])
+    w = jnp.asarray(w)
+    exact = exact or w.ndim == 0
+    if n_centers > COUNT_DEVICE_MAX_CENTERS:
+        counter_inc("kmeans.count_path", 1, path="host")
+        labels = np.asarray(kmeans_predict(X, centers, cosine))
+        fetched = labels.nbytes
+        if w.ndim == 0:
+            out = np.bincount(labels[: int(w)], minlength=n_centers)
+        else:
+            wh = np.asarray(w)
+            fetched += wh.nbytes
+            out = np.bincount(labels, weights=wh, minlength=n_centers)
+        counter_inc("d2h.bytes", int(fetched), site="fit")
+        return out.astype(np.int64) if exact else out
+    counter_inc("kmeans.count_path", 1, path="device")
+    blocks = 0
+    if not exact:
+        blocks = n // X.sharding.shard_shape(X.shape)[0]  # row shards
+        blocks *= math.gcd(n // blocks, COUNT_BLOCK_SPLIT)
+    if not cosine and _ps.use_fused_assign(n_centers, d):
+        out = _label_counts(kmeans_predict(X, centers), w, n_centers, blocks)
+    else:
+        counter_inc("kmeans.assign_path", 1, path="xla")
+        out = _assign_counts_xla(X, centers, w, cosine, blocks)
+    if blocks and mesh_of(X) is not None:
+        # a count is reduced over the row shards already; the block sums are not
+        out = replicate_rows(out, mesh_of(X))
+    out = np.asarray(out)
+    counter_inc("d2h.bytes", int(out.nbytes), site="fit")
+    return out.astype(np.int64) if exact else out.sum(axis=0, dtype=np.float64)
 
 
 @compiled_kernel("kmeans.inertia")
@@ -309,8 +402,10 @@ def kmeans_init(
     init: str,
     init_steps: int,
     seed: int,
+    unit_weight: bool = False,
 ) -> np.ndarray:
-    """Compute initial centers (host-side result).
+    """Compute initial centers (host-side result). `unit_weight`: `w` holds
+    only 0 and 1 (FitInputs.unit_weight), so the candidates' weights are counts.
 
     init == "random": k distinct real rows.
     init == "k-means||" (or "scalable-k-means++"): Gumbel-top-k oversampling rounds,
@@ -335,11 +430,8 @@ def kmeans_init(
         )
     with span("kmeans.init.weigh"):
         # weight candidates by how many points they attract (one cheap pass)
-        assign = np.asarray(kmeans_predict(X, jnp.asarray(candidates)))
-        wh = np.asarray(w)
-        counter_inc("d2h.bytes", int(assign.nbytes + wh.nbytes), site="fit")
-        weights = np.bincount(
-            assign, weights=wh, minlength=candidates.shape[0]
+        weights = assign_counts(
+            X, jnp.asarray(candidates), w, exact=unit_weight
         ).astype(candidates.dtype)
         weights = np.maximum(weights, 1e-12)
     with span("kmeans.init.pp"):
@@ -370,7 +462,9 @@ def kmeans_fit(
             )
         X = _normalize_rows(X)  # spherical kmeans operates on the unit sphere
     with span("kmeans.init"):
-        init_centers = jnp.asarray(kmeans_init(X, w, k, init, init_steps, seed))
+        init_centers = jnp.asarray(
+            kmeans_init(X, w, k, init, init_steps, seed, unit_weight)
+        )
     with span("kmeans.lloyd"):
         return _lloyd(X, w, init_centers, k, max_iter, tol, cosine, unit_weight)
 

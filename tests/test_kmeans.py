@@ -183,3 +183,150 @@ def test_kmeans_training_summary(n_devices):
         assert not m2.hasSummary
         with pytest.raises(RuntimeError):
             _ = m2.summary
+
+
+# ------------------------------------------------ rows per centre, on the device
+
+
+def _placed(n_shards, *arrays):
+    """Row-shard host arrays over `n_shards` virtual devices (1: one device)."""
+    from spark_rapids_ml_tpu.parallel.partitioner import active_partitioner
+
+    import jax.numpy as jnp
+
+    if n_shards == 1:
+        return [jnp.asarray(a) for a in arrays]
+    part = active_partitioner(n_shards)
+    return [part.shard(a) for a in arrays]
+
+
+_ABOVE_BOUNDARY = 8193  # over ops/kmeans.py's COUNT_DEVICE_MAX_CENTERS
+
+
+@pytest.mark.parametrize("n_shards", [1, 8], ids=["one_device", "eight_devices"])
+@pytest.mark.parametrize(
+    "n_centers,cosine,selection",
+    [(2, False, None), (20, False, None), (81, False, None),
+     (_ABOVE_BOUNDARY, False, None), (20, True, None), (20, False, "pallas_fused")],
+    ids=["k2", "k20", "k81", "above_boundary", "cosine", "pallas_labels"],
+)
+@pytest.mark.parametrize(
+    "weights", ["unit", "zeros_in_the_middle", "padding_beyond_m", "random_float32"]
+)
+def test_assign_counts_equal_the_hosts_bincount(weights, n_centers, cosine, selection,
+                                               n_shards, n_devices):
+    """What `kmeans.init.weigh` and `kmeans.summary` read back: the per-centre
+    count of kmeans_predict's own labels, bit for bit for 0/1 weights and to
+    1e-6 of the float64 bincount for float32 weights."""
+    from spark_rapids_ml_tpu import config
+    from spark_rapids_ml_tpu.profiling import counter_totals
+    from spark_rapids_ml_tpu.ops import kmeans as K
+
+    assert K.COUNT_DEVICE_MAX_CENTERS < _ABOVE_BOUNDARY
+    n_shards = min(n_shards, n_devices)
+    rng = np.random.default_rng(n_centers)
+    n = 2048
+    X = (rng.normal(size=(n, 8)) + rng.integers(0, 4, (n, 1))).astype(np.float32)
+    C = (rng.normal(size=(n_centers, 8)) + rng.integers(0, 4, (n_centers, 1))).astype(np.float32)
+    m = n - 37
+    w = {
+        "unit": np.r_[np.ones(m), np.zeros(n - m)],
+        "zeros_in_the_middle": (rng.random(n) > 0.3).astype(np.float64),
+        "padding_beyond_m": np.r_[np.ones(m), np.zeros(n - m)],
+        "random_float32": rng.uniform(0.1, 3.0, n),
+    }[weights].astype(np.float32)
+    Xj, wj = _placed(n_shards, X, w)
+    Cj = _placed(1, C)[0]
+    if selection:
+        config.set("knn.selection", selection)
+    try:
+        labels = np.asarray(K.kmeans_predict(Xj, Cj, cosine))
+        before = dict(counter_totals())
+        if weights == "padding_beyond_m":
+            got = K.assign_counts(Xj, Cj, m, cosine)
+        else:
+            got = K.assign_counts(Xj, Cj, wj, cosine, exact=weights == "unit")
+        after = counter_totals()
+    finally:
+        if selection:
+            config.unset("knn.selection")
+    want = np.bincount(labels, weights=w.astype(np.float64), minlength=n_centers)
+    on_host = n_centers > K.COUNT_DEVICE_MAX_CENTERS
+    path = "kmeans.count_path{path=%s}" % ("host" if on_host else "device")
+    assert after.get(path, 0) - before.get(path, 0) == 1
+    if weights == "random_float32":
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+    else:
+        assert got.dtype == (np.float64 if weights == "zeros_in_the_middle" else np.int64)
+        np.testing.assert_array_equal(got, want)
+    fetched = after.get("d2h.bytes{site=fit}", 0) - before.get("d2h.bytes{site=fit}", 0)
+    if on_host:  # the labels, and the weights where there are any
+        assert fetched == (1 if weights == "padding_beyond_m" else 2) * n * 4
+    else:
+        assert fetched <= 64 * n_shards * n_centers * 4
+
+
+@pytest.mark.parametrize("n_centers", [3, 9])
+def test_label_counts_are_exact_beyond_float32s_integers(n_centers):
+    """The reduction alone, labels in and counts out: a centre that holds more
+    than 2**24 rows is counted to the row, which a float32 sum cannot do."""
+    import jax.numpy as jnp
+
+    from spark_rapids_ml_tpu.ops.kmeans import _label_counts
+
+    n = (1 << 24) + 40_001
+    labels = np.full((n,), 1, np.int32)
+    labels[::1024] = n_centers - 1
+    labels[5::4096] = 0
+    m = n - 11
+    want = np.bincount(labels[:m], minlength=n_centers)
+    if want[1] % 2 == 0:  # an odd count over 2**24 is no float32
+        m -= 1
+        want[labels[m]] -= 1
+    assert want[1] > 1 << 24 and int(np.float32(want[1])) != want[1]
+    got = np.asarray(_label_counts(jnp.asarray(labels), jnp.asarray(m), n_centers))
+    np.testing.assert_array_equal(got, want)
+
+
+# (num_workers, distanceMeasure, weighted) -> first column of cluster_centers_,
+# inertia, summary.clusterSizes of the parent tree (ecda1c3, CPU): the k-means||
+# start draws from the candidates' weights, so counting them any other way than
+# the host's bincount moves every line of this table
+_PARENT_FIT = {
+    (1, "euclidean", False): ([-3.31988, 9.29116, 8.28601, -8.46024, 0.14415],
+                              89777.59375, [200, 183, 212, 202, 206]),
+    (1, "cosine", False): ([-0.22988, 0.00588, 0.51235, -0.57987, 0.6991],
+                           157.6925048828125, [202, 194, 229, 203, 175]),
+    (1, "euclidean", True): ([0.27204, -8.45971, 8.41616, -3.2578, 9.5809],
+                             137407.296875, [210, 202, 210, 201, 180]),
+    (8, "euclidean", False): ([-3.31988, 9.29116, 8.28601, -8.46024, 0.14415],
+                              89777.609375, [200, 183, 212, 202, 206]),
+    (8, "cosine", False): ([-0.22988, 0.00588, 0.51235, -0.57987, 0.6991],
+                           157.6925048828125, [202, 194, 229, 203, 175]),
+    (8, "euclidean", True): ([0.27204, -8.45971, 8.41616, -3.25781, 9.5809],
+                             137407.28125, [210, 202, 210, 201, 180]),
+}
+
+
+@pytest.mark.parametrize("num_workers,metric,weighted", list(_PARENT_FIT))
+def test_seeded_fit_is_the_parents_fit(num_workers, metric, weighted, n_devices):
+    if num_workers > n_devices:
+        pytest.skip(f"needs {num_workers} virtual devices")
+    X, _ = make_blobs(n_samples=1003, n_features=6, centers=5, cluster_std=4.0,
+                      random_state=7)
+    X = X.astype(np.float32)
+    params = dict(k=5, seed=11, maxIter=4, tol=0.0, num_workers=num_workers,
+                  distanceMeasure=metric)
+    if weighted:
+        w = np.random.default_rng(3).uniform(0.1, 3.0, size=len(X)).astype(np.float32)
+        model = KMeans(weightCol="w", **params).fit(
+            pd.DataFrame({"features": list(X), "w": w}))
+    else:
+        model = KMeans(**params).fit(X)
+    first_column, inertia, sizes = _PARENT_FIT[(num_workers, metric, weighted)]
+    np.testing.assert_allclose(model.cluster_centers_[:, 0], first_column, atol=2e-5)
+    assert model._model_attributes["inertia"] == pytest.approx(inertia, rel=1e-6)
+    assert list(model.summary.clusterSizes) == sizes
+    counters = model.fit_report_["metrics"]["counters"]
+    assert counters["kmeans.count_path{path=device}"] == 2
+    assert "kmeans.count_path{path=host}" not in counters

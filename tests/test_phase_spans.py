@@ -177,13 +177,14 @@ def test_h2d_bytes_are_the_bytes_put(n_devices, family):
 
 
 def test_kmeans_fit_counts_what_it_reads_back(n_devices):
-    from spark_rapids_ml_tpu.parallel.partition import pad_rows
-
     X = _table(rows=1003)
-    model = _estimator("kmeans").fit(X)
-    padded = pad_rows(X, n_devices)[0].shape[0]
-    # the start's labels and row weights, then the summary's labels
-    assert model.fit_report_["metrics"]["counters"]["d2h.bytes{site=fit}"] == 3 * padded * 4
+    est = _estimator("kmeans")
+    model = est.fit(X)
+    k, steps = est.getOrDefault("k"), est.getOrDefault("initSteps")
+    # int32 counts, reduced on the device: the weights of the start's
+    # 1 + steps * 2k candidates, then the summary's k cluster sizes
+    assert model.fit_report_["metrics"]["counters"]["d2h.bytes{site=fit}"] == (
+        (1 + steps * 2 * k) * 4 + k * 4)
     assert sum(model.summary.clusterSizes) == len(X)
 
 
