@@ -30,7 +30,7 @@ from ..core.params import (
     Param,
     TypeConverters,
 )
-from ..observability import span
+from ..observability import counter_inc, span
 from ..ops.kmeans import assign_counts, kmeans_fit, kmeans_predict
 
 
@@ -214,6 +214,11 @@ class KMeans(_KMeansClass, _TpuEstimator, _KMeansParams):
                 # positional: rows beyond desc.m), including user weight-0 rows,
                 # matching Spark's groupBy(prediction).count().
                 with span("kmeans.summary"):
+                    # the fetched centres go up again for the summary's pass
+                    counter_inc(
+                        "h2d.bytes", int(res["cluster_centers"].nbytes),
+                        site="fit.centers",
+                    )
                     res["cluster_sizes"] = assign_counts(
                         inputs.features,
                         jnp.asarray(res["cluster_centers"]),

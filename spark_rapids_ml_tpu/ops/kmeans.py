@@ -412,11 +412,16 @@ def kmeans_init(
     then weighted k-means++ on the ~(1 + steps·2k) candidates.
 
     The caller holds the span `kmeans.init` (kmeans_fit, ops/streaming.py);
-    the three phases of the k-means|| start, each ending in a read back to the
-    host, are its children here."""
+    its children here: `kmeans.init.random`, or the three phases of the
+    k-means|| start, each ending in a read back to the host. The rows of
+    centre shape that come back (the k picked rows, or the candidates) are
+    counted as `d2h.bytes{site=fit.centers}`."""
     key = jax.random.PRNGKey(seed & 0x7FFFFFFF)
     if init == "random":
-        return np.asarray(_random_real_rows(X, w, k, key))
+        with span("kmeans.init.random"):
+            centers = np.asarray(_random_real_rows(X, w, k, key))
+            counter_inc("d2h.bytes", int(centers.nbytes), site="fit.centers")
+            return centers
 
     rng = np.random.default_rng(seed & 0x7FFFFFFF)
     with span("kmeans.init.oversample"):
@@ -428,8 +433,10 @@ def kmeans_init(
         candidates = np.asarray(
             _oversample_rounds(X, w, first, sub, l, max(init_steps, 1))
         )
+        counter_inc("d2h.bytes", int(candidates.nbytes), site="fit.centers")
     with span("kmeans.init.weigh"):
         # weight candidates by how many points they attract (one cheap pass)
+        counter_inc("h2d.bytes", int(candidates.nbytes), site="fit.centers")
         weights = assign_counts(
             X, jnp.asarray(candidates), w, exact=unit_weight
         ).astype(candidates.dtype)
@@ -462,9 +469,9 @@ def kmeans_fit(
             )
         X = _normalize_rows(X)  # spherical kmeans operates on the unit sphere
     with span("kmeans.init"):
-        init_centers = jnp.asarray(
-            kmeans_init(X, w, k, init, init_steps, seed, unit_weight)
-        )
+        init_centers = kmeans_init(X, w, k, init, init_steps, seed, unit_weight)
+        counter_inc("h2d.bytes", int(init_centers.nbytes), site="fit.centers")
+        init_centers = jnp.asarray(init_centers)
     with span("kmeans.lloyd"):
         return _lloyd(X, w, init_centers, k, max_iter, tol, cosine, unit_weight)
 
@@ -501,7 +508,9 @@ def _lloyd(
     #          3x on the Gram kernel (ops/pallas_xtwx.py); falls back to "1"
     #          when sample weights are present
     #   "0"/"" XLA always
-    # `kmeans.lloyd_path{path=}` counts which path actually ran.
+    # `kmeans.lloyd_path{path=}` counts which path actually ran, and
+    # `kmeans.lloyd_gate{fused=0|1,reason=}` which test decided it:
+    # cosine | backend | small_k | vmem under "auto", else forced.
     from ..autotune.defaults import LLOYD_FUSED_MIN_K as _FUSED_MIN_K
 
     _pallas_env = __import__("os").environ.get("SRML_TPU_PALLAS_KMEANS", "auto")
@@ -533,17 +542,29 @@ def _lloyd(
             )
             if _tuned_min_k is not None:
                 _min_k = int(_tuned_min_k)
-        use_fused = (
-            not cosine
-            and jax.default_backend() == "tpu"
-            and k >= _min_k
-            and lloyd_fits_vmem(k, int(X.shape[1]), _n_split)
+        # the tests in the order they are asked; the first that fails names
+        # the reason, and a fused fit has passed the last (`reason=vmem`)
+        if cosine:
+            _reason = "cosine"
+        elif jax.default_backend() != "tpu":
+            _reason = "backend"
+        elif k < _min_k:
+            _reason = "small_k"
+        else:
+            _reason = "vmem"
+        use_fused = _reason == "vmem" and lloyd_fits_vmem(
+            k, int(X.shape[1]), _n_split
         )
         _pallas_env = "mask" if unit_weight else "1"
     else:
-        use_fused = not cosine and _pallas_env in ("1", "mask")
+        _forced_on = _pallas_env in ("1", "mask")
+        use_fused = _forced_on and not cosine
+        _reason = "cosine" if _forced_on and cosine else "forced"
     from .. import observability as _obs
 
+    # nothing else says that a wide fit left the fused kernel because its
+    # centres do not fit VMEM
+    counter_inc("kmeans.lloyd_gate", 1, fused=int(use_fused), reason=_reason)
     if use_fused:
         from ..parallel.partitioner import mesh_of
         from ._precision import parity_precision
@@ -572,8 +593,10 @@ def _lloyd(
             X, w, init_centers, float(tol), int(max_iter), cosine=cosine,
             fast_math=bool(_config.get("fast_math")),
         )
+    centers = np.asarray(centers)
+    counter_inc("d2h.bytes", int(centers.nbytes), site="fit.centers")
     return {
-        "cluster_centers": np.asarray(centers),
+        "cluster_centers": centers,
         "inertia": float(inertia),
         "n_iter": int(n_iter),
     }
