@@ -34,7 +34,7 @@ import numpy as np
 from ..autotune.defaults import COUNT_BLOCK_SPLIT, COUNT_DEVICE_MAX_CENTERS
 from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
-from ._precision import FAST, pdot
+from ._precision import FAST, parity_precision, pdot
 from .selection import top_k_max
 
 
@@ -56,7 +56,7 @@ def _normalize_rows(X: jax.Array) -> jax.Array:
 
 
 @compiled_kernel("kmeans.lloyd_fit",
-                 static_argnames=("max_iter", "cosine", "fast_math"))
+                 static_argnames=("max_iter", "cosine", "fast_math", "unit_weight"))
 def lloyd_fit(
     X: jax.Array,
     w: jax.Array,
@@ -65,6 +65,7 @@ def lloyd_fit(
     max_iter: int,
     cosine: bool = False,
     fast_math: bool = False,
+    unit_weight: bool = False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Lloyd iterations until max center movement² <= tol² or max_iter.
 
@@ -79,10 +80,24 @@ def lloyd_fit(
     fast_math=True runs the ASSIGNMENT distance matmul at MXU bf16 (single-pass)
     precision — the centroid-update contraction and the final reported inertia stay
     at parity precision, so model attributes remain fp32-exact while the hot loop's
-    dominant matmul runs at full MXU throughput (config key `fast_math`)."""
+    dominant matmul runs at full MXU throughput (config key `fast_math`).
+
+    unit_weight=True says that `w` holds zeros and ones only (the pad prefix
+    mask of a fit without a weightCol). The update contraction onehotᵀ·X then
+    runs at the precision pair (DEFAULT, parity): `one_hot · w` is exact in
+    bf16, so the bf16 passes that multiply its mid and low parts multiply
+    zeros, and the passes kept are the very products the full matmul sums
+    (three of six under `highest`). With arbitrary weights the product is no
+    bf16 number and the contraction stays (parity, parity), as the distance
+    matmul, the counts and every inertia do in both cases."""
     k = init_centers.shape[0]
     if cosine:
         init_centers = _normalize_rows(init_centers)
+    # the compiler cannot see through `* w` that the one-hot operand is exact
+    # in bf16; a pure one-hot it runs at three passes by itself
+    update_precision = (
+        (FAST, parity_precision()) if unit_weight else parity_precision()
+    )
 
     def _dists(centers, fast=False):
         if cosine:
@@ -102,7 +117,7 @@ def lloyd_fit(
         min_d2 = jnp.min(d2, axis=1)
         onehot = jax.nn.one_hot(assign, k, dtype=X.dtype) * w[:, None]
         counts = jnp.sum(onehot, axis=0)
-        sums = pdot(onehot.T, X)
+        sums = jnp.matmul(onehot.T, X, precision=update_precision)
         new_centers = jnp.where(
             counts[:, None] > 0, sums / jnp.maximum(counts, 1.0)[:, None], centers
         )
@@ -521,7 +536,6 @@ def _lloyd(
         # placeability; an unplaceable (k, d) stays on XLA rather than
         # handing Mosaic a compile it cannot place. Forced "1"/"mask" stay
         # unconditional (explicit opt-in, as before).
-        from ._precision import parity_precision
         from .pallas_kmeans import _N_SPLIT, lloyd_fits_vmem
 
         _n_split = (
@@ -567,7 +581,6 @@ def _lloyd(
     counter_inc("kmeans.lloyd_gate", 1, fused=int(use_fused), reason=_reason)
     if use_fused:
         from ..parallel.partitioner import mesh_of
-        from ._precision import parity_precision
         from .pallas_kmeans import lloyd_fit_pallas
 
         mesh = mesh_of(X)
@@ -589,9 +602,15 @@ def _lloyd(
         )
     else:
         _obs.counter_inc("kmeans.lloyd_path", 1, path="xla")
+        # bf16 passes of the update matmul at the stated float32 precision:
+        # three where lloyd_fit may take the one-hot operand as exact
+        exact_onehot = (
+            unit_weight and parity_precision() == jax.lax.Precision.HIGHEST
+        )
+        counter_inc("kmeans.lloyd_update", 1, passes=3 if exact_onehot else 6)
         centers, inertia, n_iter = lloyd_fit(
             X, w, init_centers, float(tol), int(max_iter), cosine=cosine,
-            fast_math=bool(_config.get("fast_math")),
+            fast_math=bool(_config.get("fast_math")), unit_weight=unit_weight,
         )
     centers = np.asarray(centers)
     counter_inc("d2h.bytes", int(centers.nbytes), site="fit.centers")

@@ -93,6 +93,7 @@ def test_a_wide_fit_on_a_tpu_says_it_left_the_fused_kernel_for_vmem(monkeypatch)
     got = _lloyd_counters(monkeypatch, "tpu", k=1000, d=3000)
     assert got == {"kmeans.lloyd_gate{fused=0,reason=vmem}": 1,
                    "kmeans.lloyd_path{path=xla}": 1,
+                   "kmeans.lloyd_update{passes=3}": 1,
                    "d2h.bytes{site=fit.centers}": 1000 * 3000 * 4}
 
 
