@@ -10,9 +10,9 @@
 #
 # tpu mode runs on the attached TPU and FAILS when JAX finds none (a speed comes
 # from the chip or not at all); cpu mode forces the virtual 8-device CPU mesh (the
-# CI smoke configuration: correctness and counts, never a speed) and skips the
-# chip-only bench.py line. Results append to benchmark/results/report.csv and
-# each bench prints its timing + quality line.
+# CI smoke configuration: correctness and counts, never a speed). Results append
+# to benchmark/results/report.csv and each bench prints its timing + quality
+# line. The chip benchmark the driver records is another thing: cellbench/run.py.
 #
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -74,8 +74,4 @@ for b in $BENCHES; do
     --report_path "$REPORT_DIR/report.csv" "${EXTRA[@]}"
 done
 
-# the flagship line: one process, chip only (bench.py exits 2 on a CPU backend)
-if [ "$MODE" = "tpu" ]; then
-  python bench.py
-fi
 echo "report: $REPORT_DIR/report.csv"

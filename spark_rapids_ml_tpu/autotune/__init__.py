@@ -2,7 +2,7 @@
 # Closed-loop autotuner (docs/design.md §6i): telemetry-driven knob search
 # with persisted per-platform tuning tables.
 #
-# The observability arc (§6f device roofline, §6g live telemetry, §6h comm
+# The observability arc (§6f device cost capture, §6g live telemetry, §6h comm
 # plane) measured everything a tuner needs; this package spends it. Three
 # pieces:
 #
@@ -17,9 +17,8 @@
 #               writes, corrupt/stale fall-through to defaults (counted),
 #               loaded once per process.
 #   search.py   the measurement loop: candidates timed through the §6f
-#               compiled_kernel AOT cache inside `autotune.trial` spans (so
-#               every entry carries measured mfu/roofline_bound/comm_frac),
-#               MAD noise floor mirroring ci/bench_check.py.
+#               compiled_kernel AOT cache inside `autotune.trial` spans,
+#               median + MAD noise floor (`autotune.noise_mads`).
 #   defaults.py the knob-registry defaults module — the one home for the
 #               numeric tile/threshold defaults ops/ used to hard-code
 #               (the analyzer, tools/analysis, enforces the split).

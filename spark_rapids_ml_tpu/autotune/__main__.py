@@ -8,8 +8,8 @@
 # CURRENT backend and persists the winners into the per-platform tuning
 # table under --dir / SRML_TPU_TUNE_DIR / autotune.dir. `--list` prints the
 # knob registry. Runs inside a FitRun so, with SRML_TPU_METRICS_DIR set, the
-# sweep exports a full structured run report (trial spans with measured
-# mfu/roofline verdicts) like every other unit of work in this library.
+# sweep exports a full structured run report (trial spans with their
+# kernels' analyzed flops/bytes) like every other unit of work in this library.
 #
 
 from __future__ import annotations

@@ -143,10 +143,9 @@ CONTINUAL_DECAY = 1.0
 # accumulator kernel.
 CONTINUAL_UPDATE_BATCH_ROWS = 1 << 14
 # CONTINUAL_DRIFT_MADS: MADs of separation above the baseline median a fresh
-# per-row signal needs to fire drift. Provenance: mirrors the measurement
-# discipline everywhere else in the tree — `autotune.noise_mads` and
-# ci/bench_check.py both demand 3 MADs before calling two samples different,
-# and drift is the same judgment (is this batch's loss a new distribution or
+# per-row signal needs to fire drift. Provenance: the same separation
+# `autotune.noise_mads` (3.0) demands before calling two samples different;
+# drift is the same judgment (is this batch's loss a new distribution or
 # the old one's noise?).
 CONTINUAL_DRIFT_MADS = 3.0
 

@@ -6,11 +6,10 @@
 # parallel harness: every trial kernel is a `compiled_kernel` (the §6f AOT
 # cache), so the warmup pass compiles exactly once per candidate signature
 # and the timed reps run cached executables; each timed rep runs inside an
-# `autotune.trial` span, so the device plane attributes analyzed flops/bytes
-# and closes the span with measured mfu / roofline_bound / comm_frac — every
-# table entry carries the roofline story of its winner, not just wall time.
+# `autotune.trial` span, so the device plane attributes the analyzed
+# flops/bytes of the candidate's kernels to it.
 #
-# Noise handling mirrors ci/bench_check.py's MAD logic: reps are taken
+# Noise handling is median + MAD (`autotune.noise_mads`): reps are taken
 # round-robin across candidates (a monotone warming trend cannot flatter
 # late candidates), each candidate keeps its median + median-absolute-
 # deviation, and a challenger only displaces the default when its median win
@@ -99,8 +98,7 @@ def _measure_candidates(
     replicates: int,
     knob: str,
 ) -> Dict[str, Dict[str, Any]]:
-    """Round-robin timed reps per candidate; per-candidate median/MAD plus
-    the span-attributed device verdicts of the timed reps."""
+    """Round-robin timed reps per candidate; per-candidate median/MAD."""
     import numpy as np
 
     from ..observability import runs as _runs
@@ -108,40 +106,24 @@ def _measure_candidates(
     for fn in cands.values():  # warmup: AOT compile, untimed
         _sync(fn())
     times: Dict[str, List[float]] = {label: [] for label in cands}
-    devices: Dict[str, List[Dict[str, Any]]] = {label: [] for label in cands}
     for rep in range(max(int(replicates), 1)):
         for label, fn in cands.items():
             with _runs.span(
                 "autotune.trial",
                 {"knob": knob, "candidate": label, "rep": rep},
             ):
-                node = _runs._span_stack()[-1]
                 t0 = time.perf_counter()
                 _sync(fn())
                 times[label].append(time.perf_counter() - t0)
-            dev = node.attrs.get("device")
-            if isinstance(dev, dict):
-                devices[label].append(dev)
     stats: Dict[str, Dict[str, Any]] = {}
     for label, ts in times.items():
         arr = np.asarray(ts, dtype=np.float64)
         med = float(np.median(arr))
-        st: Dict[str, Any] = {
+        stats[label] = {
             "median_s": med,
             "mad_s": float(np.median(np.abs(arr - med))),
             "trials": len(ts),
         }
-        devs = devices[label]
-        mfus = [d["mfu"] for d in devs if d.get("mfu") is not None]
-        if mfus:
-            st["mfu"] = float(np.median(np.asarray(mfus)))
-        bounds = [d.get("roofline_bound") for d in devs if d.get("roofline_bound")]
-        if bounds:
-            st["roofline_bound"] = max(set(bounds), key=bounds.count)
-        fracs = [d["comm_frac"] for d in devs if d.get("comm_frac") is not None]
-        if fracs:
-            st["comm_frac"] = float(np.median(np.asarray(fracs)))
-        stats[label] = st
     return stats
 
 
@@ -178,7 +160,6 @@ def _entry(knob: str, bucket: str, dtype: str, value: Any, winner: str,
         "baseline_mad_s": round(stats[default_label]["mad_s"], 6),
         "speedup": round(speedup, 4),
         "trials": st["trials"],
-        **{f: st[f] for f in ("mfu", "roofline_bound", "comm_frac") if f in st},
         "candidates": {
             lb: round(s["median_s"], 6) for lb, s in sorted(stats.items())
         },

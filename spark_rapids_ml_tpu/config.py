@@ -121,20 +121,13 @@ _DEFAULTS: Dict[str, Any] = {
     "observability.max_report_files": 4,
     # device-performance plane (observability/device.py, docs/design.md §6f):
     # compiled_kernel AOT cost/memory-analysis capture + compile accounting +
-    # roofline span attribution. Off = kernels run as plain jax.jit calls.
+    # span cost attribution. Off = kernels run as plain jax.jit calls.
     "observability.device_enabled": True,
     # HBM telemetry: sample local_devices() memory_stats() at span boundaries
     # (gauges are simply absent on platforms without memory_stats — CPU)
     "observability.hbm_sampling": True,
     "observability.hbm_sample_interval_s": 0.05,  # span-boundary rate limit
-    # roofline peak overrides (FLOP/s and bytes/s PER CHIP); 0 = auto from the
-    # per-platform peak table keyed on device_kind
-    "observability.peak_flops": 0.0,
-    "observability.peak_bw": 0.0,
     # communication plane (observability/comm.py, docs/design.md §6h):
-    # per-chip ICI/interconnect peak bytes/s override for the comm_frac /
-    # comm_bound verdicts; 0 = auto from the peak table's ICI column
-    "observability.peak_ici_bw": 0.0,
     # per-rank skew above which a rank is flagged a straggler (its phase wall
     # time vs the rank median): fires a `straggler` event into the run's event
     # log + flight recorder and counts comm.stragglers{phase=}
@@ -142,11 +135,6 @@ _DEFAULTS: Dict[str, Any] = {
     # absolute per-phase wall-time floor for straggler flags: ratios over
     # millisecond-scale phases are scheduler jitter, not stragglers
     "observability.straggler_min_wall_s": 0.25,
-    # opt-in jax.profiler capture of ONE designated pass of a streamed fit:
-    # set profile_dir to enable; profile_pass picks the pass (default 2 — the
-    # first post-compile steady-state pass); one capture per site per process
-    "observability.profile_dir": None,
-    "observability.profile_pass": 2,
     # live telemetry plane (observability/server.py, docs/design.md §6g):
     # opt-in driver-resident HTTP endpoint serving /metrics (Prometheus pull),
     # /healthz and /runs[/<run_id>] (live JSON view of open runs). None = no
@@ -299,7 +287,7 @@ _DEFAULTS: Dict[str, Any] = {
     # measurement-loop replication: timed reps per candidate (round-robin
     # across candidates so warming drift cannot favor late candidates), and
     # how many MADs of separation a challenger needs to displace the default
-    # (the ci/bench_check.py lesson: judging two noise samples is not a win)
+    # (judging two noise samples against each other is not a win)
     "autotune.replicates": 5,
     "autotune.noise_mads": 3.0,
 }
@@ -343,13 +331,8 @@ _ENV_KEYS: Dict[str, str] = {
     "observability.device_enabled": "SRML_TPU_DEVICE_OBSERVABILITY",
     "observability.hbm_sampling": "SRML_TPU_HBM_SAMPLING",
     "observability.hbm_sample_interval_s": "SRML_TPU_HBM_SAMPLE_INTERVAL_S",
-    "observability.peak_flops": "SRML_TPU_PEAK_FLOPS",
-    "observability.peak_bw": "SRML_TPU_PEAK_BW",
-    "observability.peak_ici_bw": "SRML_TPU_PEAK_ICI_BW",
     "observability.straggler_threshold": "SRML_TPU_STRAGGLER_THRESHOLD",
     "observability.straggler_min_wall_s": "SRML_TPU_STRAGGLER_MIN_WALL_S",
-    "observability.profile_dir": "SRML_TPU_PROFILE_DIR",
-    "observability.profile_pass": "SRML_TPU_PROFILE_PASS",
     "observability.http_port": "SRML_TPU_METRICS_PORT",
     "observability.http_host": "SRML_TPU_METRICS_HOST",
     "observability.flight_recorder_events": "SRML_TPU_FLIGHT_RECORDER_EVENTS",

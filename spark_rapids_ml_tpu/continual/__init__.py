@@ -9,8 +9,8 @@
 #                snapshot/restore via reliability/checkpoint.py, fixed block
 #                geometry so a steady update stream adds zero new
 #                `device.compile` entries after warm-up
-#   drift        median + MAD-floor judgment (the bench_check/autotune
-#                measurement discipline) over per-update inertia/loss/
+#   drift        median + MAD-floor judgment (as `autotune.noise_mads`)
+#                over per-update inertia/loss/
 #                residual, emitting `continual.drift{model=,signal=}` into
 #                run reports and the flight recorder
 #   promotion    validate-on-holdout then swap through serving.mutate_model

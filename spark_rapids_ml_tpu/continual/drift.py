@@ -3,8 +3,7 @@
 # per-row signal (inertia / loss / residual) the fit-time distribution's
 # noise, or a new distribution?
 #
-# The judgment is the tree's one measurement discipline (ci/bench_check.py,
-# `autotune.noise_mads`): a robust location (median) plus a MAD noise floor,
+# The judgment is the one `autotune.noise_mads` makes: a robust location (median) plus a MAD noise floor,
 # and a challenger only counts as DIFFERENT beyond `continual.drift_mads`
 # MADs of separation. The baseline seeds from the fit-time convergence tail
 # when a fit report is available (`baseline_from_convergence`); otherwise the
@@ -28,8 +27,7 @@ import numpy as np
 from .. import config as _config
 from ..observability import counter_inc, event
 
-# sigma = _MAD_TO_SIGMA * MAD under normality — the same constant
-# ci/bench_check.py's noise gate reasons with.
+# sigma = _MAD_TO_SIGMA * MAD under normality (1 / Phi^-1(3/4))
 _MAD_TO_SIGMA = 1.4826
 # relative noise floor: identical-to-the-ulp baselines (tiny synthetic
 # streams) would otherwise make ANY deviation "drift"
@@ -39,7 +37,7 @@ _ABS_FLOOR = 1e-12
 
 def resolve_drift_mads() -> float:
     """`continual.drift_mads` resolution: config pin, then tuning table, then
-    the defaults-module constant (3.0 — the bench_check separation rule)."""
+    the defaults-module constant (CONTINUAL_DRIFT_MADS, 3.0)."""
     from .. import autotune as _autotune
     from ..autotune.defaults import CONTINUAL_DRIFT_MADS
 

@@ -45,8 +45,7 @@ from ..ops.streaming import (
 )
 from ..reliability.checkpoint import copy_carry
 
-# MAD scale factor for a normal distribution (sigma = 1.4826 * MAD) — the same
-# constant the drift detector and ci/bench_check.py reason with.
+# floor under a weight sum, so an empty cluster or batch divides by no zero
 _EPS_COUNT = 1e-12
 
 

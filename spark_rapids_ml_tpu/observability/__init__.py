@@ -11,7 +11,7 @@
 #   inference.py  TransformRun, predict_dispatch, shape buckets + sentinel
 #   export.py     JSONL run/transform reports (rotating) + Prometheus textfile
 #   device.py     compiled_kernel cost/memory-analysis capture, HBM telemetry,
-#                 roofline span attribution, compile accounting, profiler hook
+#                 span cost attribution, compile accounting
 #   server.py     opt-in live HTTP endpoint: /metrics, /healthz, /runs[/<id>],
 #                 /runs/<id>/ranks (barrier timeline)
 #   flight.py     failure flight recorder: bounded ring buffer + postmortem
@@ -19,8 +19,8 @@
 #   tracing.py    causal request tracing (§6l): W3C traceparent ids, per-request
 #                 span trees with fan-in links, tail-based sampling ring,
 #                 trace_reports.jsonl export + /traces live endpoints
-#   comm.py       communication plane: HLO collective accounting, comm
-#                 roofline, per-rank skew + straggler detection, timeline
+#   comm.py       communication plane: HLO collective accounting, per-rank
+#                 skew + straggler detection, timeline
 #
 
 from .registry import (
@@ -63,7 +63,6 @@ from .comm import (
     collectives_of_computation,
     extract_collectives,
     rank_timeline,
-    scenario_comm_summary,
 )
 from .inference import (
     TransformRun,
@@ -90,11 +89,7 @@ from .device import (
     compiled_kernel,
     kernel_cost,
     kernel_cost_records,
-    platform_ici_bw,
-    platform_peaks,
-    profile_pass,
     sample_hbm,
-    scenario_summary,
 )
 from .server import (
     server_address,
@@ -155,7 +150,6 @@ __all__ = [
     "collectives_of_computation",
     "extract_collectives",
     "rank_timeline",
-    "scenario_comm_summary",
     "TransformRun",
     "deliver_partition_snapshot",
     "predict_dispatch",
@@ -176,11 +170,7 @@ __all__ = [
     "compiled_kernel",
     "kernel_cost",
     "kernel_cost_records",
-    "platform_ici_bw",
-    "platform_peaks",
-    "profile_pass",
     "sample_hbm",
-    "scenario_summary",
     "server_address",
     "start_metrics_server",
     "stop_metrics_server",

@@ -6,7 +6,7 @@
 # dark: XLA inserts the collectives (the whole point of the one-SPMD-program
 # architecture, design.md §1) and nothing measured them, and per-rank skew was
 # invisible even though arXiv:1612.01437 identifies straggler/partition-skew
-# handling as the dominant cost of distributed Spark ML. Three things live
+# handling as the dominant cost of distributed Spark ML. Two things live
 # here:
 #
 #   * Collective accounting — the ONE place in the tree that parses optimized
@@ -21,15 +21,6 @@
 #     forms. Per call, analyzed bytes aggregate as
 #     `comm.collective_ops{kind=,kernel=}` / `comm.collective_bytes{...}` and
 #     attribute to the innermost open span like flops/bytes do.
-#
-#   * Comm roofline — analyzed collective bytes over measured span wall time
-#     yield achieved interconnect bandwidth; against the per-`device_kind`
-#     ICI/link peak column of the roofline table (observability/device.py,
-#     override `observability.peak_ici_bw`) that is `comm_frac`, and the
-#     span's `comm_bound` verdict says whether the estimated collective time
-#     exceeds the compute/memory roofline time — the "is this fit
-#     allreduce-shaped or interconnect-bound" question ROADMAP item 2's pod
-#     scale-out needs answered before tuning.
 #
 #   * Rank skew & stragglers — worker-scope snapshots (barrier fit tasks,
 #     transform partitions) carry per-rank wall time, rows and bytes per
@@ -189,40 +180,6 @@ def collectives_of_computation(fn: Any, *args: Any,
     jitted = jax.jit(fn, static_argnames=tuple(static_argnames))
     exe = jitted.lower(*args).compile()
     return collectives_from_executable(exe) or {}
-
-
-# ------------------------------------------------------------- comm roofline
-
-
-def classify_comm(flops: float, hbm_bytes: float, comm_bytes: float,
-                  duration_s: float, peak_flops: float, peak_bw: float,
-                  peak_ici_bw: float) -> Dict[str, Any]:
-    """Comm-roofline verdict for one closed span: achieved interconnect
-    bandwidth (analyzed collective bytes over measured wall time), the
-    fraction of the ICI/link peak that represents (`comm_frac`), and
-    `comm_bound` — True when the roofline-estimated collective time exceeds
-    the compute/memory roofline time, i.e. the span's ceiling is the
-    interconnect, not the chip. Same caveat as mfu (design.md §6f): wall time
-    bounds dispatch on async backends, so both fractions are lower bounds."""
-    out: Dict[str, Any] = {
-        "comm_bytes": comm_bytes,
-        "achieved_ici_bw": None,
-        "comm_frac": None,
-        "comm_bound": False,
-    }
-    if comm_bytes <= 0 or duration_s <= 0:
-        return out
-    achieved = comm_bytes / duration_s
-    out["achieved_ici_bw"] = achieved
-    if peak_ici_bw > 0:
-        out["comm_frac"] = achieved / peak_ici_bw
-        est_comm_s = comm_bytes / peak_ici_bw
-        est_compute_s = max(
-            flops / peak_flops if peak_flops > 0 else 0.0,
-            hbm_bytes / peak_bw if peak_bw > 0 else 0.0,
-        )
-        out["comm_bound"] = est_comm_s > est_compute_s
-    return out
 
 
 # ------------------------------------------- per-rank skew / barrier timeline
@@ -400,44 +357,3 @@ def note_worker_merge(run: Any) -> None:
             "(threshold %.2fx)", entry["rank"], entry["skew"] or 0.0,
             worst_phase, thr,
         )
-
-
-# ------------------------------------------------------------- bench summary
-
-
-def scenario_comm_summary(report: Mapping[str, Any],
-                          wall_s: Optional[float] = None) -> Dict[str, Any]:
-    """Communication summary of one run report (a bench scenario): total
-    analyzed collective ops/bytes from the run's `comm.*` counters, the
-    scenario-level `comm_frac` (collective bytes over wall clock against the
-    per-chip ICI peak — same wall-clock caveats as `scenario_summary`'s mfu),
-    and the worst `comm.rank_skew` gauge when the scenario exercised the
-    rank-snapshot plane. bench.py emits these as `<unit>_comm_frac` /
-    `<unit>_rank_skew`, gated advisory by ci/bench_check.py."""
-    from . import device as _device
-
-    metrics = report.get("metrics") or {}
-    counters = metrics.get("counters") or {}
-    comm_bytes = float(sum(
-        v for k, v in counters.items() if k.startswith("comm.collective_bytes")
-    ))
-    comm_ops = int(sum(
-        v for k, v in counters.items() if k.startswith("comm.collective_ops")
-    ))
-    wall = wall_s if wall_s is not None else (report.get("duration_s") or 0.0)
-    ici = _device.platform_ici_bw()
-    comm_frac = (
-        round((comm_bytes / wall) / ici, 6)
-        if comm_bytes > 0 and wall and wall > 0 and ici > 0
-        else None
-    )
-    skews = [
-        v for k, v in (metrics.get("gauges") or {}).items()
-        if k.startswith("comm.rank_skew")
-    ]
-    return {
-        "comm_ops": comm_ops,
-        "comm_bytes": comm_bytes,
-        "comm_frac": comm_frac,
-        "rank_skew": round(max(skews), 4) if skews else None,
-    }

@@ -1,12 +1,8 @@
 #
-# Device-performance plane: XLA cost-analysis roofline attribution, HBM
-# telemetry, and compile accounting (docs/design.md §6f).
+# Device-performance plane: XLA cost-analysis capture, HBM telemetry, and
+# compile accounting (docs/design.md §6f).
 #
-# PRs 3-4 made fit and transform LOGICALLY observable (metrics, trace trees,
-# recompile sentinel); the system stayed blind at the device level — BENCH_r03's
-# est_mfu ≈ 4.6% came from a hand-rolled analytic flop count, and ROADMAP item 3
-# makes "MFU/roofline fraction in bench JSON" the success metric for the Pallas
-# arc. Three things live here:
+# Two things live here:
 #
 #   * compiled_kernel — the one choke point for every jitted kernel the library
 #     compiles. It wraps jax.jit with an AOT lower().compile() cache keyed by
@@ -17,11 +13,11 @@
 #     memory_analysis() (argument/output/temp bytes) are captured per
 #     executable. Calls then run the cached executable directly and ATTRIBUTE
 #     the analyzed flops/bytes to the innermost open trace span, so FitRun /
-#     TransformRun span nodes carry real device work, not just wall time.
-#     Inlines through the plain jitted call under tracing (vmap/grad/nested
-#     jit) or when `observability.device_enabled` is off. An AOT compile or
-#     executable-call failure RAISES: there is no silent jit fallback, so the
-#     compile accounting always describes what actually runs.
+#     TransformRun span nodes carry the compiler's count of the work, not just
+#     wall time. Inlines through the plain jitted call under tracing
+#     (vmap/grad/nested jit) or when `observability.device_enabled` is off. An
+#     AOT compile or executable-call failure RAISES: there is no silent jit
+#     fallback, so the compile accounting always describes what actually runs.
 #
 #   * HBM telemetry — `local_devices()[*].memory_stats()` sampled at span
 #     boundaries (rate-limited) into the `device.hbm_bytes_in_use` gauge plus a
@@ -30,24 +26,11 @@
 #     older runtimes) are detected ONCE and the gauges are simply absent — no
 #     warning spam.
 #
-#   * Roofline attribution — analyzed flops/bytes combined with measured span
-#     wall time against a per-platform peak table (overridable via
-#     `observability.peak_flops` / `observability.peak_bw`) yields achieved
-#     FLOP/s, MFU, roofline fraction and a compute-/memory-bound
-#     classification per span and per bench scenario (bench.py replaces its
-#     analytic `est_mfu` with the measured `mfu` from here, gated
-#     direction-aware by ci/bench_check.py).
-#
-# Accuracy caveats, by construction: jax dispatch is asynchronous, so span
-# wall time bounds dispatch+compile on accelerators (an MFU computed from it is
-# a lower bound when the caller did not sync); XLA's HLO cost analysis counts a
-# dynamic-trip-count while_loop body ONCE, so whole-fit programs (lloyd_fit)
-# under-report flops vs per-pass streamed kernels. Both biases are stable
-# across rounds, which is what the direction-aware bench gate needs.
-#
-# The opt-in `observability.profile_dir` hook captures ONE jax.profiler trace
-# for the designated pass (`observability.profile_pass`, default 2 — the first
-# post-compile steady-state pass) of a streamed fit, once per process per site.
+# The records are counts, never shares of a peak: XLA's HLO cost analysis counts
+# a dynamic-trip-count while_loop body ONCE (lloyd_fit's 30 iterations as one),
+# and span wall time is the host's. What share of the chip a kernel reaches is
+# the benchmark's question, answered from the device trace against
+# `cellbench/peaks.json`.
 #
 # The analyzer (fence/device-analysis-off-plane) bans direct `.cost_analysis()` /
 # `.memory_stats()` calls outside this module so the capture contract (and its
@@ -56,37 +39,23 @@
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import inspect
 import sys
 import threading
 import time
-from typing import Any, Callable, Dict, Iterator, List, Mapping, Optional, Tuple
+from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
 import weakref
 
 from .. import config as _config
 from ..utils import get_logger
+from . import comm as _comm
 from . import runs as _runs
 
 _logger = get_logger("observability.device")
 
 _lock = threading.RLock()
-
-_comm_mod = None
-
-
-def _comm():
-    """Lazy communication-plane import (observability/comm.py imports this
-    module lazily for the ICI peak; the reverse edge resolves at call time —
-    the same cycle-breaking as runs._device)."""
-    global _comm_mod
-    if _comm_mod is None:
-        from . import comm as cm
-
-        _comm_mod = cm
-    return _comm_mod
 
 # every live CompiledKernel, so reset_device_plane can drop executable caches
 # (tests; a stale cache would report zero compiles for work a fresh process
@@ -119,33 +88,7 @@ _HBM_MAX_PROBE_ERRORS = 3
 # per-run HBM peaks, keyed by run_id while the run is open
 _run_peaks: Dict[str, int] = {}
 
-# profiler hook: sites already captured this process (one trace per site)
-_profiled_sites: set = set()
-
 _errors_logged: set = set()
-
-# per-platform peak table: device_kind substring (lowercase, first match wins)
-# -> (peak FLOP/s per chip at parity/f32-equivalent precision, HBM bytes/s per
-# chip, ICI/interconnect bytes/s per chip — the comm-plane roofline column,
-# docs/design.md §6h). TPU compute/HBM rows follow published chip specs (bf16
-# peak halved for the f32-equivalent MXU rate the parity kernels run at); ICI
-# rows are published per-chip interchip-interconnect totals; the cpu/gpu rows
-# are order-of-magnitude placeholders that make mfu/roofline/comm keys PRESENT
-# and comparable across rounds — absolute truth on those backends comes from
-# the `observability.peak_flops` / `observability.peak_bw` /
-# `observability.peak_ici_bw` overrides.
-_PEAK_TABLE: Tuple[Tuple[str, Tuple[float, float, float]], ...] = (
-    ("v5 lite", (98e12, 819e9, 200e9)),
-    ("v5e", (98e12, 819e9, 200e9)),
-    ("v5p", (229e12, 2765e9, 600e9)),
-    ("v6", (459e12, 1640e9, 448e9)),
-    ("v4", (137e12, 1228e9, 300e9)),
-    ("v3", (61e12, 900e9, 100e9)),
-    ("gpu", (19.5e12, 1555e9, 600e9)),
-    ("cpu", (2e11, 5e10, 1e10)),
-)
-
-_peaks_cache: Optional[Tuple[float, float, float, str]] = None
 
 
 def _enabled() -> bool:
@@ -162,86 +105,17 @@ def _log_once(key: str, msg: str, *args: Any) -> None:
 
 def reset_device_plane() -> None:
     """Clear all process-global device-plane state (tests)."""
-    global _hbm_supported, _hbm_last_sample, _peaks_cache, _hbm_probe_errors
+    global _hbm_supported, _hbm_last_sample, _hbm_probe_errors
     with _lock:
         _records.clear()
         _run_peaks.clear()
-        _profiled_sites.clear()
         _errors_logged.clear()
         _hbm_supported = None
         _hbm_last_sample = 0.0
         _hbm_probe_errors = 0
-        _peaks_cache = None
         _sharding_reprs.clear()
         for kernel in list(_kernels):
             kernel._cache.clear()
-
-
-# ------------------------------------------------------------------ peak table
-
-
-def _platform_row() -> Tuple[float, float, float, str]:
-    """(peak_flops, peak_bw, peak_ici_bw, platform) of the local device kind —
-    the raw table row (cached), before any config override. A device no row
-    matches is an error, not a default: a roofline share against another
-    chip's peaks is a wrong number, so asking for peaks on an unknown
-    `device_kind` raises."""
-    global _peaks_cache
-    with _lock:
-        cached = _peaks_cache
-    if cached is None:
-        import jax
-
-        dev = jax.local_devices()[0]
-        platform = str(dev.platform)
-        kind = str(getattr(dev, "device_kind", "") or "")
-        # a TPU is matched by its device_kind only ("tpu" is in every kind);
-        # cpu/gpu kinds are host/vendor strings, so those match by platform
-        hay = (kind if platform == "tpu" else platform).lower()
-        for key, (f, b, i) in _PEAK_TABLE:
-            if key in hay:
-                cached = (f, b, i, platform)
-                break
-        else:
-            raise ValueError(
-                f"no peak-table row for device_kind {kind!r} on platform "
-                f"{platform!r}; add one to observability/device.py::_PEAK_TABLE"
-                " (with its source) — peaks are never defaulted"
-            )
-        with _lock:
-            _peaks_cache = cached
-    return cached
-
-
-def platform_peaks() -> Tuple[float, float, str]:
-    """(peak_flops_per_chip, peak_bw_per_chip, platform). Config overrides win;
-    otherwise the first _PEAK_TABLE row whose key substring-matches the local
-    TPU's device kind (cpu/gpu match by platform). Raises ValueError for a
-    device no row matches."""
-    over_f = float(_config.get("observability.peak_flops") or 0.0)
-    over_b = float(_config.get("observability.peak_bw") or 0.0)
-    flops, bw, _, platform = _platform_row()
-    return (over_f or flops, over_b or bw, platform)
-
-
-def platform_ici_bw() -> float:
-    """Per-chip ICI/interconnect peak bytes/s — the comm-plane roofline column
-    (docs/design.md §6h). `observability.peak_ici_bw` overrides the table."""
-    over = float(_config.get("observability.peak_ici_bw") or 0.0)
-    return over or _platform_row()[2]
-
-
-def _classify(flops: float, bytes_accessed: float,
-              peaks: Tuple[float, float, str]) -> Dict[str, Any]:
-    """Roofline classification from analyzed totals: operational intensity vs
-    the ridge point of the platform roof."""
-    peak_flops, peak_bw, _ = peaks
-    ridge = peak_flops / peak_bw if peak_bw > 0 else 0.0
-    oi = (flops / bytes_accessed) if bytes_accessed > 0 else None
-    bound = "compute" if (oi is not None and oi >= ridge) else "memory"
-    ceiling = peak_flops if oi is None else min(peak_flops, oi * peak_bw)
-    return {"operational_intensity": oi, "roofline_bound": bound,
-            "ceiling_flops_per_s": ceiling}
 
 
 # ------------------------------------------------------------- compiled_kernel
@@ -292,7 +166,10 @@ class CompiledKernel:
     def __init__(self, name: str, fn: Callable, jit_kwargs: Dict[str, Any]):
         self.name = name
         self._fn = fn
+        self._jit_kwargs = jit_kwargs
         self._jit = self._make_jit(fn, jit_kwargs)
+        # the trace epoch at which self._jit last traced (None: not yet)
+        self._jit_epoch: Optional[Tuple[Tuple[str, str], ...]] = None
         self._cache: Dict[Any, Dict[str, Any]] = {}
         self._klock = threading.RLock()
         static_argnums = jit_kwargs.get("static_argnums") or ()
@@ -331,10 +208,34 @@ class CompiledKernel:
 
         return jax.jit(fn, **jit_kwargs)
 
+    def _jit_at_epoch(self):
+        """The jit object to trace through. jit answers `lower()` and nested
+        calls from its own trace cache, which is keyed by the wrapped function
+        and the argument types and knows nothing of the trace epoch: once a
+        shape has traced, a changed `parity_precision` would be answered with
+        the old program. So when the epoch differs from the one this jit last
+        traced at, it is replaced by a jit of a fresh wrapper of the same
+        function (a fresh `jax.jit` of the same function object would hit the
+        same cache), which has traced nothing. With an unchanged epoch this is
+        the jit built at decoration, and nothing re-traces."""
+        epoch = _trace_epoch()
+        with self._klock:
+            if self._jit_epoch != epoch:
+                if self._jit_epoch is not None:
+                    fn = self._fn
+
+                    @functools.wraps(fn)  # same name, so the same program name
+                    def retraced(*args: Any, **kwargs: Any):
+                        return fn(*args, **kwargs)
+
+                    self._jit = self._make_jit(retraced, self._jit_kwargs)
+                self._jit_epoch = epoch
+            return self._jit
+
     @property
     def jitted(self):
         """The underlying jax.jit-wrapped function (AOT helpers, tests)."""
-        return self._jit
+        return self._jit_at_epoch()
 
     def __reduce__(self):
         # pickle BY REFERENCE (module attribute lookup), never by value: the
@@ -344,7 +245,7 @@ class CompiledKernel:
         return (_resolve_kernel, (self.__module__, self.__qualname__))
 
     def lower(self, *args: Any, **kwargs: Any):
-        return self._jit.lower(*args, **kwargs)
+        return self._jit_at_epoch().lower(*args, **kwargs)
 
     # ---- signature ----
 
@@ -453,9 +354,10 @@ class CompiledKernel:
             return None  # under trace: inline through the plain jit path
         # trace-affecting config rides in the signature (the trace epoch):
         # a kernel body that reads one of these keys at trace time can never
-        # serve a STALE bake — changing the key re-keys the AOT cache and
-        # _compile_and_capture re-lowers (lower() always re-traces), reading
-        # the new value. This is what licenses the one sanctioned trace-time
+        # serve a STALE bake — changing the key re-keys the AOT cache, and
+        # _compile_and_capture lowers through _jit_at_epoch, which re-traces
+        # with the new value (lower() alone would not: it answers from jit's
+        # trace cache). This is what licenses the one sanctioned trace-time
         # config read (ops/_precision.py::parity_precision).
         return (tuple(_leaf_key(l) for l in leaves), treedef,
                 statics + _trace_epoch())
@@ -464,7 +366,7 @@ class CompiledKernel:
 
     def _compile_and_capture(self, sig, args, kwargs) -> Dict[str, Any]:
         t0 = time.perf_counter()
-        lowered = self._jit.lower(*args, **kwargs)
+        lowered = self._jit_at_epoch().lower(*args, **kwargs)
         exe = lowered.compile()
         compile_s = time.perf_counter() - t0
         cost = _extract_cost(exe, lowered)
@@ -479,7 +381,7 @@ class CompiledKernel:
         # signature for collective ops/bytes/replica-groups; None (no HLO
         # surface on this runtime) just means no collective accounting
         try:
-            collectives = _comm().collectives_from_executable(exe)
+            collectives = _comm.collectives_from_executable(exe)
         except Exception as e:
             _log_once(f"comm:{self.name}",
                       "kernel %s: collective extraction failed (%s)",
@@ -500,7 +402,7 @@ class CompiledKernel:
 
     def __call__(self, *args: Any, **kwargs: Any):
         if not _enabled():
-            return self._jit(*args, **kwargs)
+            return self._jit_at_epoch()(*args, **kwargs)
         canon = self._canonicalize(args, kwargs)
         if canon is not None:
             call_args, statics = canon
@@ -515,7 +417,7 @@ class CompiledKernel:
             dyn_args, dyn_kwargs, statics = self._split(args, kwargs)
         sig = self._signature(dyn_args, dyn_kwargs, statics)
         if sig is None:  # tracer inputs: inline through the enclosing trace
-            return self._jit(*args, **kwargs)
+            return self._jit_at_epoch()(*args, **kwargs)
         entry = self._cache.get(sig)
         if entry is None:
             with self._klock:
@@ -537,10 +439,9 @@ class CompiledKernel:
 
 # config keys whose values a kernel body may read AT TRACE TIME (today only
 # parity_precision — ops/_precision.py). Folding the current value into every
-# AOT signature makes such reads stale-proof: see CompiledKernel._signature.
-# The residual: with the device plane disabled (observability.device_enabled
-# off) calls run through plain jax.jit, whose cache does not know the epoch —
-# documented in docs/design.md §6j.
+# AOT signature makes such reads stale-proof: see CompiledKernel._signature
+# and _jit_at_epoch (which covers the plain-jit paths too: the device plane
+# switched off, and a kernel called under another's trace).
 _TRACE_EPOCH_KEYS = ("parity_precision",)
 
 
@@ -657,7 +558,7 @@ def compiled_kernel(name: str, **jit_kwargs: Any) -> Callable:
     """Decorator factory: `@compiled_kernel("ops.foo", static_argnames=(...))`
     replaces `@functools.partial(jax.jit, static_argnames=(...))` for every
     kernel the library compiles — same call semantics, plus compile accounting,
-    cost/memory analysis capture and roofline span attribution."""
+    cost/memory analysis capture and span attribution."""
 
     def wrap(fn: Callable) -> CompiledKernel:
         return CompiledKernel(name, fn, jit_kwargs)
@@ -697,7 +598,7 @@ def compiles_total() -> int:
 
 
 def device_report_section(registry: Any = None) -> Optional[Dict[str, Any]]:
-    """The `device` section of a run report: peak table in force + the cost
+    """The `device` section of a run report: the platform + the cost
     records of the kernels THIS run actually called (filtered via the run's
     `device.kernel_calls{kernel=}` counters — a long-lived serving process
     must not serialize the whole process-global record table into every
@@ -727,12 +628,10 @@ def device_report_section(registry: Any = None) -> Optional[Dict[str, Any]]:
             r["run_calls"] = run_calls.get(r["kernel"], 0)
     if not records:
         return None
-    peak_flops, peak_bw, platform = platform_peaks()
+    import jax
+
     return {
-        "platform": platform,
-        "peak_flops": peak_flops,
-        "peak_bw": peak_bw,
-        "peak_ici_bw": platform_ici_bw(),
+        "platform": str(jax.local_devices()[0].platform),
         "kernels": records,
     }
 
@@ -809,120 +708,13 @@ def note_run_end(run: Any) -> None:
             _log_once("peak_gauge", "hbm peak gauge failed: %s", e)
 
 
-# ------------------------------------------------------------ span attribution
+# ------------------------------------------------------------ span close hook
 
 
 def on_span_close(node: Any) -> None:
-    """runs.span close hook: roofline-classify any device work attributed to
-    the span, and keep the HBM gauge fresh (rate-limited). Must never raise —
-    it sits inside every span's finally."""
+    """runs.span close hook: keep the HBM gauge fresh (rate-limited). Must
+    never raise — it sits inside every span's finally."""
     try:
-        if not _enabled():
-            return
-        dev = node.attrs.get("device")
-        if dev is not None and node.duration_s:
-            peaks = platform_peaks()
-            achieved = dev["flops"] / node.duration_s
-            dev["achieved_flops_per_s"] = achieved
-            dev["mfu"] = achieved / peaks[0] if peaks[0] > 0 else 0.0
-            cls = _classify(dev["flops"], dev["bytes"], peaks)
-            dev["operational_intensity"] = cls["operational_intensity"]
-            dev["roofline_bound"] = cls["roofline_bound"]
-            ceiling = cls["ceiling_flops_per_s"]
-            dev["roofline_frac"] = achieved / ceiling if ceiling > 0 else 0.0
-            # comm roofline (§6h): achieved interconnect bandwidth / comm_frac
-            # / comm_bound from the span's attributed collective bytes
-            if dev.get("comm_bytes"):
-                dev.update(_comm().classify_comm(
-                    dev["flops"], dev["bytes"], dev["comm_bytes"],
-                    node.duration_s, peaks[0], peaks[1], platform_ici_bw(),
-                ))
         sample_hbm()
     except Exception as e:
         _log_once("span_close", "device span hook failed: %s", e)
-
-
-# ----------------------------------------------------------- scenario summary
-
-
-def scenario_summary(report: Mapping[str, Any],
-                     wall_s: Optional[float] = None) -> Dict[str, Any]:
-    """Measured MFU + roofline classification for one run report (a bench
-    scenario): total analyzed flops/bytes from the run's device counters over
-    the scenario wall clock against the PER-CHIP platform peak. cost_analysis
-    runs on the compiled (post-SPMD-partitioning) per-device module, so the
-    analyzed flops are already per-chip — no further division by chip count
-    (doing so would deflate MFU by n_chips on a pod). This REPLACES bench.py's
-    analytic est_mfu; mfu here is conservative (wall time includes host work)
-    but measured, and the bench gate tracks its direction."""
-    counters = (report.get("metrics") or {}).get("counters") or {}
-    flops = float(sum(
-        v for k, v in counters.items() if k.startswith("device.flops_total")
-    ))
-    bytes_accessed = float(sum(
-        v for k, v in counters.items() if k.startswith("device.bytes_total")
-    ))
-    compiles = int(sum(
-        v for k, v in counters.items()
-        if k.startswith("device.compile{") or k == "device.compile"
-    ))
-    wall = wall_s if wall_s is not None else (report.get("duration_s") or 0.0)
-    peaks = platform_peaks()
-    mfu = (
-        flops / wall / peaks[0]
-        if wall and wall > 0 and peaks[0] > 0
-        else 0.0
-    )
-    cls = _classify(flops, bytes_accessed, peaks)
-    return {
-        "mfu": round(mfu, 6),
-        "roofline_bound": cls["roofline_bound"],
-        "device_flops": flops,
-        "device_bytes": bytes_accessed,
-        "device_compiles": compiles,
-        "platform": peaks[2],
-    }
-
-
-# -------------------------------------------------------------- profiler hook
-
-
-@contextlib.contextmanager
-def profile_pass(site: str, pass_no: int) -> Iterator[None]:
-    """Opt-in jax.profiler capture of ONE designated pass of a streamed fit:
-    active only when `observability.profile_dir` is set and `pass_no` equals
-    `observability.profile_pass` (default 2 — the first post-compile
-    steady-state pass); captures once per site per process. Trace lands in
-    `<profile_dir>/<site>/` for xprof/tensorboard."""
-    pdir = _config.get("observability.profile_dir")
-    if not pdir or int(pass_no) != int(_config.get("observability.profile_pass")):
-        yield
-        return
-    with _lock:
-        if site in _profiled_sites:
-            yield
-            return
-        _profiled_sites.add(site)
-    import os
-
-    target = os.path.join(str(pdir), site.replace("/", "_").replace(".", "_"))
-    try:
-        import jax.profiler
-
-        jax.profiler.start_trace(target)
-    except Exception as e:
-        _log_once(f"profile:{site}", "profiler capture failed for %s: %s",
-                  site, e)
-        yield
-        return
-    try:
-        yield
-    finally:
-        try:
-            jax.profiler.stop_trace()
-            _runs.counter_inc("device.profile_captures", 1, site=site)
-            _logger.info("wrote profiler trace for %s pass %d to %s",
-                         site, pass_no, target)
-        except Exception as e:
-            _log_once(f"profile_stop:{site}",
-                      "profiler stop failed for %s: %s", site, e)

@@ -185,9 +185,8 @@ def observe(name: str, value: float,
 
 
 def add_span_total(name: str, seconds: float) -> None:
-    """Legacy flat accumulation (profiling.add_time) PLUS a same-named
-    exponential latency histogram: every per-batch `add_time` call site gains a
-    distribution for free, not just a sum."""
+    """Flat accumulation of seconds under a span name PLUS a same-named
+    exponential latency histogram: a distribution, not just a sum."""
     for reg in _sink_registries():
         reg.add_span_total(name, seconds)
         reg.histogram(name).observe(seconds)
@@ -374,8 +373,7 @@ def span(name: str, attrs: Optional[Mapping[str, Any]] = None) -> Iterator[SpanN
         # inclusive device accounting: raw kernel cost rolls up into the
         # enclosing span on this thread, so a wrapper span opened ABOVE the
         # dispatch layer (serving.batch around transform.predict) still
-        # carries the §6f cost of the kernels it caused. Raw fields only —
-        # each level gets its own roofline classification at its own close.
+        # carries the §6f cost of the kernels it caused.
         dev = node.attrs.get("device")
         if dev and stack:
             pdev = stack[-1].attrs.get("device")
@@ -390,9 +388,7 @@ def span(name: str, attrs: Optional[Mapping[str, Any]] = None) -> Iterator[SpanN
             agg = pdev.setdefault("kernels", {})
             for kname, c in (dev.get("kernels") or {}).items():
                 agg[kname] = agg.get(kname, 0) + c
-        # device plane (observability/device.py): roofline-classify any kernel
-        # work attributed to this span + keep the HBM gauge fresh. Runs BEFORE
-        # add_span so the stored span dicts carry the finalized attrs.
+        # device plane (observability/device.py): keep the HBM gauge fresh
         _device().on_span_close(node)
         _flight().note_span_close(node)
         for reg in _sink_registries():

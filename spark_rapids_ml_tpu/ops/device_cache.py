@@ -235,20 +235,17 @@ def cached_build(cache: Optional[DeviceBatchCache], cache_key: Any,
     implementation so the "zero pass-2 uploads" accounting CI asserts on can
     never drift between the tiers. The caller's fault point fires BEFORE this
     (replayed batches stay fault-injectable)."""
-    import time
-
     if cache is not None:
         hit = cache.get(cache_key, batch_index)
         if hit is not None:
             return hit
-    t0 = time.perf_counter()
-    # structured span: each actual upload is a `stream.ingest` node in the fit
-    # trace tree (child of the pass that triggered it), on top of the legacy
-    # per-site totals + per-batch latency histogram add_time feeds below
-    with _obs.span("stream.ingest", {"site": site, "batch": batch_index}):
-        batch = build()
+    # each actual upload is a `stream.ingest` node in the fit trace tree (child
+    # of the pass that triggered it); the per-site span inside it keeps the
+    # per-site totals + per-batch latency histogram
     # srml-metric: stream.ingest_s — per-site span family (dynamic suffix)
-    profiling.add_time(f"stream.ingest_s.{site}", time.perf_counter() - t0)
+    with _obs.span("stream.ingest", {"site": site, "batch": batch_index}), \
+            _obs.span(f"stream.ingest_s.{site}"):
+        batch = build()
     profiling.count("stream.upload_batches")
     profiling.count(
         "stream.upload_bytes",

@@ -59,8 +59,8 @@ def _xtx_kernel(n_split, nv_ref, s_ref, x_ref, s2_ref, s1_ref):
     tail block also loads unspecified values from past the array edge, which the
     same mask zeroes before any arithmetic). s_ref is a CSE guard: pallas_call is
     opaque to XLA, so chaining a varying scalar through it is the only way a
-    benchmark loop of identical passes doesn't collapse to one (bench.py uses it;
-    production passes 0)."""
+    benchmark loop of identical passes doesn't collapse to one (no caller sets
+    it any more: ROADMAP N3; production passes 0)."""
     b = pl.program_id(0)
 
     @pl.when(b == 0)

@@ -28,7 +28,7 @@ from ..observability import (
     progress as obs_progress,
     span as obs_span,
 )
-from ..observability.device import compiled_kernel, profile_pass
+from ..observability.device import compiled_kernel
 from ..reliability import (
     StreamBatchError,
     fault_point,
@@ -689,11 +689,8 @@ def _streaming_logreg_fit(
         # `logreg.step` span per pass in the fit trace, with its per-batch
         # `stream.ingest` uploads (if any) as children
         _step_no[0] += 1
-        # profile_pass: opt-in jax.profiler capture of ONE designated pass
-        # (observability.profile_dir / profile_pass — docs/design.md §6f)
-        with profile_pass("logreg.step", _step_no[0]):
-            with obs_span("logreg.step", {"pass": _step_no[0]}):
-                return _value_and_grad(params_flat)
+        with obs_span("logreg.step", {"pass": _step_no[0]}):
+            return _value_and_grad(params_flat)
 
     def _value_and_grad(params_flat: np.ndarray):
         params = jnp.asarray(params_flat.reshape(shape).astype(dt))
@@ -1010,11 +1007,8 @@ def _streaming_kmeans_fit(
         )
         # one Lloyd iteration == one full streamed pass: a `kmeans.step` span
         # per pass (pass 1 carries the jit compile of the batch accumulator),
-        # with any `stream.ingest` uploads it triggered as child spans; the
-        # designated pass may additionally capture a jax.profiler trace
-        # (observability.profile_dir — docs/design.md §6f)
-        with profile_pass("kmeans.step", it + 1), \
-                obs_span("kmeans.step", {"pass": it + 1, "compile": it == 0}):
+        # with any `stream.ingest` uploads it triggered as child spans
+        with obs_span("kmeans.step", {"pass": it + 1, "compile": it == 0}):
             carry = _accumulate_stream(
                 carry,
                 lambda c, batch, centers=centers: _accum_kmeans(

@@ -3,7 +3,7 @@
 # §6d). This module USED to own two flat process-global dicts (span seconds,
 # event counts); it now forwards every call to the typed metrics registry and
 # run-scope fan-out in `observability/`, keeping the historical surface —
-# span / add_time / span_totals / reset_spans / count / counter_totals /
+# span / span_totals / reset_spans / count / counter_totals /
 # reset_counters / trace — byte-compatible for every existing call site and
 # test. New instrumentation should import `spark_rapids_ml_tpu.observability`
 # directly (Counter/Gauge/Histogram with labels, structured spans, events).
@@ -42,14 +42,6 @@ def span(name: str, verbose: bool = False) -> Iterator[None]:
     finally:
         if verbose and node is not None:
             _logger.info("%s: %.3fs", name, node.duration_s)
-
-
-def add_time(name: str, seconds: float) -> None:
-    """Accumulate seconds under a span name WITHOUT the profiler annotation or
-    trace-node machinery — the per-batch fallback for call sites that already
-    timed themselves. Also feeds the same-named latency histogram, so every
-    add_time site gains a per-batch distribution for free."""
-    _obs.add_span_total(name, seconds)
 
 
 def span_totals() -> Dict[str, float]:

@@ -392,8 +392,7 @@ class MicroBatcher:
         }
         dev = (bnode.attrs or {}).get("device") if bnode is not None else None
         if dev:
-            for k in ("flops", "bytes", "comm_bytes", "calls",
-                      "roofline", "intensity_flop_per_byte", "mfu"):
+            for k in ("flops", "bytes", "comm_bytes", "calls"):
                 if dev.get(k) is not None:
                     exec_attrs[k] = dev[k]
             kernels = dev.get("kernels") or {}

@@ -325,6 +325,46 @@ def test_metrics_consumed_unemitted_and_near_miss(tmp_path):
     assert len(hits) == 1 and "cache.hitz_total" in hits[0].message  # noqa: metrics/consumed-unemitted — fixture token, not a real consumer
 
 
+def test_metrics_benchmark_metric_file_reads_are_consumptions(tmp_path):
+    """cellbench/metrics/*.json is the consumer the driver runs: a metric file
+    that names a counter, a label key or a span the library does not emit is
+    reported; the ones that match are not."""
+    _write(tmp_path, "spark_rapids_ml_tpu/ops/lloydish.py", """
+        from ..observability.runs import counter_inc, span
+
+        def lloyd():
+            with span("kmeans.lloyd"):
+                counter_inc("kmeans.lloyd_path", 1, path="xla")
+    """)
+    _write(tmp_path, "spark_rapids_ml_tpu/observability/registry.py", """
+        # srml-metric: span.seconds{span}
+    """)
+    def metric(name, body):
+        _write(tmp_path, f"cellbench/metrics/{name}.json", json.dumps(body))
+
+    metric("ok_counter", {"kind": "report_counter_per_op",
+                          "counter": "kmeans.lloyd_path", "labels": {"path": "xla"}})
+    metric("ok_span", {"kind": "report_counter_per_op", "counter": "span.seconds",
+                       "labels": {"span": "kmeans.lloyd"}})
+    metric("ok_filled_in", {"kind": "span_seconds_per_op", "span": "{estimator}.prepare"})
+    renamed = "kmeans.lloyd_route"  # noqa: metrics/consumed-unemitted — fixture token, not a real consumer
+    metric("renamed_counter", {"kind": "report_counter_per_op",
+                               "counter": renamed, "labels": {"path": "xla"}})
+    renamed = "kmeans.loop"  # noqa: metrics/consumed-unemitted — fixture token, not a real consumer
+    metric("renamed_span", {"kind": "report_counter_per_op", "counter": "span.seconds",
+                            "labels": {"span": renamed}})
+    metric("wrong_label", {"kind": "report_counter_per_op",
+                           "counter": "kmeans.lloyd_path", "labels": {"route": "xla"}})
+    _, findings, _ = _run(tmp_path)
+    got = {(f.rule, f.rel.rsplit("/", 1)[-1]) for f in findings
+           if f.rel.startswith("cellbench/")}
+    assert got == {
+        ("metrics/consumed-unemitted", "renamed_counter.json"),
+        ("metrics/consumed-unemitted", "renamed_span.json"),
+        ("metrics/label-mismatch", "wrong_label.json"),
+    }
+
+
 def test_metrics_label_mismatch_and_subset_near_miss(tmp_path):
     _write(tmp_path, "spark_rapids_ml_tpu/ops/a.py", """
         from ..observability.runs import counter_inc

@@ -1,0 +1,255 @@
+"""The program's side of the benchmark's contract: every span, counter, label,
+model attribute and program name that a per-layer metric of `BENCHMARK.json`
+reads of the program is there, under that name, when the cell's estimator runs
+through the public path.
+
+`cellbench/` is the one yardstick and no tier-1 test imports it; this file only
+READS `BENCHMARK.json`, `cellbench/metrics/*.json` and `cellbench/configs/*.json`.
+One case per (per-layer metric, cell) pair whose metric file reads the program
+(kinds `report_counter_per_op`, `counter_delta`, `counter_delta_per_op`,
+`span_seconds_per_op`, `model_attribute`, `roofline`); the other kinds
+(`device_busy_per_op`, `mfu`, `upload_floor`) read the device trace and the
+harness's own clock. A metric file of a kind this file does not know fails by
+name: give the new kind its reader's check here.
+
+Each cell runs once per module at toy size on the CPU (rows, columns, `k`,
+`maxIter` cut; every other parameter the configuration's own): a cold operation,
+then a warm one, which is the one read, as the harness reads a window after its
+warm-up. Counts and names only: nothing here is a speed.
+"""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROWS, COLS, MAX_K, MAX_ITER = 512, 16, 4, 3
+
+# kinds that read the harness's clock or the device trace, nothing of the program
+HARNESS_KINDS = {"device_busy_per_op", "mfu", "upload_floor"}
+
+
+def _load(*rel):
+    with open(os.path.join(REPO, *rel)) as f:
+        return json.load(f)
+
+
+BENCH = _load("BENCHMARK.json")
+CELLS = {w["name"]: w for w in BENCH["workloads"]}
+CONFIG_FILES = {c["name"]: c["file"] for c in BENCH["configs"]}
+
+
+def _pairs():
+    out = []
+    for entry in BENCH["per_layer"]:
+        spec = _load("cellbench", "metrics", entry["name"] + ".json")
+        if spec["kind"] in HARNESS_KINDS:
+            continue
+        for cell in entry["workloads"]:
+            out.append(pytest.param(entry, spec, cell, id=f"{entry['name']}-{cell}"))
+    return out
+
+
+# ------------------------------------------------------------- the toy runs
+
+
+def _build(cfg, chips):
+    """The configuration's estimator with its own parameters, sizes cut."""
+    from spark_rapids_ml_tpu.clustering import KMeans
+    from spark_rapids_ml_tpu.feature import PCA
+
+    families = {"kmeans": KMeans, "kmeans_wide": KMeans, "pca": PCA}
+    if cfg["estimator"] not in families:
+        pytest.fail(f"configuration names estimator family {cfg['estimator']!r}: "
+                    "tests/test_benchmark_contract.py does not know how to build it")
+    params = dict(cfg["params"])
+    if "k" in params:
+        params["k"] = min(int(params["k"]), MAX_K)
+    if "maxIter" in params:
+        params["maxIter"] = min(int(params["maxIter"]), MAX_ITER)
+    if cfg.get("seed_param"):
+        params[cfg["seed_param"]] = 7
+    return families[cfg["estimator"]](num_workers=chips, **params)
+
+
+def _programs_called(counters):
+    """Optimized-HLO text of every executable of the kernels the run called."""
+    from spark_rapids_ml_tpu.observability import device, split_label_key
+
+    called = {split_label_key(key)[1].get("kernel") for key in counters
+              if split_label_key(key)[0] == "device.kernel_calls"}
+    return [entry["exe"].as_text() for kernel in list(device._kernels)
+            if kernel.name in called for entry in list(kernel._cache.values())]
+
+
+def _run_cell(cell_name):
+    from spark_rapids_ml_tpu import config, profiling
+    from spark_rapids_ml_tpu.observability import device
+    from spark_rapids_ml_tpu.observability.export import iter_spans
+
+    cell = CELLS[cell_name]
+    cfg = _load(CONFIG_FILES[cell["config"]])
+    if cell["traffic"] not in ("fit", "transform"):
+        pytest.fail(f"cell {cell_name} has traffic {cell['traffic']!r}: "
+                    "tests/test_benchmark_contract.py knows fit and transform")
+    X = np.random.default_rng(30).normal(size=(ROWS, COLS)).astype(np.float32)
+    # on the chip `auto` takes the Pallas Gram kernel; off it only "1" does
+    # (interpret mode), and the benchmark's `_xtx_jit` lives in that kernel
+    settings = {**cfg.get("program_settings", {}), "pallas_xtwx": "1"}
+    for key, value in settings.items():
+        config.set(key, value)
+    device.reset_device_plane()  # the cold operation compiles, whatever ran before
+    try:
+        estimator = _build(cfg, int(cell["chips"]))
+        model = estimator.fit(X) if cell["traffic"] == "transform" else None
+
+        def operate():
+            return estimator.fit(X) if cell["traffic"] == "fit" else model.transform(X)
+
+        cold_before = dict(profiling.counter_totals())
+        operate()
+        before = dict(profiling.counter_totals())
+        result = operate()
+        after = dict(profiling.counter_totals())
+    finally:
+        for key in settings:
+            config.unset(key)
+    fitted = result if cell["traffic"] == "fit" else model
+    report = fitted.fit_report_ if cell["traffic"] == "fit" else fitted.transform_report_
+    counters = dict(report["metrics"].get("counters") or {})
+    return {
+        "estimator": type(estimator).__name__,
+        "traffic": cell["traffic"],
+        "model": fitted,
+        "report_counters": counters,
+        "spans": {s["name"] for s in iter_spans(report)},
+        "cold_before": cold_before, "before": before, "after": after,
+        "programs": _programs_called(counters),
+    }
+
+
+@pytest.fixture(scope="module")
+def runs():
+    cache = {}
+
+    def get(cell_name):
+        if cell_name not in cache:
+            cache[cell_name] = _run_cell(cell_name)
+        return cache[cell_name]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def emitted():
+    """The metric names the package's source emits, as the analyzer's
+    metric-contract pass harvests them."""
+    import sys
+
+    sys.path.insert(0, REPO)
+    from tools.analysis.core import ProjectIndex
+    from tools.analysis.metrics import _harvest_emissions
+
+    index = ProjectIndex(REPO, targets=("spark_rapids_ml_tpu",))
+    return {e.name for mod in index.files for e in _harvest_emissions(mod)}
+
+
+# ------------------------------------------- what each reader kind would find
+
+
+def _total(counters, name, labels):
+    from spark_rapids_ml_tpu.observability import split_label_key
+
+    total = 0.0
+    for key, value in counters.items():
+        base, have = split_label_key(key)
+        if base == name and all(have.get(k) == v for k, v in labels.items()):
+            total += float(value)
+    return total
+
+
+def _check_report_counter(entry, spec, run, emitted):
+    from spark_rapids_ml_tpu.observability import label_key
+
+    assert run["traffic"] == "fit", "report_counter_per_op reads fit_report_"
+    labels = spec.get("labels", {})
+    key = label_key(spec["counter"], labels)
+    counters = run["report_counters"]
+    if not labels and _total(counters, spec["counter"], {}) == 0:
+        # a counter read whole, of something that did not happen in this fit
+        # (the in-core path makes no host copy): the reader reads 0, as on the
+        # chip. The name must still be one the package emits.
+        assert spec["counter"] in emitted, (
+            f"{entry['name']}: no library code emits `{spec['counter']}`")
+        return
+    assert key in counters, (
+        f"{entry['name']}: no `{key}` in fit_report_: "
+        f"{sorted(k for k in counters if k.startswith(spec['counter']))}")
+    assert counters[key] > 0, (key, counters[key])
+    if entry["unit"] == "count":
+        assert counters[key] == 1, f"{key} counts {counters[key]} in one fit"
+
+
+def _check_counter_delta(entry, spec, run, emitted):
+    name = spec["counter"]
+    cold = _total(run["before"], name, {}) - _total(run["cold_before"], name, {})
+    assert cold > 0, f"{entry['name']}: the cold operation added nothing to `{name}`"
+    warm = _total(run["after"], name, {}) - _total(run["before"], name, {})
+    assert warm == 0, f"{entry['name']}: the warm operation added {warm} to `{name}`"
+
+
+def _check_counter_delta_per_op(entry, spec, run, emitted):
+    from spark_rapids_ml_tpu.observability import label_key
+
+    key = label_key(spec["counter"], spec.get("labels", {}))
+    assert key in run["after"], f"{entry['name']}: no `{key}` among the process's counters"
+    assert run["after"][key] - run["before"].get(key, 0) > 0, key
+
+
+def _check_span(entry, spec, run, emitted):
+    name = spec["span"].format(estimator=run["estimator"])
+    assert name in run["spans"], (
+        f"{entry['name']}: no span `{name}` in the run's trace tree: {sorted(run['spans'])}")
+
+
+# what the estimator families under cellbench/estimators put under each name
+MODEL_ATTRIBUTES = {"n_iter": lambda model: int(model.summary.numIter)}
+
+
+def _check_model_attribute(entry, spec, run, emitted):
+    if spec["attribute"] not in MODEL_ATTRIBUTES:
+        pytest.fail(f"{entry['name']} reads model attribute {spec['attribute']!r}: "
+                    "tests/test_benchmark_contract.py does not know where the program keeps it")
+    assert 1 <= MODEL_ATTRIBUTES[spec["attribute"]](run["model"]) <= MAX_ITER
+
+
+def _check_roofline(entry, spec, run, emitted):
+    # the trace names a compiled program `jit_<function>` and an operation
+    # inside it by the scope `jit(<function>)` of the jit it was traced under
+    if "program" in spec:
+        want = f"HloModule jit_{spec['program']}"
+    else:
+        want = f"jit({spec['op']})"
+    assert any(want in text for text in run["programs"]), (
+        f"{entry['name']}: none of the {len(run['programs'])} programs the run "
+        f"called carries `{want}`")
+
+
+CHECKS = {
+    "report_counter_per_op": _check_report_counter,
+    "counter_delta": _check_counter_delta,
+    "counter_delta_per_op": _check_counter_delta_per_op,
+    "span_seconds_per_op": _check_span,
+    "model_attribute": _check_model_attribute,
+    "roofline": _check_roofline,
+}
+
+
+@pytest.mark.parametrize("entry,spec,cell", _pairs())
+def test_the_program_gives_the_metric_what_it_reads(entry, spec, cell, runs, emitted):
+    if spec["kind"] not in CHECKS:
+        pytest.fail(f"cellbench/metrics/{entry['name']}.json is of kind {spec['kind']!r}: "
+                    "tests/test_benchmark_contract.py has no check for that reader")
+    CHECKS[spec["kind"]](entry, spec, runs(cell), emitted)

@@ -1,6 +1,6 @@
 """Communication plane (observability/comm.py — docs/design.md §6h): HLO
 collective extraction (synthetic + real sharded programs), compiled_kernel
-collective accounting and span comm-roofline attribution, per-rank skew math,
+collective accounting and span byte attribution, per-rank skew math,
 straggler events + gauges, the /runs/<id>/ranks barrier-timeline endpoint,
 postmortem rank timelines, the delay-fault straggler injection site, and the
 transform_partials.jsonl rotation contract."""
@@ -37,7 +37,6 @@ def _clean():
     for key in (
         "observability.straggler_threshold",
         "observability.straggler_min_wall_s",
-        "observability.peak_ici_bw",
         "observability.http_port",
         "observability.metrics_dir",
         "observability.max_report_bytes",
@@ -167,41 +166,13 @@ def test_compiled_kernel_records_collectives_and_span_comm(n_devices):
            if k.startswith("comm.collective_ops")}
     assert ops and all("kind=all_reduce" in k for k in ops), counters
     assert any(k.startswith("comm.collective_bytes") for k in counters)
-    # span attribution + comm roofline verdict on close
+    # the collective's bytes are attributed to the span that made the call
     from spark_rapids_ml_tpu.observability.export import iter_spans
 
     step = next(s for s in iter_spans(rep) if s["name"] == "comm.step")
-    d = step["attrs"]["device"]
-    assert d["comm_bytes"] > 0
-    assert d["achieved_ici_bw"] > 0
-    assert d["comm_frac"] is not None and d["comm_frac"] > 0
-    assert isinstance(d["comm_bound"], bool)
-    # the device report section carries the ICI peak column + the records
-    assert rep["device"]["peak_ici_bw"] > 0
+    assert step["attrs"]["device"]["comm_bytes"] > 0
+    # the device report section carries the records
     assert any("collectives" in r for r in rep["device"]["kernels"])
-
-
-def test_peak_ici_override_and_classify_verdicts():
-    config.set("observability.peak_ici_bw", 123.0)
-    assert dev.platform_ici_bw() == 123.0
-    config.unset("observability.peak_ici_bw")
-    assert dev.platform_ici_bw() > 0  # table column
-
-    # comm-dominated: tiny compute, big payload over a slow link
-    v = comm.classify_comm(
-        flops=10.0, hbm_bytes=10.0, comm_bytes=1e9, duration_s=1.0,
-        peak_flops=1e12, peak_bw=1e12, peak_ici_bw=1e9,
-    )
-    assert v["comm_bound"] is True and v["comm_frac"] == pytest.approx(1.0)
-    # compute-dominated: huge flops, negligible payload
-    v = comm.classify_comm(
-        flops=1e12, hbm_bytes=10.0, comm_bytes=100.0, duration_s=1.0,
-        peak_flops=1e12, peak_bw=1e12, peak_ici_bw=1e9,
-    )
-    assert v["comm_bound"] is False
-    # no payload: verdict absent, never a division error
-    v = comm.classify_comm(0.0, 0.0, 0.0, 1.0, 1e12, 1e12, 1e9)
-    assert v["comm_frac"] is None and v["comm_bound"] is False
 
 
 # --------------------------------------------------------------- skew math
@@ -439,53 +410,6 @@ def test_sleep_plus_raise_clause_rejected_at_parse():
     # each alone stays legal
     assert parse_fault_spec("ingest:sleep=0.1")[0].sleep == 0.1
     assert parse_fault_spec("ingest:raise=TimeoutError")[0].exc is TimeoutError
-
-
-# ----------------------------------------------------- bench_check comm gate
-
-
-def _load_bench_check():
-    import importlib.util
-    from pathlib import Path
-
-    path = Path(__file__).resolve().parent.parent / "ci" / "bench_check.py"
-    spec = importlib.util.spec_from_file_location("bench_check_comm", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_check_extracts_comm_keys_and_applies_noise_floor(tmp_path):
-    import json as _json
-
-    bc = _load_bench_check()
-
-    def artifact(name, secondary):
-        doc = {"parsed": {"secondary": dict(secondary, platform="cpu")}}
-        (tmp_path / name).write_text(_json.dumps(doc))
-
-    # near-zero comm_frac jitter (the CPU-mesh regime) must NOT regress even
-    # in strict mode: a ratio of two noise samples is meaningless
-    artifact("BENCH_r01.json", {"kmeans_bench_secs": 10.0,
-                                "kmeans_comm_frac": 1.2e-6,
-                                "kmeans_rank_skew": 1.05})
-    artifact("BENCH_r02.json", {"kmeans_bench_secs": 10.0,
-                                "kmeans_comm_frac": 1.9e-6,
-                                "kmeans_rank_skew": 1.35})
-    assert bc.check(str(tmp_path), threshold=0.25) == 0
-    rows = bc.compare(
-        bc.extract(str(tmp_path / "BENCH_r01.json")),
-        bc.extract(str(tmp_path / "BENCH_r02.json")),
-    )
-    verdicts = {r["scenario"]: r["verdict"] for r in rows}
-    assert verdicts["kmeans_comm_frac"] == "ok (below noise floor)"
-    assert verdicts["kmeans_rank_skew"] == "ok (below noise floor)"
-    # above the floor the keys ARE ratio-gated, lower-is-better
-    artifact("BENCH_r03.json", {"kmeans_bench_secs": 10.0,
-                                "kmeans_comm_frac": 0.10})
-    artifact("BENCH_r04.json", {"kmeans_bench_secs": 10.0,
-                                "kmeans_comm_frac": 0.30})
-    assert bc.check(str(tmp_path), threshold=0.25) == 1
 
 
 # ------------------------------------------------- sidecar rotation contract

@@ -1,15 +1,10 @@
 """Device-performance plane (observability/device.py — docs/design.md §6f):
-compiled_kernel cost/memory-analysis capture + compile accounting, roofline
-span attribution, HBM telemetry graceful degrade, histogram quantile edges,
-corrupt-JSONL tolerance, scenario summaries, the profiler hook, and the
-direction-aware *_mfu bench gate."""
+compiled_kernel cost/memory-analysis capture + compile accounting, span cost
+attribution, HBM telemetry graceful degrade, histogram quantile edges and
+corrupt-JSONL tolerance."""
 
-import importlib.util
-import json
 import logging
 import os
-import types
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -39,10 +34,6 @@ def _clean():
     for key in (
         "observability.device_enabled",
         "observability.hbm_sampling",
-        "observability.peak_flops",
-        "observability.peak_bw",
-        "observability.profile_dir",
-        "observability.profile_pass",
         "observability.metrics_dir",
         "stream_threshold_bytes",
         "stream_batch_rows",
@@ -155,6 +146,57 @@ def test_trace_epoch_rekeys_cache_on_parity_precision_change():
         config.unset("parity_precision")
 
 
+@pytest.mark.parametrize("first,second", [("high", "highest"), ("highest", "high")])
+def test_trace_epoch_change_retraces_a_kernel_already_traced_at_the_shape(first, second):
+    """Re-keying the AOT cache is not enough: `jit.lower()` answers from jit's
+    own trace cache, so a kernel that has traced a shape at one
+    `parity_precision` used to compile the SAME program again under the new
+    key. After the change of epoch the kernel must run what a kernel that has
+    never traced compiles at the second precision. The CPU backend computes
+    every f32 dot in full, so the bits cannot tell two precisions apart here;
+    the compiled program's `operand_precision` can, and it is what decides the
+    bits on the chip."""
+    import re
+
+    from spark_rapids_ml_tpu.ops._precision import pdot
+
+    def make(name):
+        @obs.compiled_kernel(name)
+        def gram(x):
+            return pdot(x.T, x)
+
+        return gram
+
+    def last_program(kernel):
+        return list(kernel._cache.values())[-1]["exe"].as_text()
+
+    def precisions(text):
+        return re.findall(r"operand_precision=\{(\w+),(\w+)\}", text)
+
+    x = jnp.asarray(
+        np.random.default_rng(0).normal(size=(64, 8)).astype(np.float32))
+    try:
+        config.set("parity_precision", first)
+        kernel = make("t.epoch_retrace")
+        kernel(x)
+        program_first = last_program(kernel)
+        config.set("parity_precision", second)
+        out_second = np.asarray(kernel(x))
+        program_second = last_program(kernel)
+        fresh = make("t.epoch_fresh")  # has traced nothing: a new process's kernel
+        out_fresh = np.asarray(fresh(x))
+        assert precisions(program_first) == [(first, first)]
+        assert precisions(program_second) == [(second, second)]
+        assert precisions(last_program(fresh)) == [(second, second)]
+        np.testing.assert_array_equal(out_second, out_fresh)
+        # an unchanged epoch re-traces and compiles nothing
+        kernel(x)
+        assert dev.compile_count("t.epoch_retrace") == 2
+        assert "HloModule jit_gram" in program_second  # the trace readers' name
+    finally:
+        config.unset("parity_precision")
+
+
 def test_compiled_kernel_memory_analysis_breakdown():
     @obs.compiled_kernel("t.add")
     def add(a, b):
@@ -206,79 +248,6 @@ def test_compiled_kernel_donation_preserved():
     assert c.is_deleted()  # the donated input really was consumed
 
 
-def test_span_attribution_and_roofline_classification():
-    config.set("observability.peak_flops", 1e12)
-    config.set("observability.peak_bw", 1e9)  # ridge = 1000 flops/byte
-
-    @obs.compiled_kernel("t.memk")
-    def memk(a):
-        return a + 1.0  # OI << 1000: memory-bound
-
-    with obs.fit_run("DevTest") as run:
-        with obs.span("devtest.step"):
-            memk(jnp.ones((256, 64)))
-    rep = run.report()
-    step = next(s for s in iter_spans(rep) if s["name"] == "devtest.step")
-    d = step["attrs"]["device"]
-    assert d["flops"] > 0 and d["bytes"] > 0 and d["calls"] == 1
-    assert d["roofline_bound"] == "memory"
-    assert 0.0 <= d["mfu"] and d["roofline_frac"] >= 0.0
-    assert d["kernels"] == {"t.memk": 1}
-    # compute-bound classification with an inverted ridge
-    config.set("observability.peak_flops", 1e12)
-    config.set("observability.peak_bw", 1e15)  # ridge ~ 1e-3
-    with obs.fit_run("DevTest2") as run2:
-        with obs.span("devtest.step2"):
-            memk(jnp.ones((256, 64)))
-    rep2 = run2.report()
-    step2 = next(s for s in iter_spans(rep2) if s["name"] == "devtest.step2")
-    assert step2["attrs"]["device"]["roofline_bound"] == "compute"
-
-
-def test_peak_overrides_and_platform_table():
-    flops, bw, platform = dev.platform_peaks()
-    assert flops > 0 and bw > 0
-    config.set("observability.peak_flops", 123.0)
-    config.set("observability.peak_bw", 456.0)
-    assert dev.platform_peaks()[:2] == (123.0, 456.0)
-
-
-def _FakeDevice(platform, device_kind):
-    return types.SimpleNamespace(platform=platform, device_kind=device_kind)
-
-
-@pytest.mark.parametrize("kind,flops,bw", [
-    ("TPU v5 lite", 98e12, 819e9),
-    ("TPU v5e", 98e12, 819e9),
-    ("TPU v4", 137e12, 1228e9),
-])
-def test_known_tpu_device_kinds_resolve_to_their_row(monkeypatch, kind, flops, bw):
-    monkeypatch.setattr(jax, "local_devices", lambda: [_FakeDevice("tpu", kind)])
-    assert dev.platform_peaks() == (flops, bw, "tpu")
-
-
-@pytest.mark.parametrize("platform,kind", [
-    ("tpu", "TPU v9 hyper"),  # a TPU no row names: there is no catch-all row
-    ("tpu", ""),
-    ("rocm", "MI300"),  # an unknown platform no longer gets the CPU row
-])
-def test_unknown_device_kind_raises_where_peaks_are_asked_for(
-        monkeypatch, platform, kind):
-    """A device that is not in the table is an error, not a default: a roofline
-    share against another chip's peaks is a wrong number."""
-    monkeypatch.setattr(
-        jax, "local_devices", lambda: [_FakeDevice(platform, kind)])
-    with pytest.raises(ValueError, match="no peak-table row"):
-        dev.platform_peaks()
-    with pytest.raises(ValueError, match="no peak-table row"):
-        dev.platform_ici_bw()
-    # an explicit override does not rescue it: the platform label itself
-    # comes from the row
-    config.set("observability.peak_flops", 1.0)
-    with pytest.raises(ValueError, match="no peak-table row"):
-        dev.platform_peaks()
-
-
 # ----------------------------------------- streamed fit end-to-end (satellite)
 
 
@@ -296,7 +265,7 @@ def _streamed_kmeans_model():
     )
 
 
-def test_streamed_kmeans_spans_carry_cost_and_roofline():
+def test_streamed_kmeans_spans_carry_cost():
     model = _streamed_kmeans_model()
     rep = model.fit_report_
     steps = [s for s in iter_spans(rep) if s["name"] == "kmeans.step"]
@@ -304,7 +273,6 @@ def test_streamed_kmeans_spans_carry_cost_and_roofline():
     for s in steps:
         d = s["attrs"]["device"]
         assert d["flops"] > 0 and d["bytes"] > 0
-        assert d["roofline_bound"] in ("compute", "memory")
         assert "streaming.accum_kmeans" in d["kernels"]
     # compile counters match the distinct shape signatures the device plane
     # recorded per kernel — the accounting the recompile sentinel trusts
@@ -317,14 +285,6 @@ def test_streamed_kmeans_spans_carry_cost_and_roofline():
         r["kernel"] == "streaming.accum_kmeans" and r["flops"] > 0
         for r in rep["device"]["kernels"]
     )
-
-
-def test_scenario_summary_measures_mfu():
-    model = _streamed_kmeans_model()
-    summary = dev.scenario_summary(model.fit_report_, wall_s=1.0)
-    assert summary["mfu"] > 0.0
-    assert summary["roofline_bound"] in ("compute", "memory")
-    assert summary["device_flops"] > 0 and summary["device_compiles"] >= 1
 
 
 # ------------------------------------------------- HBM telemetry (satellite)
@@ -414,76 +374,3 @@ def test_load_run_reports_skips_corrupt_lines(tmp_path):
     # a fully missing file still raises (pre-existing contract)
     with pytest.raises(OSError):
         load_run_reports(str(tmp_path / "nope.jsonl"))
-
-
-# ----------------------------------------------------------- profiler hook
-
-
-def test_profile_pass_gating(tmp_path):
-    # no profile_dir: no-op, no trace artifacts
-    with dev.profile_pass("site.a", 2):
-        pass
-    assert list(tmp_path.iterdir()) == []
-    config.set("observability.profile_dir", str(tmp_path))
-    config.set("observability.profile_pass", 2)
-    with dev.profile_pass("site.a", 1):  # wrong pass: no capture
-        pass
-    assert list(tmp_path.iterdir()) == []
-    with dev.profile_pass("site.a", 2):  # designated pass: captures
-        jnp.ones((8,)).block_until_ready()
-    out = tmp_path / "site_a"
-    assert out.exists()
-    assert profiling.counter_totals()["device.profile_captures{site=site.a}"] == 1
-    with dev.profile_pass("site.a", 2):  # once per site per process
-        pass
-    assert profiling.counter_totals()["device.profile_captures{site=site.a}"] == 1
-
-
-# ------------------------------------------------ bench gate: *_mfu direction
-
-
-def _load_bench_check():
-    path = Path(__file__).resolve().parent.parent / "ci" / "bench_check.py"
-    spec = importlib.util.spec_from_file_location("bench_check_mfu", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _bench_artifact(tmp_path, name, secondary):
-    doc = {"parsed": {"secondary": dict(secondary, platform="cpu")}}
-    p = tmp_path / name
-    p.write_text(json.dumps(doc))
-    return p
-
-
-def test_bench_check_mfu_is_higher_is_better(tmp_path):
-    bc = _load_bench_check()
-    _bench_artifact(tmp_path, "BENCH_r01.json",
-                    {"pca_bench_secs": 10.0, "pca_mfu": 0.10})
-    _bench_artifact(tmp_path, "BENCH_r02.json",
-                    {"pca_bench_secs": 10.0, "pca_mfu": 0.04})
-    # mfu DROPPED 60%: regression even though wall time is unchanged
-    assert bc.check(str(tmp_path), threshold=0.25) == 1
-    # mfu RISING is an improvement, never a failure
-    _bench_artifact(tmp_path, "BENCH_r03.json",
-                    {"pca_bench_secs": 10.0, "pca_mfu": 0.50})
-    assert bc.check(str(tmp_path), threshold=0.25) == 0
-    rows = bc.compare(
-        bc.extract(str(tmp_path / "BENCH_r02.json")),
-        bc.extract(str(tmp_path / "BENCH_r03.json")),
-    )
-    mfu_row = next(r for r in rows if r["scenario"] == "pca_mfu")
-    assert mfu_row["verdict"] == "improved"
-    secs_row = next(r for r in rows if r["scenario"] == "pca")
-    assert secs_row["verdict"] == "ok"
-
-
-def test_bench_check_extracts_mfu_from_escaped_tail(tmp_path):
-    bc = _load_bench_check()
-    # truncated wrapper whose bench line lives in an escaped `tail` string —
-    # every quote appears as \" in the raw text and the regex sweep must hit
-    raw = '{"tail": "{\\"pca_mfu\\": 0.031, \\"platform\\": \\"cpu\\"'
-    (tmp_path / "BENCH_r01.json").write_text(raw)
-    art = bc.extract(str(tmp_path / "BENCH_r01.json"))
-    assert art["scenarios"].get("pca_mfu") == pytest.approx(0.031)

@@ -104,9 +104,9 @@ def test_span_records_timing_when_body_raises():
     assert profiling.counter_totals()["span.errors{span=failing.pass}"] == 1
 
 
-def test_add_time_feeds_histogram():
-    profiling.add_time("batch.s", 0.002)
-    profiling.add_time("batch.s", 0.004)
+def test_add_span_total_feeds_histogram():
+    obs.add_span_total("batch.s", 0.002)
+    obs.add_span_total("batch.s", 0.004)
     assert profiling.span_totals()["batch.s"] == pytest.approx(0.006)
     st = obs.global_registry().histogram("batch.s").state()
     assert st["count"] == 2
@@ -335,7 +335,7 @@ def test_streamed_fit_report_acceptance(n_devices, tmp_path):
     assert c["stream.upload_batches"] == n_batches  # pass 2+ uploaded ZERO
     assert c["cache.hits"] == (len(steps) - 1) * n_batches
     hists = rep["metrics"]["histograms"]
-    assert hists["stream.ingest_s.ingest"]["count"] == n_batches
+    assert hists["stream.ingest_s.ingest{status=ok}"]["count"] == n_batches
     assert rep["metrics"]["gauges"]["cache.bytes_resident"] == 0
     # JSONL round-trip carries the same report
     back = obs.load_run_reports(str(tmp_path))

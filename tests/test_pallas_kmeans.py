@@ -39,8 +39,8 @@ def test_fused_fit_matches_lloyd_fit(n_devices, precision):
     """Parity gate for the fused kernel at every precision tier: same centers,
     inertia AND effective iteration count as the XLA parity path. On the CPU
     interpret backend the DEFAULT tier is f32-exact too, so all three tiers must
-    match exactly; on real TPU the HIGHEST (6-pass) tier is the parity claim —
-    bench.py asserts the same live (fused_parity_ok)."""
+    match exactly; on real TPU the HIGHEST (6-pass) tier is the parity claim
+    (chip_smoke.py checks the kernel against XLA and numpy there)."""
     import jax
 
     X, init = _blobs(n=512)

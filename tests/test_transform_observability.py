@@ -2,12 +2,10 @@
 §6e): TransformRun scopes + transform_reports.jsonl, the instrumented predict
 dispatch with shape-bucket telemetry and the recompile sentinel, per-partition
 sidecar aggregation of the distributed transform plane, CV trial traces,
-JSONL rotation, histogram quantiles, and the bench regression gate."""
+JSONL rotation and histogram quantiles."""
 
-import importlib.util
 import json
 import os
-from pathlib import Path
 
 import numpy as np
 import pandas as pd
@@ -438,76 +436,3 @@ def test_histogram_quantile_bucket_edges():
 def test_histogram_quantile_inf_bucket_clamps():
     st = {"count": 4, "sum": 100.0, "buckets": [0, 0, 4]}
     assert interpolate_quantile(st, 0.99, [1.0, 2.0]) == pytest.approx(2.0)
-
-
-# ------------------------------------------------------------- bench gate unit
-
-
-def _load_bench_check():
-    path = Path(__file__).resolve().parent.parent / "ci" / "bench_check.py"
-    spec = importlib.util.spec_from_file_location("bench_check", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _write_round(root, n, platform, scenarios):
-    secondary = {f"{k}_bench_secs": v for k, v in scenarios.items()}
-    secondary["platform"] = platform
-    doc = {
-        "n": n,
-        "rc": 0,
-        "tail": "truncated..." + json.dumps({"secondary": secondary}),
-        "parsed": {"metric": "m", "value": 1.0, "secondary": secondary},
-    }
-    (Path(root) / f"BENCH_r{n:02d}.json").write_text(json.dumps(doc))
-
-
-def test_bench_check_detects_regression(tmp_path, capsys):
-    bc = _load_bench_check()
-    _write_round(tmp_path, 1, "cpu", {"kmeans": 10.0, "pca": 2.0})
-    _write_round(tmp_path, 2, "cpu", {"kmeans": 13.0, "pca": 2.1})
-    assert bc.check(str(tmp_path)) == 1  # kmeans +30% > 25%
-    out = capsys.readouterr().out
-    assert "REGRESSED" in out and "kmeans" in out
-    assert bc.check(str(tmp_path), advisory=True) == 0
-
-
-def test_bench_check_passes_within_threshold_and_platform_mismatch(tmp_path):
-    bc = _load_bench_check()
-    _write_round(tmp_path, 1, "cpu", {"kmeans": 10.0})
-    _write_round(tmp_path, 2, "cpu", {"kmeans": 12.0, "umap": 5.0})
-    assert bc.check(str(tmp_path)) == 0  # +20% within threshold; umap new-only
-    _write_round(tmp_path, 3, "tpu", {"kmeans": 99.0})
-    assert bc.check(str(tmp_path)) == 0  # cpu -> tpu: not comparable
-
-
-def test_bench_check_extracts_from_escaped_tail(tmp_path):
-    bc = _load_bench_check()
-    # the real artifact shape: the bench line lives only in the `tail` string,
-    # whose quotes are escaped at the FILE level (json.dumps of the doc) — a
-    # raw-text regex would miss it; extract() must scan the decoded tail
-    doc = {
-        "n": 4,
-        "tail": '... "kmeans_headline_bench_secs": 7.6, "platform": "cpu" ...',
-        "parsed": None,
-    }
-    p = Path(tmp_path) / "BENCH_r04.json"
-    p.write_text(json.dumps(doc))
-    info = bc.extract(str(p))
-    assert info["scenarios"] == {"kmeans_headline": 7.6}
-    assert info["platform"] == "cpu"
-
-
-def test_bench_check_extracts_from_truncated_artifact(tmp_path):
-    """A wrapper truncated mid-tail is not valid JSON; the regex sweep over the
-    raw text must still find the ESCAPED `\\"name_bench_secs\\"` form."""
-    bc = _load_bench_check()
-    p = Path(tmp_path) / "BENCH_r05.json"
-    p.write_text(
-        '{"n": 5, "tail": "... \\"pca_bench_secs\\": 1.4, '
-        '\\"platform\\": \\"cpu\\", ...'  # cut off mid-string: json.loads fails
-    )
-    info = bc.extract(str(p))
-    assert info["scenarios"] == {"pca": 1.4}
-    assert info["platform"] == "cpu"

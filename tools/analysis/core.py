@@ -36,7 +36,6 @@ DEFAULT_TARGETS = (
     "tests",
     "ci",
     "tools",
-    "bench.py",
     "__graft_entry__.py",
 )
 

@@ -79,7 +79,7 @@ register_rule(
     """
 Those dicts no longer exist — profiling.py is a compat shim over the typed
 registry (observability/registry.py); historically direct mutation corrupted
-scoped FitRun accounting. Go through the public surface (count/add_time/
+scoped FitRun accounting. Go through the public surface (count/span/
 counter_totals/...) or the observability API.
 """,
 )

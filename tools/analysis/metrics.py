@@ -6,7 +6,7 @@
 #
 #   EMISSIONS — every Counter/Gauge/Histogram/span write with a literal name:
 #     the fan-out helpers (counter_inc/gauge_set/gauge_inc/gauge_dec/observe/
-#     add_span_total), the legacy shims (count/add_time/legacy_count), the
+#     add_span_total), the legacy shims (count/legacy_count), the
 #     registry getters (.counter("x")/.gauge("x")/.histogram("x")), and
 #     span("x"). Label KEYS come from the call's keyword arguments. A dynamic
 #     site (non-literal name) can declare itself with a pragma comment:
@@ -14,8 +14,10 @@
 #
 #   CONSUMPTIONS — metric-shaped string literals (`ns.name` dotted grammar,
 #     first segment restricted to an emitted namespace) in the consumer
-#     corpora: tests/, ci/ (bench_check + test.sh heredoc smokes), bench.py,
-#     benchmark/, and the docs (docs/*.md, README.md).
+#     corpora: tests/, ci/ (the test.sh heredoc smokes), benchmark/, and the
+#     docs (docs/*.md, README.md); and what the benchmark's metric files
+#     (cellbench/metrics/*.json) read of the program: `counter` with the keys
+#     of `labels`, and the span a `span` label or field names.
 #
 # and reports three contract breaks:
 #   metrics/consumed-unemitted — a consumer references a name no library code
@@ -29,10 +31,17 @@
 from __future__ import annotations
 
 import ast
+import json
 import re
 from typing import Dict, List, Optional, Set, Tuple
 
-from .core import AnalysisContext, ModuleInfo, register_pass, register_rule
+from .core import (
+    AnalysisContext,
+    Finding,
+    ModuleInfo,
+    register_pass,
+    register_rule,
+)
 
 register_rule(
     "metrics/consumed-unemitted",
@@ -52,9 +61,12 @@ register_rule(
     """
 Two emission sites write the same metric name with label-key sets where
 neither is a subset of the other. The exported series splits into disjoint
-key spaces: `name{a=}` and `name{b=}` never aggregate, dashboards and
-bench_check greps silently see half the data. Pick one label schema per name
-(a site may ADD labels to a common core, but not swap them).
+key spaces: `name{a=}` and `name{b=}` never aggregate, and a dashboard's
+greps silently see half the data. Pick one label schema per name
+(a site may ADD labels to a common core, but not swap them). Also reported: a
+benchmark metric file (cellbench/metrics/*.json) that reads a counter by a
+label key no emission site of that counter writes — its reader would sum
+nothing.
 """,
 )
 register_rule(
@@ -78,7 +90,6 @@ _EMIT_FUNCS: Dict[str, Set[str]] = {
     "add_span_total": set(),
     "legacy_count": set(),
     "count": set(),
-    "add_time": set(),
     "span": set(),
 }
 
@@ -121,7 +132,8 @@ _SHELL_CONSUMERS = ("ci/test.sh",)
 
 # consumer python files: anything under these roots reads metrics back
 _CONSUMER_PREFIXES = ("tests/", "ci/", "benchmark/")
-_CONSUMER_FILES = ("bench.py",)
+# the benchmark's per-layer metric files: data, read by cellbench/readers
+_BENCH_METRIC_DIR = "cellbench/metrics"
 
 
 class _Emission:
@@ -219,7 +231,7 @@ def _harvest_emissions(mod: ModuleInfo) -> List[_Emission]:
 
 
 def _is_consumer(mod: ModuleInfo) -> bool:
-    return mod.rel.startswith(_CONSUMER_PREFIXES) or mod.rel in _CONSUMER_FILES
+    return mod.rel.startswith(_CONSUMER_PREFIXES)
 
 
 # dotted vocabularies that share the metric grammar but are NOT metrics:
@@ -283,6 +295,39 @@ def _harvest_py_consumptions(mod: ModuleInfo,
     return out
 
 
+def _bench_metric_reads(ctx: AnalysisContext
+                        ) -> List[Tuple[str, int, str, str, Optional[Set[str]]]]:
+    """(file, line, line text, name, label keys or None) for everything the
+    benchmark's metric files read of the program: the `counter` with the keys
+    of `labels`, and the span named by a `span` label or a `span` field (a
+    name with a `{placeholder}` is filled in at run time and is not checked
+    here: tests/test_benchmark_contract.py runs it)."""
+    out: List[Tuple[str, int, str, str, Optional[Set[str]]]] = []
+    root = ctx.index.root / _BENCH_METRIC_DIR
+    for path in sorted(root.glob("*.json")) if root.is_dir() else ():
+        rel = path.relative_to(ctx.index.root).as_posix()
+        text = ctx.index.read_text(rel) or ""
+        try:
+            spec = json.loads(text)
+        except ValueError:
+            continue
+        if not isinstance(spec, dict):
+            continue
+        lines = text.splitlines()
+
+        def read(name: str, keys: Optional[Set[str]]) -> None:
+            at = next((i for i, ln in enumerate(lines) if f'"{name}"' in ln), 0)
+            out.append((rel, at + 1, lines[at] if lines else "", name, keys))
+
+        labels = spec.get("labels") or {}
+        if isinstance(spec.get("counter"), str):
+            read(spec["counter"], set(labels))
+        for name in (spec.get("span"), labels.get("span")):
+            if isinstance(name, str) and "{" not in name:
+                read(name, None)
+    return out
+
+
 @register_pass("metrics")
 def run(ctx: AnalysisContext) -> None:
     emissions: List[_Emission] = []
@@ -338,14 +383,38 @@ def run(ctx: AnalysisContext) -> None:
                 if base.split(".")[0] in namespaces and not satisfied(base):
                     # shell corpus has no ModuleInfo; report against test.sh
                     # through a synthetic one-off emit
-                    from .core import Finding
-
                     ctx.findings.append(Finding(
                         "metrics/consumed-unemitted", rel, i,
                         f"`{base}` is consumed here but no library code "
                         "emits it",
                         line_text=line,
                     ))
+
+    # ---- the benchmark's metric files: exact names, and label keys some
+    # emission site of the counter writes (a dynamic site may write any)
+    for rel, line, line_text, name, keys in _bench_metric_reads(ctx):
+        sites = emitted.get(name)
+        if sites is None:
+            ctx.findings.append(Finding(
+                "metrics/consumed-unemitted", rel, line,
+                f"`{name}` is read by this benchmark metric file but no "
+                "library code emits it: the per-layer metric reads nothing "
+                "on the chip",
+                line_text=line_text,
+            ))
+        elif keys and not any(
+            e.labels is None or keys <= set(e.labels) for e in sites
+        ):
+            ctx.findings.append(Finding(
+                "metrics/label-mismatch", rel, line,
+                f"`{name}` is read here by labels "
+                f"{{{', '.join(sorted(keys))}}} but no emission site writes "
+                "them all: "
+                + "; ".join(sorted(
+                    f"{{{', '.join(e.labels or ())}}} at {e.rel}:{e.line}"
+                    for e in sites)),
+                line_text=line_text,
+            ))
 
     # ---- label-set conflicts (static sites only; None == dynamic, skipped)
     for name in sorted(emitted):
