@@ -570,7 +570,7 @@ def check_lloyd(unit_mask: bool, n: int = 262_181, d: int = 128, k: int = 128,
     c_p, in_p, it_p = lloyd_fit_pallas(
         Xj, wj, init, 0.0, 3, mesh=None, interpret=_interpret_default(),
         precision=prec, unit_mask=unit_mask)
-    c_x, in_x, it_x = lloyd_fit(Xj, wj, init, 0.0, 3)
+    c_x, in_x, it_x, _ = lloyd_fit(Xj, wj, init, 0.0, 3)
     # unstructured data: a handful of boundary rows may flip between two
     # f32-parity matmul emulations; one flipped row of ~2,000 moves a center
     # coordinate by ~5e-4, so atol 5e-3 allows a few and rejects bf16 (~0.1)
@@ -580,6 +580,50 @@ def check_lloyd(unit_mask: bool, n: int = 262_181, d: int = 128, k: int = 128,
     _check(err <= 5e-3 and rel_in <= 1e-4,
            f"Lloyd (unit_mask={unit_mask}): centers {err:.2e} inertia {rel_in:.2e}")
     return f"centers vs XLA {err:.1e}, inertia rel {rel_in:.1e}"
+
+
+def check_assign3(rows_a_device: int = 32_768, d: int = 1024, k: int = 512) -> str:
+    """The XLA Lloyd program's three-pass ranking with its six-pass second
+    look (`ops/kmeans.py::_assign3`) over whatever mesh the process has: under
+    `shard_map` on several chips, plain on one. Against the six-pass program
+    on the same placed table."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_ml_tpu.observability import collective_summary
+    from spark_rapids_ml_tpu.ops.kmeans import _second_look_rows, lloyd_fit
+    from spark_rapids_ml_tpu.parallel.partitioner import active_partitioner
+
+    part = active_partitioner()
+    n_dev = int(part.mesh.devices.size)
+    n = rows_a_device * n_dev
+    X, _ = make_blobs(n, d, k, SEED + 17)
+    w = np.ones(n, np.float32)
+    w[-50:] = 0.0
+    Xj, wj = part.shard(X), part.shard(w)
+    init = jnp.asarray(X[np.random.default_rng(SEED + 19).choice(n, k, replace=False)])
+    recheck, mesh = _second_look_rows(Xj, k, False, False)
+    _check(recheck == rows_a_device // 16 and (mesh is not None) == (n_dev > 1),
+           f"assign3: the shape test gave recheck={recheck}, mesh={mesh} on {n_dev} devices")
+    args = (Xj, wj, init, 0.0, 6)
+    c_3, in_3, it_3, looks = lloyd_fit(*args, unit_weight=True, recheck=recheck, mesh=mesh)
+    c_6, in_6, it_6, _ = lloyd_fit(*args, unit_weight=True)
+    looks = np.asarray(jax.device_get(looks))
+    err = float(np.abs(np.asarray(c_3) - np.asarray(c_6)).max())
+    rel_in = abs(float(in_3) - float(in_6)) / float(in_6)
+    _check(int(it_3) == int(it_6), "assign3: iteration counts differ")
+    _check(looks.shape == (n_dev, 2) and (looks >= 0).all() and looks.sum() > 0,
+           f"assign3: the second look's counts are {looks.tolist()}")
+    # a row six passes cannot rank may lie with either centre: one such row of
+    # ~256 moves a centre coordinate by ~1e-2; bit-equal on every run so far
+    _check(err <= 1e-5 and rel_in <= 1e-6,
+           f"assign3: centres {err:.2e} inertia {rel_in:.2e} off the six-pass program")
+    exe = lloyd_fit.lower(*args, unit_weight=True, recheck=recheck, mesh=mesh).compile()
+    kinds = collective_summary(exe.as_text())
+    _check(set(kinds) <= {"all_reduce"}, f"assign3: a row crosses a shard: {kinds}")
+    return (f"{n_dev} row shard(s) of {rows_a_device} x {d}, k={k}: centres vs six passes "
+            f"{err:.1e}, [rows looked at again, iterations whole] a shard {looks.tolist()}, "
+            f"collectives {({kind: v['ops'] for kind, v in kinds.items()})}")
 
 
 def check_assign(n: int = 262_181, d: int = 128, k: int = 128,
@@ -741,7 +785,9 @@ def check_histograms(n: int = 100_013, d: int = 64, gate: bool = True) -> str:
     return f"node-bin vs XLA {e_h:.1e}, segment vs XLA {e_g:.1e}"
 
 
-# the nine pl.pallas_call sites: xtwx (2), kmeans (2), select (3), histogram (2)
+# the nine pl.pallas_call sites: xtwx (2), kmeans (2), select (3), histogram
+# (2); and the XLA Lloyd program's three-pass assignment, which runs per row
+# shard (`python chip_smoke.py assign3` on four chips runs that check alone)
 KERNEL_CHECKS: List[Tuple[str, Callable[[], str]]] = [
     ("pallas_xtwx xtx (Gram) d=128", lambda: check_gram(128)),
     ("pallas_xtwx xtx (Gram) d=512", lambda: check_gram(512)),
@@ -754,14 +800,19 @@ KERNEL_CHECKS: List[Tuple[str, Callable[[], str]]] = [
     ("pallas_select top-k scan k=32", lambda: check_topk(32)),
     ("pallas_select count (DBSCAN)", check_count),
     ("pallas_histogram node-bin + segment, 32 bins", check_histograms),
+    ("xla lloyd assign3 k=512 d=1024", check_assign3),
 ]
 
 
 def leg_kernels(st: Dict[str, Any]) -> None:
+    only = st.get("only") or [""]
     for name, fn in KERNEL_CHECKS:
+        if not any(word in name for word in only):
+            continue
         t0 = time.perf_counter()
         detail = fn()
-        _say(f"  {name}: compiled by Mosaic, {detail} "
+        by = "XLA" if name.startswith("xla") else "Mosaic"
+        _say(f"  {name}: compiled by {by}, {detail} "
              f"[{time.perf_counter() - t0:.1f}s cold set-up, not a speed]")
 
 
@@ -835,24 +886,30 @@ def main() -> int:
     _say(f"chip_smoke: mesh {dict(mesh.shape)} over devices "
          f"{[d.id for d in mesh.devices.flat]}")
 
-    t0 = time.perf_counter()
-    X, true_centers = make_blobs(N_ROWS, N_COLS, KMEANS_K, SEED)
-    _say(f"[data] {N_ROWS} x {N_COLS} float32 seeded blobs on the host "
-         f"({X.nbytes / 1e9:.2f} GB) in {time.perf_counter() - t0:.1f}s")
-
-    st: Dict[str, Any] = {"X": X, "true_centers": true_centers}
-    for name, leg in (("fit", leg_fit), ("transform", leg_transform),
-                      ("serve", leg_serve), ("stream", leg_stream),
-                      ("kernels", leg_kernels), ("after", leg_after)):
+    only = sys.argv[1:]  # words of kernel checks' names: those alone, no other leg
+    legs = (("fit", leg_fit), ("transform", leg_transform),
+            ("serve", leg_serve), ("stream", leg_stream),
+            ("kernels", leg_kernels), ("after", leg_after))
+    st: Dict[str, Any] = {"only": only}
+    if only:
+        legs = legs[4:]
+    else:
+        t0 = time.perf_counter()
+        X, true_centers = make_blobs(N_ROWS, N_COLS, KMEANS_K, SEED)
+        _say(f"[data] {N_ROWS} x {N_COLS} float32 seeded blobs on the host "
+             f"({X.nbytes / 1e9:.2f} GB) in {time.perf_counter() - t0:.1f}s")
+        st.update(X=X, true_centers=true_centers)
+    for name, leg in legs:
         _say(f"[{name}]")
         t0 = time.perf_counter()
         leg(st)
         _say(f"[{name}] passed; {time.perf_counter() - t0:.1f}s wall incl. "
              "compile and host reference (cold set-up information, not a speed)")
 
-    _say(f"chip_smoke: placement by device id (allocator activity per leg): "
-         f"fit={st['fit_touched']} transform={st['transform_touched']} "
-         f"serve={st['serve_touched']} of devices {[d.id for d in devices]}")
+    if not only:
+        _say(f"chip_smoke: placement by device id (allocator activity per leg): "
+             f"fit={st['fit_touched']} transform={st['transform_touched']} "
+             f"serve={st['serve_touched']} of devices {[d.id for d in devices]}")
     _say(f"chip_smoke: persistent compile cache hits={cache_events['hits']} "
          f"misses={cache_events['misses']}; total set-up wall "
          f"{time.perf_counter() - t_start:.1f}s (cold when hits=0)")

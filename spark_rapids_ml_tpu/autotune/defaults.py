@@ -54,6 +54,24 @@ MIN_ITEM_TILE = 128
 FUSED_ASSIGN_MIN_K = 128
 LLOYD_FUSED_MIN_K = 128
 
+# The XLA Lloyd program (ops/kmeans.py::lloyd_fit) ranks its assignment at
+# three MXU passes, with a six-pass second look at the rows three passes
+# cannot rank, from LLOYD_FUSED_MIN_K centres on where centres x columns
+# reaches this. NOT a tunable, no knob reads it. What the mechanism saves grows
+# with k.d (three passes of 2.k.d FLOP a row); what it adds does not (a sort
+# of the rows, a gather, a wider reduction): on a v5e it is 2 to 3 times
+# SLOWER at k.d of 2,560 to 32,768, 1.10 to 1.19 at 60,000 to 384,000
+# (k=128 at 3000 columns: 1.095, six passes still half hidden behind the read
+# of X), and 0.67 to 0.85 of the six-pass iteration at every one of the six
+# shapes of 524,288 from 64 to 2048 columns, 0.74 at 3,000,000
+# (tools/lloyd_assign_bench.py; PERF.md §6, PR 31). One row in
+# LLOYD_RECHECK_SHARE of a row shard may take the second look in an iteration;
+# more undecided rows send the iteration to six passes whole, so the share
+# sets a speed and never a result (a thirty-second read the same fit time at
+# the cell's shape, 2.433 against 2.442 s).
+LLOYD_ASSIGN3_MIN_WORK = 1 << 19
+LLOYD_RECHECK_SHARE = 16
+
 # Rows per centre (ops/kmeans.py::assign_counts). NOT tunables, no knob reads
 # them. Up to COUNT_DEVICE_MAX_CENTERS centres the device compares every row
 # with every centre id and sums the hits in one fusion: work of n x centres,

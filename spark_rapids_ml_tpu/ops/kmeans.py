@@ -25,13 +25,19 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..autotune.defaults import COUNT_BLOCK_SPLIT, COUNT_DEVICE_MAX_CENTERS
+from ..autotune.defaults import (
+    COUNT_BLOCK_SPLIT,
+    COUNT_DEVICE_MAX_CENTERS,
+    LLOYD_ASSIGN3_MIN_WORK,
+    LLOYD_FUSED_MIN_K,
+    LLOYD_RECHECK_SHARE,
+)
 from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
 from ._precision import FAST, parity_precision, pdot
@@ -39,11 +45,13 @@ from .selection import top_k_max
 
 
 @functools.partial(jax.jit, static_argnames=("fast",))
-def _sq_dists(X: jax.Array, centers: jax.Array, fast: bool = False) -> jax.Array:
+def _sq_dists(X: jax.Array, centers: jax.Array, fast: bool = False,
+              x2: Optional[jax.Array] = None) -> jax.Array:
     """(n, k) squared euclidean distances; the MXU hot loop. `fast=True` runs the
     cross-term matmul at MXU bf16 precision — valid for ASSIGNMENT (ranking) use;
-    anything feeding model attributes stays at parity precision."""
-    x2 = jnp.sum(X * X, axis=1, keepdims=True)
+    anything feeding model attributes stays at parity precision. `x2`: the
+    squared row norms (n,), where the caller has them."""
+    x2 = jnp.sum(X * X, axis=1, keepdims=True) if x2 is None else x2[:, None]
     c2 = jnp.sum(centers * centers, axis=1)
     cross = jnp.matmul(X, centers.T, precision=FAST) if fast else pdot(X, centers.T)
     d2 = x2 - 2.0 * cross + c2
@@ -55,8 +63,146 @@ def _normalize_rows(X: jax.Array) -> jax.Array:
     return X / jnp.maximum(norms, 1e-12)
 
 
+# Interval ranking (lloyd_fit's assignment where `recheck` > 0; docs/design.md
+# §6d). float32 on the MXU is a sum of bf16 products: a = hi + mid + lo, three
+# bf16 numbers of eight significand bits each. Six passes (HIGHEST) sum hi.hi,
+# hi.mid, mid.hi, mid.mid, hi.lo, lo.hi; three (HIGH) the first three. The
+# parts are widest where every cut truncates: |hi| <= |a|, |mid| < 2^-7 |a|,
+# |lo| < 2^-15 |a|, and what three passes drop of one product x_l c_l is then
+# under (2^-14 + 2 * 2^-15) |x_l c_l| = 2^-13 |x_l c_l|, all of one sign where
+# the products are; sum_l |x_l c_l| <= |x| |c| (Cauchy-Schwarz): EPS3. A cut
+# that rounds to nearest leaves less (3 * 2^-16 if all do), so the bound holds
+# however the hardware cuts. The chip cuts hi by truncation (tools/
+# lloyd_assign_bench.py `passes`: 5.8 * 2^-16 on rows where rounding would
+# read 2^-30) and reads 3.9 * 2^-16 on rows built to reach 8 * 2^-16.
+_EPS3 = 2.0**-13
+_U32 = 2.0**-24  # float32's unit roundoff
+
+
+def _cross3(X: jax.Array, Ct: jax.Array) -> jax.Array:
+    """The ranking cross term X.C^T at three bf16 passes."""
+    return jnp.matmul(X, Ct, precision=jax.lax.Precision.HIGH)
+
+
+def _rank3(X: jax.Array, x2: jax.Array, centers: jax.Array, c2: jax.Array):
+    """Nearest centre of every row from three-pass distances, and which rows
+    those decide. Returns (labels (n,) int32, decided (n,) bool).
+
+    d3 = x2 - 2.cross3 + c2 stands for an interval [d3 - e, d3 + e] with
+
+        e_ij = 2 (EPS3 + d u) |x_i| |c_j|  +  8 u (x2_i + c2_j),   u = 2^-24.
+
+    The first term bounds, in the cross term doubled, what the three dropped
+    products sum to (EPS3, above) and the float32 accumulation of the three
+    kept ones: d.u is the textbook bound of a length-d float32 dot product
+    summed in sequence, which a blocked accumulation such as the MXU's
+    (chunks of the contraction summed apart, then chunks and passes added)
+    stays under. The second covers the two roundings of `x2 - 2.cross + c2`,
+    every intermediate of which is under 2 (x2 + c2) in magnitude, once for
+    d3 and once for the interval's ends. So the exact sum D6 of the very
+    products six passes keep lies inside the interval, and where exactly one
+    centre's interval reaches below the least upper end (second-least lower
+    end > least upper end) that centre is the nearest by D6. The six-pass
+    program rounds D6 in float32 by the same d.u and can differ from it only
+    on rows its own rounding decides: float32 ties, which have no six-pass
+    answer to agree with. Nothing here is fitted to a table.
+
+    `max(d2, 0)` of the six-pass expression is monotone; it can merge two
+    centres at 0 (the lower index then wins), so a row is decided only if the
+    second-least lower end also exceeds 0. NaNs compare false: undecided."""
+    d = X.shape[1]
+    cross = _cross3(X, centers.T)
+    d3 = x2[:, None] - 2.0 * cross + c2
+    e = (2.0 * (_EPS3 + d * _U32)) * jnp.sqrt(x2)[:, None] * jnp.sqrt(c2) + (
+        8.0 * _U32
+    ) * (x2[:, None] + c2)
+    lo, hi = d3 - e, d3 + e
+    inf = jnp.array(jnp.inf, lo.dtype)
+    ids = jax.lax.broadcasted_iota(jnp.int32, lo.shape, 1)
+
+    def least(a, b):
+        # (least lower end, its centre, second-least lower end, least upper end)
+        a_lo, a_id, a_lo2, a_hi = a
+        b_lo, b_id, b_lo2, b_hi = b
+        a_first = (a_lo < b_lo) | ((a_lo == b_lo) & (a_id < b_id))
+        return (
+            jnp.minimum(a_lo, b_lo),
+            jnp.where(a_first, a_id, b_id),
+            jnp.minimum(jnp.maximum(a_lo, b_lo), jnp.minimum(a_lo2, b_lo2)),
+            jnp.minimum(a_hi, b_hi),
+        )
+
+    # one variadic reduction, fused into the matmul as the argmin was: no
+    # (n, k) array reaches HBM
+    lo1, labels, lo2, hi1 = jax.lax.reduce(
+        (lo, ids, jnp.full_like(lo, inf), hi),
+        (inf, jnp.array(np.iinfo(np.int32).max, jnp.int32), inf, inf),
+        least, (1,),
+    )
+    # `lo1 <= hi1` holds of every row (each lower end is under its upper end);
+    # it is asked so that the least lower end has a reader. Left unread, the
+    # chip's compiler stores that output in bfloat16, and in some programs
+    # keeps the reduction's running least there between tiles: the other
+    # three outputs are then ranked against a bfloat16 number, and one row
+    # in eleven came out decided for the wrong centre (PERF.md §6, PR 31)
+    return labels, (lo2 > jnp.maximum(hi1, 0.0)) & (lo1 <= hi1)
+
+
+def _assign3(X: jax.Array, x2: jax.Array, w: jax.Array, centers: jax.Array,
+             six_only: jax.Array, recheck: int):
+    """One row shard's assignment: ranked at three passes, and up to `recheck`
+    undecided rows decided by the six-pass expression (gathered, ranked against
+    all centres, scattered back). More undecided rows than that and the shard
+    runs the six-pass assignment whole: `recheck` sets the speed, never the
+    result. A shard that has stopped ranking (`six_only`, (1,) bool: see
+    `lloyd_fit`) skips the three passes and holds every row undecided.
+    Returns (labels (n,), [[rows given the second look, 1 if six passes ran
+    whole]] int32 (1, 2))."""
+    n = X.shape[0]
+
+    def ranked(_):
+        return _rank3(X, x2, centers, jnp.sum(centers * centers, axis=1))
+
+    def unranked(_):
+        return jnp.zeros((n,), jnp.int32), jnp.zeros((n,), bool)
+
+    labels, decided = jax.lax.cond(six_only[0], unranked, ranked, None)
+    undecided = ~decided & (w > 0)  # padding has no label to get right
+    n_undecided = jnp.sum(undecided, dtype=jnp.int32)
+    overflow = n_undecided > recheck
+
+    def six_pass(X, x2):
+        return jnp.argmin(_sq_dists(X, centers, x2=x2), axis=1).astype(jnp.int32)
+
+    def whole(_):
+        return six_pass(X, x2)
+
+    def second_look(_):
+        rows = jnp.arange(n, dtype=jnp.int32)
+        # undecided rows first, in order; then decided ones fill the block
+        # (their six-pass label is the one they have), so no index repeats.
+        # The row norms ride through the sort: gathered one by one they cost
+        # twice the sort
+        picked, x2_picked = jax.lax.sort(
+            (jnp.where(undecided, rows, rows + n), x2), num_keys=1, is_stable=False
+        )
+        picked, x2_picked = picked[:recheck], x2_picked[:recheck]
+        picked = jnp.where(picked >= n, picked - n, picked)
+        exact = six_pass(X[picked], x2_picked)
+        return labels.at[picked].set(exact, unique_indices=True)
+
+    # the barrier keeps the compiler from moving the update's one-hot into
+    # both branches, where it becomes an (n, k) array in HBM
+    labels = jax.lax.optimization_barrier(
+        jax.lax.cond(overflow, whole, second_look, None)
+    )
+    looked = jnp.where(overflow, 0, n_undecided)
+    return labels, jnp.stack([looked, overflow.astype(jnp.int32)])[None, :]
+
+
 @compiled_kernel("kmeans.lloyd_fit",
-                 static_argnames=("max_iter", "cosine", "fast_math", "unit_weight"))
+                 static_argnames=("max_iter", "cosine", "fast_math", "unit_weight",
+                                  "recheck", "mesh"))
 def lloyd_fit(
     X: jax.Array,
     w: jax.Array,
@@ -66,12 +212,14 @@ def lloyd_fit(
     cosine: bool = False,
     fast_math: bool = False,
     unit_weight: bool = False,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    recheck: int = 0,
+    mesh=None,
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Lloyd iterations until max center movement² <= tol² or max_iter.
 
-    Returns (centers, inertia, n_iter). Convergence on per-center movement matches
-    Spark's KMeans semantics (the reference remaps tol=0 to a tiny epsilon,
-    clustering.py:84-141 — callers do that remap).
+    Returns (centers, inertia, n_iter, second_look). Convergence on per-center
+    movement matches Spark's KMeans semantics (the reference remaps tol=0 to a
+    tiny epsilon, clustering.py:84-141 — callers do that remap).
 
     cosine=True runs spherical kmeans (Spark's distanceMeasure='cosine'): callers
     pass row-normalized X; centers are re-normalized every update and the cost is
@@ -88,8 +236,28 @@ def lloyd_fit(
     bf16, so the bf16 passes that multiply its mid and low parts multiply
     zeros, and the passes kept are the very products the full matmul sums
     (three of six under `highest`). With arbitrary weights the product is no
-    bf16 number and the contraction stays (parity, parity), as the distance
-    matmul, the counts and every inertia do in both cases."""
+    bf16 number and the contraction stays (parity, parity), as the counts and
+    every inertia do in both cases.
+
+    recheck > 0 (euclidean, six-pass parity, no fast_math: `_lloyd` decides)
+    ranks the assignment at three passes and gives up to `recheck` rows a row
+    shard the six-pass second look (`_rank3`, `_assign3`); the labels are the
+    six-pass assignment's but for float32 ties. `mesh` names the mesh X's
+    rows are sharded over, so that each shard selects, gathers and branches
+    by itself and no row crosses a shard. Cosine keeps six passes: its
+    interval is another one (1 - x̂·ĉ, both of unit norm) and no cell runs it.
+
+    The interval's width grows with |x||c| and the centres' margins do not, so
+    whether three passes decide most rows is a property of the TABLE, which
+    no shape shows: on rows far from the origin against their spread (an
+    uncentred table) every row is undecided, and an iteration that ranks and
+    then runs six passes whole pays for nine. The first iteration may (a
+    random start puts every centre on a row). A shard that holds more than
+    `recheck` undecided rows in any later one stops ranking for the rest of
+    the fit: the loss is bounded by two rankings a fit, whatever the table.
+    `second_look`, int32 (row shards, 2): rows given the second look and
+    iterations that ran six passes whole (by overflow, or after one), summed
+    over the fit (zeros without `recheck`)."""
     k = init_centers.shape[0]
     if cosine:
         init_centers = _normalize_rows(init_centers)
@@ -106,15 +274,42 @@ def lloyd_fit(
             return 1.0 - pdot(X, centers.T)
         return _sq_dists(X, centers, fast=fast)
 
+    assign3, shards = None, 1
+    if recheck:
+        x2 = jnp.sum(X * X, axis=1)  # once a fit: the table does not change
+        assign3 = functools.partial(_assign3, recheck=recheck)
+        if mesh is not None:
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as P
+
+            from ..parallel.mesh import DATA_AXIS
+
+            shards = mesh.shape[DATA_AXIS]
+            assign3 = shard_map(
+                assign3, mesh=mesh,
+                in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS), P(),
+                          P(DATA_AXIS)),
+                out_specs=(P(DATA_AXIS), P(DATA_AXIS, None)), check_vma=False,
+            )
+
     def cond(state):
-        _, _, it, shift2 = state
+        _, _, it, shift2, _, _ = state
         return jnp.logical_and(it < max_iter, shift2 > tol * tol)
 
     def body(state):
-        centers, _, it, _ = state
-        d2 = _dists(centers, fast=fast_math)
-        assign = jnp.argmin(d2, axis=1)
-        min_d2 = jnp.min(d2, axis=1)
+        centers, _, it, _, looks, six_only = state
+        if assign3 is not None:
+            assign, look = assign3(X, x2, w, centers, six_only)
+            six_only = six_only | ((look[:, 1] > 0) & (it > 0))
+            # saturating: a count that stops is better than one that wraps
+            looks = looks + jnp.minimum(look, np.iinfo(np.int32).max - looks)
+            # the loop's own inertia is overwritten below; its sum stays, and
+            # with it the shape of the loop's all-reduce on a mesh
+            min_d2 = jnp.zeros((), X.dtype)
+        else:
+            d2 = _dists(centers, fast=fast_math)
+            assign = jnp.argmin(d2, axis=1)
+            min_d2 = jnp.min(d2, axis=1)
         onehot = jax.nn.one_hot(assign, k, dtype=X.dtype) * w[:, None]
         counts = jnp.sum(onehot, axis=0)
         sums = jnp.matmul(onehot.T, X, precision=update_precision)
@@ -125,13 +320,16 @@ def lloyd_fit(
             new_centers = _normalize_rows(new_centers)
         inertia = jnp.sum(w * min_d2)
         shift2 = jnp.max(jnp.sum((new_centers - centers) ** 2, axis=1))
-        return new_centers, inertia, it + 1, shift2
+        return new_centers, inertia, it + 1, shift2, looks, six_only
 
-    init_state = (init_centers, jnp.array(0.0, X.dtype), 0, jnp.array(jnp.inf, X.dtype))
-    centers, inertia, n_iter, _ = jax.lax.while_loop(cond, body, init_state)
+    init_state = (
+        init_centers, jnp.array(0.0, X.dtype), 0, jnp.array(jnp.inf, X.dtype),
+        jnp.zeros((shards, 2), jnp.int32), jnp.zeros((shards,), bool),
+    )
+    centers, inertia, n_iter, _, looks, _ = jax.lax.while_loop(cond, body, init_state)
     # inertia reported against the final centers
     inertia = jnp.sum(w * jnp.min(_dists(centers), axis=1))
-    return centers, inertia, n_iter
+    return centers, inertia, n_iter, looks
 
 
 @compiled_kernel("kmeans.predict", static_argnames=("cosine",))
@@ -491,6 +689,36 @@ def kmeans_fit(
         return _lloyd(X, w, init_centers, k, max_iter, tol, cosine, unit_weight)
 
 
+def _second_look_rows(X: jax.Array, k: int, cosine: bool, fast_math: bool):
+    """(`recheck`, `mesh`) for `lloyd_fit`: how many rows a row shard may take
+    the six-pass second look, 0 where the assignment stays as it is: cosine
+    (another interval), `fast_math` (one pass, by choice), a stated precision
+    other than six passes, and the shapes at which three passes do not pay.
+    Decided from what the fit is, as the update's precision pair is from
+    `unit_weight`: the three passes saved are 2.k.d FLOP a row each, while the
+    selection, the gather and the wider reduction cost what they cost at any
+    k.d, and under 128 centres six passes hide behind the one read of X (the
+    sweep of PERF.md §6 PR 31: slower under k.d of 384,000, faster from
+    524,288). On a mesh the rows have to be split evenly over the data axis
+    and nothing else, so that a shard holds whole rows."""
+    from ..parallel.mesh import DATA_AXIS
+    from ..parallel.partitioner import mesh_of
+
+    if cosine or fast_math or parity_precision() != jax.lax.Precision.HIGHEST:
+        return 0, None
+    if k < LLOYD_FUSED_MIN_K or k * X.shape[1] < LLOYD_ASSIGN3_MIN_WORK:
+        return 0, None
+    mesh = mesh_of(X)
+    shard = X.sharding.shard_shape(X.shape)
+    if mesh is not None and mesh.devices.size > 1:
+        if (shard[0] * mesh.shape.get(DATA_AXIS, 0), shard[1]) != X.shape:
+            return 0, None
+    else:
+        mesh = None
+    recheck = shard[0] // LLOYD_RECHECK_SHARE
+    return recheck, mesh if recheck else None
+
+
 def _lloyd(
     X: jax.Array,
     w: jax.Array,
@@ -602,16 +830,32 @@ def _lloyd(
         )
     else:
         _obs.counter_inc("kmeans.lloyd_path", 1, path="xla")
+        fast_math = bool(_config.get("fast_math"))
+        six_pass = parity_precision() == jax.lax.Precision.HIGHEST
         # bf16 passes of the update matmul at the stated float32 precision:
         # three where lloyd_fit may take the one-hot operand as exact
-        exact_onehot = (
-            unit_weight and parity_precision() == jax.lax.Precision.HIGHEST
-        )
+        exact_onehot = unit_weight and six_pass
         counter_inc("kmeans.lloyd_update", 1, passes=3 if exact_onehot else 6)
-        centers, inertia, n_iter = lloyd_fit(
-            X, w, init_centers, float(tol), int(max_iter), cosine=cosine,
-            fast_math=bool(_config.get("fast_math")), unit_weight=unit_weight,
+        # and of the assignment: three, with a six-pass second look, where
+        # `_second_look_rows` says so
+        recheck, mesh = _second_look_rows(X, k, cosine, fast_math)
+        counter_inc(
+            "kmeans.lloyd_assign", 1,
+            passes=1 if fast_math else 3 if recheck or not six_pass else 6,
         )
+        centers, inertia, n_iter, looks = lloyd_fit(
+            X, w, init_centers, float(tol), int(max_iter), cosine=cosine,
+            fast_math=fast_math, unit_weight=unit_weight, recheck=recheck,
+            mesh=mesh,
+        )
+        if recheck:
+            if not looks.is_fully_addressable:
+                from ..parallel.partitioner import replicate_rows
+
+                looks = replicate_rows(looks, mesh)
+            rows, whole = np.asarray(looks).sum(axis=0)
+            counter_inc("kmeans.lloyd_recheck_rows", int(rows))
+            counter_inc("kmeans.lloyd_recheck_overflow", int(whole))
     centers = np.asarray(centers)
     counter_inc("d2h.bytes", int(centers.nbytes), site="fit.centers")
     return {

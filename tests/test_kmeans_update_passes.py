@@ -83,8 +83,8 @@ def test_a_zero_one_mask_fits_the_same_under_either_pair(seed, cosine):
         X[:REAL] /= np.linalg.norm(X[:REAL], axis=1, keepdims=True)
     init = X[rng.choice(REAL, K, replace=False)]
     args = (jnp.asarray(X), jnp.asarray(_mask()), jnp.asarray(init), 0.0, 8)
-    c6, inertia6, n6 = lloyd_fit(*args, cosine=cosine)
-    c3, inertia3, n3 = lloyd_fit(*args, cosine=cosine, unit_weight=True)
+    c6, inertia6, n6, _ = lloyd_fit(*args, cosine=cosine)
+    c3, inertia3, n3, _ = lloyd_fit(*args, cosine=cosine, unit_weight=True)
     np.testing.assert_array_equal(np.asarray(c3), np.asarray(c6))
     assert float(inertia3) == float(inertia6) and int(n3) == int(n6)
     # and padding is padding: the real rows alone give the same centres

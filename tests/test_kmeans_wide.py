@@ -91,9 +91,13 @@ def _lloyd_counters(monkeypatch, backend, k, d, cosine=False):
 
 def test_a_wide_fit_on_a_tpu_says_it_left_the_fused_kernel_for_vmem(monkeypatch):
     got = _lloyd_counters(monkeypatch, "tpu", k=1000, d=3000)
+    # the rows given the second look are the table's; the rest is the shape's
+    assert 0 <= got.pop("kmeans.lloyd_recheck_rows") <= 1024
     assert got == {"kmeans.lloyd_gate{fused=0,reason=vmem}": 1,
                    "kmeans.lloyd_path{path=xla}": 1,
                    "kmeans.lloyd_update{passes=3}": 1,
+                   "kmeans.lloyd_assign{passes=3}": 1,
+                   "kmeans.lloyd_recheck_overflow": 0,
                    "d2h.bytes{site=fit.centers}": 1000 * 3000 * 4}
 
 

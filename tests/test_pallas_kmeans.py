@@ -45,7 +45,7 @@ def test_fused_fit_matches_lloyd_fit(n_devices, precision):
 
     X, init = _blobs(n=512)
     w = np.ones((512,), np.float32)
-    c_ref, in_ref, it_ref = lloyd_fit(
+    c_ref, in_ref, it_ref, _ = lloyd_fit(
         jnp.asarray(X), jnp.asarray(w), jnp.asarray(init), 1e-6, 20
     )
     c_p, in_p, it_p = lloyd_fit_pallas(
@@ -88,7 +88,7 @@ def test_fused_fit_sharded(n_devices):
     X, init = _blobs(n=1024, seed=3)
     w = np.ones((1024,), np.float32)
     mesh = get_mesh()
-    c_ref, in_ref, _ = lloyd_fit(
+    c_ref, in_ref, _, _ = lloyd_fit(
         shard_array(X, mesh), shard_array(w, mesh), jnp.asarray(init), 1e-6, 15
     )
     c_p, in_p, _ = lloyd_fit_pallas(
@@ -155,7 +155,7 @@ def test_masked_fit_matches_lloyd_fit(n_devices, precision):
     mesh = get_mesh(n_devices)
     Xp, w, _ = pad_rows(X, n_devices)
     Xd, wd = shard_array(Xp, mesh), shard_array(w, mesh)
-    c_ref, in_ref, it_ref = lloyd_fit(
+    c_ref, in_ref, it_ref, _ = lloyd_fit(
         jnp.asarray(Xp), jnp.asarray(w), jnp.asarray(init), 1e-6, 20
     )
     c_m, in_m, it_m = lloyd_fit_pallas(
