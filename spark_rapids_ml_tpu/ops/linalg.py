@@ -52,15 +52,85 @@ def scaler_transform(X: jax.Array, shift: jax.Array, scale: jax.Array) -> jax.Ar
     return (X.astype(shift.dtype) - shift) / scale
 
 
-@compiled_kernel("linalg.weighted_covariance")
-def weighted_covariance(X: jax.Array, w: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Centered covariance C = Σ w_i (x_i-μ)(x_i-μ)ᵀ / (Σw - 1) via sufficient
-    statistics (single data pass: S2 = Xᵀ diag(w) X, then mean correction)."""
+def kahan_add(acc, comp, term):
+    """One compensated-summation step: returns (acc', comp') with the low-order
+    bits the naive add would drop carried in `comp`. Accumulation error stays
+    O(1) ulps over ANY number of terms instead of growing with their count (the
+    streamed tier's float32 device accumulation then matches the float64 HOST
+    accumulation it replaced: the terms were always float32, only their sum
+    ever benefited from float64). XLA does not reassociate IEEE float ops, so
+    the cancellation survives jit."""
+    y = term - comp
+    t = acc + y
+    return t, (t - acc) - y
+
+
+# Rows of one partial Gram matrix in `weighted_covariance`. On the chip a
+# float32 matmul at HIGHEST is six bf16 passes summed into one float32
+# accumulator along the contraction, and the small passes (hi·lo, mid·mid) stop
+# registering once that accumulator is large: over 357,376 rows the diagonal
+# read 2.3e-5 low, always low, which is further off than the three passes of
+# HIGH (1.1e-5). Over 4,096 rows the loss is 1e-7 and over 16,384 already 1e-6
+# (PERF.md §6, PR 32), so the partial sums stay this short and are added up
+# outside the matmul.
+GRAM_CHUNK_ROWS = 4096
+
+
+def _centered_gram(X: jax.Array, w: jax.Array, mean: jax.Array) -> jax.Array:
+    """Σ w_i (x_i-μ)(x_i-μ)ᵀ over the rows held here: one matmul per
+    `GRAM_CHUNK_ROWS` rows, the partial sums added with a compensated (Kahan)
+    sum, so that neither the matmul's accumulator nor the sum of the parts
+    loses what float32 holds. A table shorter than one chunk is one matmul."""
+    n, d = X.shape
+    chunk = GRAM_CHUNK_ROWS
+
+    def gram(xs, ws):
+        xs = xs - mean[None, :]
+        return pdot((xs * ws[:, None]).T, xs)
+
+    if n <= chunk:
+        return gram(X, w)
+
+    def body(i, carry):
+        xs = jax.lax.dynamic_slice_in_dim(X, i * chunk, chunk, 0)
+        ws = jax.lax.dynamic_slice_in_dim(w, i * chunk, chunk, 0)
+        return kahan_add(*carry, gram(xs, ws))
+
+    zeros = jnp.zeros((d, d), X.dtype)
+    full = n // chunk
+    carry = jax.lax.fori_loop(0, full, body, (zeros, zeros))
+    if n % chunk:
+        carry = kahan_add(*carry, gram(X[full * chunk:], w[full * chunk:]))
+    return carry[0]
+
+
+@compiled_kernel("linalg.weighted_covariance", static_argnames=("mesh",))
+def weighted_covariance(
+    X: jax.Array, w: jax.Array, mesh=None
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Centered covariance C = Σ w_i (x_i-μ)(x_i-μ)ᵀ / (Σw - 1): the weighted
+    mean first, then the Gram matrix of the centred rows in short partial sums
+    (`_centered_gram`). With a `mesh` of several devices each shard sums its own
+    rows and one psum adds the shards; without one (a single device, a mesh
+    that also splits the columns, or a caller that leaves the partitioning to
+    XLA) the rows are taken as they come."""
+    from ..parallel.mesh import DATA_AXIS, FEATURE_AXIS
+
     wsum = jnp.sum(w)
     mean = pdot(w, X) / wsum
-    S2 = pdot((X * w[:, None]).T, X)
-    cov = (S2 - wsum * jnp.outer(mean, mean)) / (wsum - 1.0)
-    return cov, mean, wsum
+    if (mesh is not None and mesh.shape.get(DATA_AXIS, 1) > 1
+            and mesh.shape.get(FEATURE_AXIS, 1) == 1):
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        S2 = shard_map(
+            lambda x, ws, m: jax.lax.psum(_centered_gram(x, ws, m), DATA_AXIS),
+            mesh=mesh, in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P()),
+            out_specs=P(), check_vma=False,
+        )(X, w, mean)
+    else:
+        S2 = _centered_gram(X, w, mean)
+    return S2 / (wsum - 1.0), mean, wsum
 
 
 @compiled_kernel("linalg.gram_and_xty")

@@ -38,6 +38,7 @@ from ..reliability import (
 )
 from ._precision import pdot
 from .ingest import StagingPool, stage_block
+from .linalg import kahan_add as _kahan_add
 
 
 # ----------------------------------------------------------------- fused chains
@@ -439,19 +440,6 @@ def streaming_moments(
     mean = np.asarray(sx) / wsum
     var = np.maximum((np.asarray(sxx) - wsum * mean * mean) / (wsum - 1.0), 0.0)
     return mean, var, wsum
-
-
-def _kahan_add(acc, comp, term):
-    """One compensated-summation step: returns (acc', comp') with the low-order
-    bits the naive add would drop carried in `comp`. Accumulation error stays
-    O(1) ulps over ANY number of batches instead of growing O(n_batches) —
-    float32 device accumulation then matches the effective precision of the
-    pre-donation float64 HOST accumulation it replaced (the per-batch terms
-    were always float32; only their summation ever benefited from float64).
-    XLA does not reassociate IEEE float ops, so the cancellation survives jit."""
-    y = term - comp
-    t = acc + y
-    return t, (t - acc) - y
 
 
 @compiled_kernel(

@@ -7,7 +7,8 @@ through the public path.
 READS `BENCHMARK.json`, `cellbench/metrics/*.json` and `cellbench/configs/*.json`.
 One case per (per-layer metric, cell) pair whose metric file reads the program
 (kinds `report_counter_per_op`, `counter_delta`, `counter_delta_per_op`,
-`span_seconds_per_op`, `model_attribute`, `roofline`); the other kinds
+`span_seconds_per_op`, `model_attribute`, `roofline`,
+`program_seconds_per_op`); the other kinds
 (`device_busy_per_op`, `mfu`, `upload_floor`) read the device trace and the
 harness's own clock. A metric file of a kind this file does not know fails by
 name: give the new kind its reader's check here.
@@ -29,8 +30,11 @@ ROWS, COLS, MAX_K, MAX_ITER = 512, 16, 4, 3
 # a family whose cell sits on the far side of one of the program's shape tests
 # keeps that side at toy size: `kmeans_wide` ranks Lloyd's assignment at three
 # passes, which `ops/kmeans.py::_second_look_rows` engages from 128 centres on
-# 524,288 centre coordinates (256 x 4096 floats here: 4 MB)
-TOY = {"kmeans_wide": {"rows": 256, "cols": 4096, "max_k": 128}}
+# 524,288 centre coordinates (256 x 4096 floats here: 4 MB); the configuration
+# `pca_k3_d3000` leaves the Pallas Gram kernel for the XLA program past
+# `ops/pallas_xtwx.py::MAX_FUSED_COLS` = 512 columns
+TOY = {"kmeans_wide": {"rows": 256, "cols": 4096, "max_k": 128},
+       "pca_k3_d3000": {"rows": 256, "cols": 640}}
 
 # (metric, cell) pairs whose counter reads 0 in that cell BY DESIGN: the
 # program counts the same name under another label there
@@ -106,7 +110,7 @@ def _run_cell(cell_name):
     if cell["traffic"] not in ("fit", "transform"):
         pytest.fail(f"cell {cell_name} has traffic {cell['traffic']!r}: "
                     "tests/test_benchmark_contract.py knows fit and transform")
-    toy = TOY.get(cfg["estimator"], {})
+    toy = TOY.get(cell["config"], TOY.get(cfg["estimator"], {}))
     X = np.random.default_rng(30).normal(
         size=(toy.get("rows", ROWS), toy.get("cols", COLS))).astype(np.float32)
     # on the chip `auto` takes the Pallas Gram kernel; off it only "1" does
@@ -258,6 +262,12 @@ def _check_roofline(entry, spec, run, emitted):
         f"called carries `{want}`")
 
 
+def _check_program_seconds(entry, spec, run, emitted):
+    # the roofline reader's `program` without a floor: the same name, the same way
+    assert "program" in spec and "op" not in spec, spec
+    _check_roofline(entry, spec, run, emitted)
+
+
 CHECKS = {
     "report_counter_per_op": _check_report_counter,
     "counter_delta": _check_counter_delta,
@@ -265,6 +275,7 @@ CHECKS = {
     "span_seconds_per_op": _check_span,
     "model_attribute": _check_model_attribute,
     "roofline": _check_roofline,
+    "program_seconds_per_op": _check_program_seconds,
 }
 
 
