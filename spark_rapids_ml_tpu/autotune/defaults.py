@@ -72,6 +72,30 @@ LLOYD_FUSED_MIN_K = 128
 LLOYD_ASSIGN3_MIN_WORK = 1 << 19
 LLOYD_RECHECK_SHARE = 16
 
+# The XLA Gram program (ops/linalg.py::_centered_gram, PCA's covariance past
+# the Pallas kernel's 512 columns, with a weightCol, in float64 or off a TPU)
+# cuts the columns into blocks of GRAM_BLOCK_COLS from GRAM_TRIANGLE_MIN_COLS
+# columns on, multiplies block I against the columns from I's first to the
+# last only (the block pairs I <= J of the symmetric matrix), and mirrors the
+# result once. NOT tunables, no knob reads them. Seconds a 4,096-row part as a
+# share of the single matmul's, one v5e, six passes, by columns
+# (tools/gram_triangle_bench.py; PERF.md §6, PR 33):
+#
+#   columns          576    640    768   1024   1536   2048   3000   4096
+#   256 a block    0.785  0.870  0.852  0.723  0.641  0.625  0.633  0.588
+#   384            0.830  0.918  0.902  0.757  0.697  0.670  0.561  0.605
+#   512            0.893  0.997  0.891  0.798  0.695  0.731  0.581  0.603
+#   768                                 0.873  0.768  0.718  0.610  0.628
+#
+# No width loses at any column count measured, so the threshold is the
+# sweep's lower edge and nothing under it was measured. 384: the least at
+# upstream's 3000 columns (where 256 is the worst of the four), within 0.02
+# of the least at 4096 and within 0.06 of 256 below; one matmul a block PAIR
+# in place of one a block row read 0.596 at 3000 and 0.694 at 4096 columns in
+# 512-column blocks, and 0.835 at 2048, where all its blocks are alike.
+GRAM_TRIANGLE_MIN_COLS = 576
+GRAM_BLOCK_COLS = 384
+
 # Rows per centre (ops/kmeans.py::assign_counts). NOT tunables, no knob reads
 # them. Up to COUNT_DEVICE_MAX_CENTERS centres the device compares every row
 # with every centre id and sums the hits in one fusion: work of n x centres,

@@ -19,6 +19,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..autotune.defaults import GRAM_BLOCK_COLS, GRAM_TRIANGLE_MIN_COLS
 from ..observability.device import compiled_kernel
 from ._precision import pdot
 
@@ -76,32 +77,78 @@ def kahan_add(acc, comp, term):
 GRAM_CHUNK_ROWS = 4096
 
 
-def _centered_gram(X: jax.Array, w: jax.Array, mean: jax.Array) -> jax.Array:
-    """Σ w_i (x_i-μ)(x_i-μ)ᵀ over the rows held here: one matmul per
-    `GRAM_CHUNK_ROWS` rows, the partial sums added with a compensated (Kahan)
-    sum, so that neither the matmul's accumulator nor the sum of the parts
-    loses what float32 holds. A table shorter than one chunk is one matmul."""
-    n, d = X.shape
+def gram_column_blocks(d: int) -> Tuple[Tuple[int, int], ...]:
+    """The column ranges `_centered_gram` cuts a Gram matrix of `d` columns
+    into: one range, the whole matrix in a single matmul, under
+    `GRAM_TRIANGLE_MIN_COLS`; from there on blocks of `GRAM_BLOCK_COLS`, the
+    last one what is left, of which only the pairs (I, J) with I <= J are
+    multiplied. A fit counts the form from this same answer
+    (`ops/pca.py::covariance_for_fit`)."""
+    if d < GRAM_TRIANGLE_MIN_COLS:
+        return ((0, d),)
+    return tuple((lo, min(lo + GRAM_BLOCK_COLS, d)) for lo in range(0, d, GRAM_BLOCK_COLS))
+
+
+def _upper_gram_panels(X: jax.Array, w: jax.Array, mean: jax.Array, blocks):
+    """Σ w_i (x_i-μ)(x_i-μ)ᵀ over the rows held here, as one panel a column
+    block I: its rows of the matrix from the block's first column to the last
+    column, which are the block pairs (I, J) with I <= J. One matmul per panel
+    and `GRAM_CHUNK_ROWS` rows, the partial sums added with a compensated
+    (Kahan) sum, so that neither the matmul's accumulator nor the sum of the
+    parts loses what float32 holds. A table shorter than one chunk is one
+    part; a single block's panel is the whole matrix."""
+    n = X.shape[0]
     chunk = GRAM_CHUNK_ROWS
 
     def gram(xs, ws):
         xs = xs - mean[None, :]
-        return pdot((xs * ws[:, None]).T, xs)
+        xw = xs * ws[:, None]
+        return [pdot(xw[:, lo:hi].T, xs[:, lo:]) for lo, hi in blocks]
 
     if n <= chunk:
         return gram(X, w)
 
+    def add(carry, terms):
+        accs, comps = zip(*(kahan_add(a, c, t) for a, c, t in zip(*carry, terms)))
+        return list(accs), list(comps)
+
     def body(i, carry):
         xs = jax.lax.dynamic_slice_in_dim(X, i * chunk, chunk, 0)
         ws = jax.lax.dynamic_slice_in_dim(w, i * chunk, chunk, 0)
-        return kahan_add(*carry, gram(xs, ws))
+        return add(carry, gram(xs, ws))
 
-    zeros = jnp.zeros((d, d), X.dtype)
+    zeros = [jnp.zeros((hi - lo, X.shape[1] - lo), X.dtype) for lo, hi in blocks]
     full = n // chunk
     carry = jax.lax.fori_loop(0, full, body, (zeros, zeros))
     if n % chunk:
-        carry = kahan_add(*carry, gram(X[full * chunk:], w[full * chunk:]))
+        carry = add(carry, gram(X[full * chunk:], w[full * chunk:]))
     return carry[0]
+
+
+def _mirror_upper_panels(panels, blocks) -> jax.Array:
+    """The whole symmetric matrix from its upper panels: every entry above
+    the diagonal is copied below it, once. A single block's panel is the
+    matrix as the one matmul gave it."""
+    if len(blocks) == 1:
+        return panels[0]
+    # what is padded in lies below the diagonal and is never read
+    G = jnp.concatenate(
+        [jnp.pad(panel, ((0, 0), (lo, 0))) for panel, (lo, _) in zip(panels, blocks)], axis=0)
+    return jnp.where(jnp.tri(G.shape[0], k=-1, dtype=bool), G.T, G)
+
+
+def _centered_gram(X: jax.Array, w: jax.Array, mean: jax.Array, axis=None) -> jax.Array:
+    """Σ w_i (x_i-μ)(x_i-μ)ᵀ, symmetric to the bit from `GRAM_TRIANGLE_MIN_COLS`
+    columns on, where only its upper column blocks are computed: `w` is one
+    scalar a row, so block (J, I) is the transpose of block (I, J) and a second
+    matmul would only compute the same numbers again. With `axis` (inside a
+    `shard_map`) the shards' upper panels are added by one psum before the
+    mirror."""
+    blocks = gram_column_blocks(X.shape[1])
+    panels = _upper_gram_panels(X, w, mean, blocks)
+    if axis is not None:
+        panels = jax.lax.psum(panels, axis)
+    return _mirror_upper_panels(panels, blocks)
 
 
 @compiled_kernel("linalg.weighted_covariance", static_argnames=("mesh",))
@@ -124,7 +171,7 @@ def weighted_covariance(
         from jax.sharding import PartitionSpec as P
 
         S2 = shard_map(
-            lambda x, ws, m: jax.lax.psum(_centered_gram(x, ws, m), DATA_AXIS),
+            lambda x, ws, m: _centered_gram(x, ws, m, axis=DATA_AXIS),
             mesh=mesh, in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P()),
             out_specs=P(), check_vma=False,
         )(X, w, mean)

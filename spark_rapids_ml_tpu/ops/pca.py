@@ -8,11 +8,12 @@
 # replicated symmetric eigendecomposition extracts the top-k components. What one
 # v5e chip read of the two (PERF.md §5; device seconds a fit): at d = 256 on
 # 4,190,208 rows the Gram kernel 0.0202 s and the eigh 0.0015 s; at d = 3000 on
-# 357,376 rows the XLA Gram 0.2428 s and the eigh 0.3392 s. The full eigh, taken for
-# three components, is tiny at a few hundred columns and the larger half of the
-# device's work at upstream's 3000, where its program also takes five minutes to
-# compile cold (the TPU's eigh is a divide and conquer unrolled into 97,000 lines
-# of HLO).
+# 357,376 rows the XLA Gram 0.1327 s (0.2428 s before it left out the lower
+# column blocks of the symmetric matrix, PR 33) and the eigh 0.3389 s. The full
+# eigh, taken for three components, is tiny at a few hundred columns and 72 %
+# of the device's work at upstream's 3000, where its program also takes five
+# minutes to compile cold (the TPU's eigh is a divide and conquer unrolled into
+# 97,000 lines of HLO).
 #
 # Parity notes:
 #   * component signs canonicalized so each component's max-|.| element is positive —
@@ -33,7 +34,7 @@ import numpy as np
 
 from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
-from .linalg import weighted_covariance
+from .linalg import gram_column_blocks, weighted_covariance
 
 
 @compiled_kernel("pca.from_cov", static_argnames=("k",))
@@ -104,7 +105,10 @@ def covariance_for_fit(
     `pca.gram_path{path=xla|pallas}` counts which one ran and
     `pca.gram_gate{fused=0|1,reason=}` which test decided it: beyond
     `MAX_FUSED_COLS` columns (upstream's benchmark table has 3000) nothing
-    else says that the fit left the one-read kernel for the XLA program."""
+    else says that the fit left the one-read kernel for the XLA program.
+    `pca.gram_form{form=triangle,blocks=B|form=full}` counts which form that
+    program took, from the shape test it takes it by
+    (`linalg.gram_column_blocks`); a fit on the kernel counts neither."""
     fused, reason = gram_gate(X.shape[1], unit_weight, dtype=X.dtype)
     counter_inc("pca.gram_gate", 1, fused=int(fused), reason=reason)
     counter_inc("pca.gram_path", 1, path="pallas" if fused else "xla")
@@ -118,6 +122,11 @@ def covariance_for_fit(
         return covariance_prefix_mask(
             X, w, mesh=mesh, precision=parity_precision(), interpret=interpret
         )
+    blocks = len(gram_column_blocks(X.shape[1]))
+    if blocks > 1:
+        counter_inc("pca.gram_form", 1, form="triangle", blocks=blocks)
+    else:
+        counter_inc("pca.gram_form", 1, form="full")
     return weighted_covariance(X, w, mesh=mesh)
 
 

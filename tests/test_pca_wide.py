@@ -16,6 +16,10 @@ from spark_rapids_ml_tpu.ops import pca as pca_ops
 from spark_rapids_ml_tpu.ops.pallas_xtwx import MAX_FUSED_COLS
 
 ROWS, WIDE = 2048, MAX_FUSED_COLS + 128
+# one column under the width at which the XLA program computes only the upper
+# column blocks, and a width of three blocks the last of which is narrow
+FULL_COLS = linalg.GRAM_TRIANGLE_MIN_COLS - 1
+TRIANGLE_COLS = 2 * linalg.GRAM_BLOCK_COLS + 40
 
 
 def _table(rows, cols, seed):
@@ -111,18 +115,33 @@ def _fit_counters(cols, setting=None):
             if name.startswith("pca.gram_")}
 
 
+def _triangle(cols):
+    return "pca.gram_form{blocks=%d,form=triangle}" % len(linalg.gram_column_blocks(cols))
+
+
 @pytest.mark.parametrize("cols,setting,want", [
     # past the kernel's width the XLA program runs, whatever the setting
-    (WIDE, None, {"pca.gram_gate{fused=0,reason=cols}": 1, "pca.gram_path{path=xla}": 1}),
-    (WIDE, "1", {"pca.gram_gate{fused=0,reason=cols}": 1, "pca.gram_path{path=xla}": 1}),
-    # at its last width the kernel (the interpreter, off the chip) when forced
+    (WIDE, None, {"pca.gram_gate{fused=0,reason=cols}": 1, "pca.gram_path{path=xla}": 1,
+                  _triangle(WIDE): 1}),
+    (WIDE, "1", {"pca.gram_gate{fused=0,reason=cols}": 1, "pca.gram_path{path=xla}": 1,
+                 _triangle(WIDE): 1}),
+    # at its last width the kernel (the interpreter, off the chip) when forced:
+    # the XLA program's form is not counted where that program does not run
     (MAX_FUSED_COLS, "1",
      {"pca.gram_gate{fused=1,reason=setting}": 1, "pca.gram_path{path=pallas}": 1}),
     # `auto` wants a TPU, and the tests' CPU is none
     (MAX_FUSED_COLS, None,
-     {"pca.gram_gate{fused=0,reason=platform}": 1, "pca.gram_path{path=xla}": 1}),
+     {"pca.gram_gate{fused=0,reason=platform}": 1, "pca.gram_path{path=xla}": 1,
+      "pca.gram_form{form=full}": 1}),
     (MAX_FUSED_COLS, "0",
-     {"pca.gram_gate{fused=0,reason=setting}": 1, "pca.gram_path{path=xla}": 1}),
+     {"pca.gram_gate{fused=0,reason=setting}": 1, "pca.gram_path{path=xla}": 1,
+      "pca.gram_form{form=full}": 1}),
+    # the XLA program's single matmul up to one column under the width from
+    # which it computes the upper column blocks alone
+    (FULL_COLS, None, {"pca.gram_gate{fused=0,reason=cols}": 1, "pca.gram_path{path=xla}": 1,
+                       "pca.gram_form{form=full}": 1}),
+    (TRIANGLE_COLS, None, {"pca.gram_gate{fused=0,reason=cols}": 1,
+                           "pca.gram_path{path=xla}": 1, _triangle(TRIANGLE_COLS): 1}),
 ])
 def test_a_fit_counts_which_gram_ran_and_why(cols, setting, want):
     assert _fit_counters(cols, setting) == want
@@ -244,3 +263,125 @@ def test_a_sharded_covariance_moves_the_state_once_and_no_rows(n_devices):
     # the d x d sum of the shards' parts, the column sums and the row count
     assert set(summary) == {"all_reduce"}, summary
     assert summary["all_reduce"]["bytes"] == (d * d + d + 1) * 4, summary
+
+
+# ------------------------------------- only the upper column blocks are multiplied
+
+
+def _gram(X, w):
+    """`_centered_gram` about the float64 weighted mean, and that mean."""
+    mean = (w.astype(np.float64) @ X.astype(np.float64) / w.sum()).astype(np.float32)
+    return np.asarray(linalg._centered_gram(jnp.asarray(X), jnp.asarray(w), jnp.asarray(mean))), mean
+
+
+@pytest.fixture(scope="module")
+def wide_table():
+    """Two parts and a remainder of rows, three column blocks the last of
+    which is narrow; column means of three noise widths."""
+    rows = 2 * linalg.GRAM_CHUNK_ROWS + 77
+    rng = np.random.default_rng(33)
+    X = (rng.standard_normal((rows, TRIANGLE_COLS)) + 3.0).astype(np.float32)
+    return X, {"unit": np.ones(rows, np.float32),
+               "weightCol": rng.integers(1, 4, rows).astype(np.float32)}
+
+
+@pytest.mark.parametrize("weights", ["unit", "weightCol"])
+def test_the_upper_blocks_give_the_float64_gram_and_an_exactly_symmetric_one(wide_table, weights):
+    X, ws = wide_table
+    w = ws[weights]
+    assert len(linalg.gram_column_blocks(X.shape[1])) > 1
+    G, mean = _gram(X, w)
+    Xc = X.astype(np.float64) - mean.astype(np.float64)
+    ref = (Xc * w.astype(np.float64)[:, None]).T @ Xc
+    # float32 rounding of a sum of some 8,000 (24,000 weighted) products of
+    # unit scale: the diagonal is 8,000 to 17,000, an ulp there 1e-3
+    assert np.abs(G - ref).max() <= 4e-7 * np.abs(ref).max()
+    assert (G == G.T).all()
+
+
+@pytest.mark.parametrize("weights", ["unit", "weightCol"])
+def test_the_upper_blocks_give_what_the_single_matmul_gives(wide_table, weights, monkeypatch):
+    X, ws = wide_table
+    w = ws[weights]
+    G, _ = _gram(X, w)
+    # the single matmul a part, whatever the shape
+    monkeypatch.setattr(linalg, "GRAM_TRIANGLE_MIN_COLS", X.shape[1] + 1)
+    F, _ = _gram(X, w)
+    assert np.abs(G - F).max() <= 4e-7 * np.abs(F).max()
+    # the single matmul computes both halves, and not to the same bits
+    assert np.abs(F - F.T).max() <= 4e-7 * np.abs(F).max()
+
+
+def _gram_dots_a_part(cols):
+    """Matmuls that yield a block of the Gram matrix, counted in the
+    optimised program of a table of two parts and a remainder: the loop's body
+    holds a part's, the remainder a part's again."""
+    import re
+
+    rows = 2 * linalg.GRAM_CHUNK_ROWS + 8
+    text = linalg.weighted_covariance.lower(
+        jax.ShapeDtypeStruct((rows, cols), jnp.float32), jax.ShapeDtypeStruct((rows,), jnp.float32)
+    ).compile().as_text()
+    dots = re.findall(r"= f32\[(\d+),(\d+)\]\S* dot\(", text)
+    assert len(dots) % 2 == 0, dots
+    return len(dots) // 2, sorted({(int(a), int(b)) for a, b in dots})
+
+
+def test_under_the_width_a_part_is_one_matmul_of_the_whole_matrix():
+    count, shapes = _gram_dots_a_part(FULL_COLS)
+    assert (count, shapes) == (1, [(FULL_COLS, FULL_COLS)])
+
+
+def test_from_the_width_on_a_part_multiplies_the_upper_block_pairs_only():
+    """One matmul a block ROW, block I against the columns from I's first to
+    the last: B matmuls whose outputs are the B(B+1)/2 block pairs (I, J) with
+    I <= J, and none of the other B(B-1)/2."""
+    blocks = linalg.gram_column_blocks(TRIANGLE_COLS)
+    B = len(blocks)
+    count, shapes = _gram_dots_a_part(TRIANGLE_COLS)
+    assert B >= 3 and count == B, (B, count)
+    assert shapes == sorted((hi - lo, TRIANGLE_COLS - lo) for lo, hi in blocks), shapes
+    pairs = sum((i1 - i0) * (j1 - j0) for a, (i0, i1) in enumerate(blocks) for j0, j1 in blocks[a:])
+    assert sum(a * b for a, b in shapes) == pairs < TRIANGLE_COLS ** 2
+
+
+@pytest.mark.parametrize("cols,blocks", [
+    (1, 1), (MAX_FUSED_COLS + 1, 1), (FULL_COLS, 1),
+    (linalg.GRAM_TRIANGLE_MIN_COLS, -(-linalg.GRAM_TRIANGLE_MIN_COLS // linalg.GRAM_BLOCK_COLS)),
+    (3000, -(-3000 // linalg.GRAM_BLOCK_COLS)),
+])
+def test_the_column_blocks_cover_the_columns_once_and_in_order(cols, blocks):
+    got = linalg.gram_column_blocks(cols)
+    assert len(got) == blocks
+    assert got[0][0] == 0 and got[-1][1] == cols
+    assert all(a[1] == b[0] for a, b in zip(got, got[1:]))
+    assert blocks == 1 or all(hi - lo <= linalg.GRAM_BLOCK_COLS for lo, hi in got)
+
+
+@pytest.mark.parametrize("num_workers", [2, 4])
+def test_the_shards_upper_blocks_add_up_to_the_single_device_covariance(wide_table, num_workers,
+                                                                         n_devices):
+    if num_workers > n_devices:
+        pytest.skip(f"needs {num_workers} virtual devices")
+    from spark_rapids_ml_tpu.observability.comm import collectives_from_executable
+    from spark_rapids_ml_tpu.parallel.partitioner import DataParallelPartitioner
+
+    X, ws = wide_table
+    w = ws["weightCol"]
+    one, _, _ = linalg.weighted_covariance(jnp.asarray(X), jnp.asarray(w))
+    part = DataParallelPartitioner(num_workers)
+    pad = -len(X) % num_workers
+    Xd = part.shard(np.concatenate([X, np.zeros((pad, X.shape[1]), np.float32)]))
+    wd = part.shard(np.concatenate([w, np.zeros(pad, np.float32)]))
+    cov, _, _ = linalg.weighted_covariance(Xd, wd, mesh=part.mesh)
+    one, cov = np.asarray(one), np.asarray(cov)
+    assert np.abs(cov - one).max() <= 4e-7 * np.abs(one).max()
+    assert (cov == cov.T).all()
+    # one psum of the upper blocks, the column sums and the row count: the
+    # lower blocks are never moved, and mirrored after the sum
+    exe = linalg.weighted_covariance.lower(Xd, wd, mesh=part.mesh).compile()
+    summary = collectives_from_executable(exe) or {}
+    assert set(summary) == {"all_reduce"}, summary
+    d = X.shape[1]
+    upper = sum((hi - lo) * (d - lo) for lo, hi in linalg.gram_column_blocks(d))
+    assert summary["all_reduce"]["bytes"] == (upper + d + 1) * 4 < (d * d + d + 1) * 4, summary
