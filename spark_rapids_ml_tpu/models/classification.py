@@ -47,6 +47,7 @@ from ..core.params import (
     Param,
     TypeConverters,
 )
+from ..observability import span
 from ..ops.logistic import logreg_decision, logreg_fit
 
 
@@ -271,7 +272,7 @@ class LogisticRegression(
         return self._set_params(maxIter=value)  # type: ignore[return-value]
 
     def _out_schema(self) -> List[str]:
-        return ["coefficients", "intercepts", "n_iter", "objective", "num_classes"]
+        return ["coefficients", "intercepts", "n_iter", "objective", "num_classes", "gradient"]
 
     def _enable_fit_multiple_in_single_pass(self) -> bool:
         # device-resident data is reused across param maps (the reference loops cuML
@@ -304,7 +305,8 @@ class LogisticRegression(
                 lab = np.asarray(inputs.label)
                 w = np.asarray(inputs.row_weight)
                 y_host = lab[w > 0]
-            classes, n_classes = _validate_labels(y_host)
+            with span("logistic.labels"):
+                classes, n_classes = _validate_labels(y_host)
 
             param_sets = extra_params if extra_params is not None else [base]
             results = []
@@ -416,7 +418,8 @@ class LogisticRegression(
                 "lowerBoundsOnIntercepts", "upperBoundsOnIntercepts",
             )
         )
-        classes, n_classes = _validate_labels(fd.label)
+        with span("logistic.labels"):
+            classes, n_classes = _validate_labels(fd.label)
         if bounds_set or _is_sparse(fd.features) or len(classes) <= 1:
             if chain_ops:
                 # the fuser gates on fuse-eligibility, so only a direct caller
@@ -496,13 +499,18 @@ class LogisticRegressionModel(
         n_iter: int,
         objective: float,
         num_classes: int,
+        gradient: Optional[np.ndarray] = None,
     ) -> None:
+        # `gradient`: the objective's gradient at (coefficients, intercepts) as
+        # the dense quasi-Newton fit formed it, (rows, d+1); None from any
+        # other path (ops/logistic.py::logreg_fit)
         super().__init__(
             coefficients=np.asarray(coefficients),
             intercepts=np.asarray(intercepts),
             n_iter=int(n_iter),
             objective=float(objective),
             num_classes=int(num_classes),
+            gradient=None if gradient is None else np.asarray(gradient),
         )
         self._setDefault(
             featuresCol="features",
@@ -518,6 +526,10 @@ class LogisticRegressionModel(
     @property
     def numClasses(self) -> int:
         return self._model_attributes["num_classes"]
+
+    def _serving_device_attrs(self):
+        # what predict consumes; the fit's `gradient` is a record, not a weight
+        return ("coefficients", "intercepts")
 
     def partial_fit_updater(self, **kwargs):
         """Streamed continual-learning updater anchored on this model:

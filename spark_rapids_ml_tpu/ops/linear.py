@@ -298,7 +298,7 @@ def _huber_qn(
             coef_s * coef_s
         )
 
-    params, n_iter = _run_lbfgs(loss, params0, max_iter, tol)
+    params, n_iter, _, _ = _run_lbfgs(loss, params0, max_iter, tol)
     coef = params[:d] / scale
     return coef, params[d], jnp.exp(params[d + 1]), n_iter
 

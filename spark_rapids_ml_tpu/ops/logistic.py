@@ -24,6 +24,7 @@
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
 from ._precision import pdot
 from .linalg import power_iteration_lmax, weighted_moments
@@ -69,7 +71,18 @@ def _multinomial_loss_fn(X, y_onehot, w, scale, reg_l2, fit_intercept):
 
 def _run_lbfgs(loss, params0, max_iter: int, tol: float):
     """jitted L-BFGS loop (optax) with objective-decrease + gradient stopping, the
-    stopping style of the reference's QN solver."""
+    stopping style of the reference's QN solver.
+
+    Returns `(params, n_iter, grad, (loss_evals, linesearch_steps))`. `grad`
+    is the gradient at `params` as the loop itself formed it: the zoom state
+    carries the accepted point's gradient into the next iteration, so this
+    costs no pass, and it is the one output of the timed program whose
+    arithmetic can be held to float64 from outside (zeros if no iteration
+    ran). The counts are the loop's own, of its value-and-gradient evaluations
+    (each is a forward and a backward pass over the data: one per zoom
+    line-search step, and one for the first iteration, whose state holds no
+    value yet) and of the line search's steps, a fit's totals. The caller's
+    closing `loss(params)` is one forward pass more and is not counted."""
     opt = optax.lbfgs(
         memory_size=LBFGS_MEMORY,
         linesearch=optax.scale_by_zoom_linesearch(max_linesearch_steps=LINESEARCH_MAX_STEPS),
@@ -77,13 +90,16 @@ def _run_lbfgs(loss, params0, max_iter: int, tol: float):
     value_and_grad = optax.value_and_grad_from_state(loss)
 
     def cond(state):
-        _, opt_state, it, delta, gnorm = state
+        _, _, it, delta, gnorm, _, _ = state
         return jnp.logical_and(
             it < max_iter, jnp.logical_and(delta > tol, gnorm > tol)
         )
 
     def body(state):
-        params, opt_state, it, _, _ = state
+        params, opt_state, it, _, _, evals, ls_steps = state
+        # `value_and_grad_from_state` evaluates afresh only where the state
+        # carries no finite value (the first iteration)
+        fresh = ~jnp.isfinite(optax.tree_utils.tree_get(opt_state, "value"))
         value, grad = value_and_grad(params, state=opt_state)
         updates, opt_state = opt.update(
             grad, opt_state, params, value=value, grad=grad, value_fn=loss
@@ -92,7 +108,9 @@ def _run_lbfgs(loss, params0, max_iter: int, tol: float):
         new_value = optax.tree_utils.tree_get(opt_state, "value")
         delta = jnp.abs(value - new_value) / jnp.maximum(jnp.abs(new_value), 1.0)
         gnorm = optax.tree_utils.tree_norm(grad)
-        return new_params, opt_state, it + 1, delta, gnorm
+        steps = optax.tree_utils.tree_get(opt_state, "num_linesearch_steps").astype(jnp.int32)
+        return (new_params, opt_state, it + 1, delta, gnorm,
+                evals + steps + fresh.astype(jnp.int32), ls_steps + steps)
 
     state0 = (
         params0,
@@ -100,9 +118,22 @@ def _run_lbfgs(loss, params0, max_iter: int, tol: float):
         0,
         jnp.array(jnp.inf, params0.dtype),
         jnp.array(jnp.inf, params0.dtype),
+        jnp.zeros((), jnp.int32),
+        jnp.zeros((), jnp.int32),
     )
-    params, _, n_iter, _, _ = jax.lax.while_loop(cond, body, state0)
-    return params, n_iter
+    params, opt_state, n_iter, _, _, evals, ls_steps = jax.lax.while_loop(cond, body, state0)
+    return params, n_iter, optax.tree_utils.tree_get(opt_state, "grad"), (evals, ls_steps)
+
+
+def count_lbfgs(path: str, counts) -> None:
+    """A quasi-Newton fit's totals as counters, once its result is on the host:
+    `logistic.path{path=}` (which compiled fit ran), `logistic.loss_evals`,
+    `logistic.linesearch_steps` (`_run_lbfgs`'s counts; the prox paths, which
+    have no line search, pass none)."""
+    counter_inc("logistic.path", 1, path=path)
+    if counts:
+        counter_inc("logistic.loss_evals", int(counts[0]))
+        counter_inc("logistic.linesearch_steps", int(counts[1]))
 
 
 @compiled_kernel("logistic.qn_fit",
@@ -116,8 +147,8 @@ def _qn_fit(
     else:
         loss = _binomial_loss_fn(X, y_enc, w, scale, reg_l2, fit_intercept)
         params0 = jnp.zeros((X.shape[1] + 1,), X.dtype)
-    params, n_iter = _run_lbfgs(loss, params0, max_iter, tol)
-    return params, n_iter, loss(params)
+    params, n_iter, grad, counts = _run_lbfgs(loss, params0, max_iter, tol)
+    return params, n_iter, loss(params), grad, *counts
 
 
 def _accelerated_prox_loop(smooth, prox, params0, step, max_iter: int, tol):
@@ -214,6 +245,11 @@ def _gram_lmax(X, w, scale):
     return power_iteration_lmax(G)
 
 
+def _lipschitz(X, w, scale, reg_l2, multinomial: bool):
+    """The prox paths' step bound, from the Gram's largest eigenvalue."""
+    return (0.5 if multinomial else 0.25) * _gram_lmax(X, w, scale) + reg_l2 + 1e-12
+
+
 def logreg_fit(
     X: jax.Array,
     y: jax.Array,
@@ -229,7 +265,10 @@ def logreg_fit(
     bounds: "tuple | None" = None,
 ) -> Dict[str, Any]:
     """Full fit; returns Spark-layout model attributes:
-    coefficients (k_rows, d) and intercepts (k_rows,) with k_rows = 1 for binomial.
+    coefficients (k_rows, d) and intercepts (k_rows,) with k_rows = 1 for binomial,
+    and `gradient` (k_rows, d+1): the objective's gradient at them with respect
+    to (coefficients, intercept), the intercept's entry last, as the
+    quasi-Newton loop formed it at its last iterate (None on the prox paths).
 
     `bounds` = (lb_coef, ub_coef, lb_icpt, ub_icpt) in ORIGINAL coefficient space
     ((k_rows, d) matrices / (k_rows,) vectors, None where unbounded) switches on the
@@ -309,27 +348,41 @@ def logreg_fit(
         ub_full = jnp.concatenate([ubm, ubi[:, None]], axis=1)
         if not multinomial:
             lb_full, ub_full = lb_full[0], ub_full[0]
-        lmax = _gram_lmax(X, w, scale)
-        lipschitz = (0.5 if multinomial else 0.25) * lmax + reg_l2 + 1e-12
-        params, n_iter, obj = _projected_fit(
-            X, y_enc, w, scale, reg_l2, lipschitz, bool(fit_intercept),
+        path = "projected"
+        lipschitz = _lipschitz(X, w, scale, reg_l2, multinomial)
+        solve = functools.partial(
+            _projected_fit, X, y_enc, w, scale, reg_l2, lipschitz, bool(fit_intercept),
             int(max_iter), float(tol), bool(multinomial), lb_full, ub_full,
         )
     elif reg_l1 > 0.0:
-        lmax = _gram_lmax(X, w, scale)
-        lipschitz = (0.5 if multinomial else 0.25) * lmax + reg_l2 + 1e-12
-        params, n_iter, obj = _fista_fit(
-            X, y_enc, w, scale, reg_l1, reg_l2, lipschitz, bool(fit_intercept),
-            int(max_iter), float(tol), bool(multinomial),
+        path = "fista"
+        lipschitz = _lipschitz(X, w, scale, reg_l2, multinomial)
+        solve = functools.partial(
+            _fista_fit, X, y_enc, w, scale, reg_l1, reg_l2, lipschitz,
+            bool(fit_intercept), int(max_iter), float(tol), bool(multinomial),
         )
     else:
-        params, n_iter, obj = _qn_fit(
-            X, y_enc, w, scale, reg_l2, bool(fit_intercept), int(max_iter),
+        path = "qn"
+        solve = functools.partial(
+            _qn_fit, X, y_enc, w, scale, reg_l2, bool(fit_intercept), int(max_iter),
             float(tol), bool(multinomial),
         )
 
-    params = np.asarray(params, dtype=np.float64)
-    scale_h = np.asarray(scale, dtype=np.float64)
+    with span("logistic.solve"):
+        # waited for, so that the device's solve and the host's fetch below
+        # are not one number
+        params, n_iter, obj, *qn = jax.block_until_ready(solve())
+    with span("logistic.fetch"):
+        count_lbfgs(path, qn[1:])
+        params = np.asarray(params, dtype=np.float64)
+        scale_h = np.asarray(scale, dtype=np.float64)
+        n_iter, obj = int(n_iter), float(obj)
+        gradient = None
+        if qn:
+            # d/d(coef) = d/d(coef_s) * sigma: the loop optimises coef_s = coef * sigma
+            gradient = np.atleast_2d(np.array(qn[0], dtype=np.float64))  # a copy: scaled in place
+            gradient[:, :-1] *= scale_h
+            gradient = gradient.astype(np.float32)
     if multinomial:
         coef = params[:, :-1] / scale_h
         intercept = params[:, -1]
@@ -343,8 +396,9 @@ def logreg_fit(
     return {
         "coefficients": coef.astype(np.float32),
         "intercepts": intercept.astype(np.float32),
-        "n_iter": int(n_iter),
-        "objective": float(obj),
+        "n_iter": n_iter,
+        "objective": obj,
+        "gradient": gradient,
     }
 
 

@@ -202,8 +202,8 @@ def _sparse_qn_fit(
             values, indices, y_enc, w, scale, reg_l2, fit_intercept
         )
         params0 = jnp.zeros((d + 1,), values.dtype)
-    params, n_iter = _run_lbfgs(loss, params0, max_iter, tol)
-    return params, n_iter, loss(params)
+    params, n_iter, _, counts = _run_lbfgs(loss, params0, max_iter, tol)
+    return params, n_iter, loss(params), *counts
 
 
 @compiled_kernel("sparse.fista_fit",
@@ -273,6 +273,8 @@ def sparse_logreg_fit(
     Standardization divides by the column std only (no centering — centering a sparse
     matrix would densify it; the reference's sparse path has the same convention,
     classification.py:1018-1028)."""
+    from .logistic import count_lbfgs
+
     if standardize:
         _, var, _ = sparse_weighted_moments(values, indices, w, d)
         scale = jnp.sqrt(var)
@@ -299,16 +301,18 @@ def sparse_logreg_fit(
 
         lmax = _matvec_lmax(gram_mv, d, values.dtype)
         lipschitz = (0.5 if multinomial else 0.25) * lmax + reg_l2 + 1e-12
+        path, counts = "fista", ()
         params, n_iter, obj = _sparse_fista_fit(
             values, indices, y_enc, w, scale, reg_l1, reg_l2, lipschitz, int(d),
             bool(fit_intercept), int(max_iter), float(tol), bool(multinomial),
         )
     else:
-        params, n_iter, obj = _sparse_qn_fit(
+        path = "qn"
+        params, n_iter, obj, *counts = _sparse_qn_fit(
             values, indices, y_enc, w, scale, reg_l2, int(d), bool(fit_intercept),
             int(max_iter), float(tol), bool(multinomial),
         )
-
+    count_lbfgs(path, counts)
     params = np.asarray(params, dtype=np.float64)
     scale_h = np.asarray(scale, dtype=np.float64)
     if multinomial:
