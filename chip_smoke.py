@@ -4,7 +4,7 @@
 One process drives the main path a user would call — `Estimator.fit` →
 `Model.transform` → `serving.register_model` / `POST /v1/models/<name>:predict`
 — at the repo's flagship width (KMeans k=20 and PCA k=16 over 4,000,000 × 128
-float32), then the streamed out-of-core tier, then each of the nine Pallas
+float32), then the streamed out-of-core tier, then each of the ten Pallas
 kernels through its host wrapper, and checks every result against a plain
 numpy reference. Weights and data are random, made from a seed.
 
@@ -749,6 +749,57 @@ def check_count(n: int = 65_709, d: int = 16, gate: bool = True) -> str:
     return f"{n} neighbourhood counts exact; core mask == XLA"
 
 
+def check_logistic_eval(d: int, n: int = 100_013, gate: bool = True,
+                        want: Tuple[bool, str] = (True, "layout")) -> str:
+    """The quasi-Newton fit's one-read evaluation (`ops/pallas_logistic.py`)
+    on a table placed as a fit places it: the gate's verdict from the placed
+    table's own layout (`want`: the runtime keeps a width that is a multiple
+    of 128 row-major, and such a table keeps the two XLA passes), then value
+    and gradient of the binary loss through the rule against float64 numpy
+    and against autodiff's two passes."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_ml_tpu.ops.logistic import _binomial_loss_fn
+    from spark_rapids_ml_tpu.ops.pallas_logistic import eval_gate, eval_plan
+
+    rng = np.random.default_rng(SEED + d)
+    X = (rng.standard_normal((n, d)) + 0.3 * rng.standard_normal(d)).astype(np.float32)
+    truth = rng.standard_normal(d) * (2.0 / np.sqrt(d))
+    y = (rng.random(n) < 1.0 / (1.0 + np.exp(-(X @ truth + 0.25)))).astype(np.float32)
+    w = rng.integers(1, 4, size=n).astype(np.float32)
+    w[-100:] = 0.0  # the pad_rows contract: zero-weight suffix
+    params = np.append(truth * 0.5, 0.1).astype(np.float32)
+    Xj = jnp.asarray(X)
+    verdict = eval_gate(Xj, False)
+    layout = Xj.format.layout
+    placed = f"placed major_to_minor={tuple(layout.major_to_minor)}"
+    if gate:
+        _check(verdict == want, f"logistic eval d={d}: the gate says {verdict}, {placed}")
+        if not want[0]:
+            return f"{placed}: the gate keeps two passes, reason `{want[1]}`"
+    args = (Xj, jnp.asarray(y), jnp.asarray(w), jnp.ones(d, jnp.float32), 1e-5, True)
+    v_f, g_f = jax.jit(jax.value_and_grad(_binomial_loss_fn(*args, fused=eval_plan(Xj))))(params)
+    v_x, g_x = jax.jit(jax.value_and_grad(_binomial_loss_fn(*args)))(params)
+    X64, w64 = X.astype(np.float64), w.astype(np.float64)
+    z = X64 @ params[:-1].astype(np.float64) + float(params[-1])
+    r = w64 * (1.0 / (1.0 + np.exp(-z)) - y)
+    coef = params[:-1].astype(np.float64)
+    v_ref = (w64 * (np.logaddexp(0.0, z) - y * z)).sum() / w64.sum() + 0.5e-5 * coef @ coef
+    g_ref = np.append(X64.T @ r / w64.sum() + 1e-5 * coef, r.sum() / w64.sum())
+    rms = float(np.sqrt(np.mean(g_ref * g_ref)))
+    e_r = float(np.abs(np.asarray(g_f) - g_ref).max()) / rms
+    e_x = float(np.abs(np.asarray(g_f) - np.asarray(g_x)).max()) / rms
+    e_v = abs(float(v_f) - v_ref) / v_ref
+    # float32 sums over 1e5 rows on the vector unit: the sweep's per-lane sums
+    # leave 5e-6 of the RMS coordinate, XLA's two passes 8e-5 (one v5e, PR 35);
+    # bfloat16 operands would leave 3e-3 (PERF.md §2)
+    _check(e_r <= 2e-5 and e_x <= 3e-4 and e_v <= 2e-6,
+           f"logistic eval d={d}: gradient vs numpy {e_r:.2e}, vs two passes {e_x:.2e}, "
+           f"value {e_v:.2e}")
+    return f"{placed}: gradient vs numpy {e_r:.1e}, vs two passes {e_x:.1e}, value {e_v:.1e}"
+
+
 def check_histograms(n: int = 100_013, d: int = 64, gate: bool = True) -> str:
     import jax.numpy as jnp
 
@@ -785,8 +836,11 @@ def check_histograms(n: int = 100_013, d: int = 64, gate: bool = True) -> str:
     return f"node-bin vs XLA {e_h:.1e}, segment vs XLA {e_g:.1e}"
 
 
-# the nine pl.pallas_call sites: xtwx (2), kmeans (2), select (3), histogram
-# (2); and the XLA Lloyd program's three-pass assignment, which runs per row
+# the ten pl.pallas_call sites: xtwx (2), kmeans (2), select (3), histogram
+# (2), logistic (1: 3000 and 300 columns are placed column-major and take the
+# kernel, 256 row-major and keeps two passes; 3000 columns walk 512-sample
+# blocks, 300 the largest, 4096, with 1,709 samples past the last whole block);
+# and the XLA Lloyd program's three-pass assignment, which runs per row
 # shard (`python chip_smoke.py assign3` on four chips runs that check alone)
 KERNEL_CHECKS: List[Tuple[str, Callable[[], str]]] = [
     ("pallas_xtwx xtx (Gram) d=128", lambda: check_gram(128)),
@@ -800,6 +854,10 @@ KERNEL_CHECKS: List[Tuple[str, Callable[[], str]]] = [
     ("pallas_select top-k scan k=32", lambda: check_topk(32)),
     ("pallas_select count (DBSCAN)", check_count),
     ("pallas_histogram node-bin + segment, 32 bins", check_histograms),
+    ("pallas_logistic eval d=3000", lambda: check_logistic_eval(3000, n=40_013)),
+    ("pallas_logistic eval d=300", lambda: check_logistic_eval(300)),
+    ("pallas_logistic eval d=256 (row-major: two passes)",
+     lambda: check_logistic_eval(256, want=(False, "layout"))),
     ("xla lloyd assign3 k=512 d=1024", check_assign3),
 ]
 

@@ -110,6 +110,28 @@ GRAM_BLOCK_COLS = 384
 COUNT_DEVICE_MAX_CENTERS = 8192
 COUNT_BLOCK_SPLIT = 64
 
+# The quasi-Newton LogisticRegression fit's one-read evaluation
+# (ops/pallas_logistic.py): samples of one feature-major block (d, blk) of the
+# table. NOT tunables, no knob reads them. A block holds at most
+# LOGISTIC_EVAL_BLOCK_BYTES of X (the pipeline holds two: the call raises its
+# scoped-VMEM limit past the 16 MiB default, v5e has 128 MiB), a power of two
+# of samples between LOGISTIC_EVAL_MIN_BLOCK_ROWS (a width whose 256-sample
+# block does not fit, past 8192 columns, keeps the two XLA passes) and
+# LOGISTIC_EVAL_MAX_BLOCK_ROWS (the largest the chip has run). One v5e, ms an
+# evaluation by samples a block (tools/logistic_eval_bench.py; PERF.md §6,
+# PR 35), where the two XLA passes take 11.34 and 6.47:
+#
+#   samples a block        256     512    1024    2048    4096
+#   357,376 x 3000       5.695   5.763   5.750   5.733      -
+#   2,000,000 x 300          -   3.808   3.245   3.219   3.222
+#
+# At 3000 columns any size streams at the HBM rate (744 to 753 GB/s); at 300 a
+# 512-sample block is 0.6 MB and the grid's steps show (630 GB/s), from 1.2 MB
+# on they do not (740 to 745).
+LOGISTIC_EVAL_BLOCK_BYTES = 8 << 20
+LOGISTIC_EVAL_MIN_BLOCK_ROWS = 256
+LOGISTIC_EVAL_MAX_BLOCK_ROWS = 4096
+
 # k <= this is where `auto` may hand a top-k scan to the fused running-pool
 # kernel. NOT a tunable: it is the largest k Mosaic compiled on a v5e (PR 21,
 # jax 0.9.0 / libtpu 0.0.34; k=10 and k=32 agree with XLA and numpy). The

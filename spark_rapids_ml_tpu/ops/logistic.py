@@ -9,7 +9,9 @@
 # jax.value_and_grad of the weighted cross-entropy; the contraction over the sharded
 # row axis makes XLA emit the psum (where cuML put its NCCL allreduce). The optimizer
 # loop is a lax.while_loop around optax.lbfgs (memory 10, zoom linesearch ≤20 steps —
-# the reference's cuML settings).
+# the reference's cuML settings). Through autodiff an evaluation is two reads of X
+# (logits, then gradient); where `pallas_logistic.eval_gate` says so the binary data
+# term is instead ONE sweep with its own derivative rule (ops/pallas_logistic.py).
 #
 # L1/elastic-net uses FISTA proximal gradient instead of OWL-QN: same distributed
 # gradient, soft-threshold prox on coefficients (not intercept), Lipschitz constant
@@ -36,21 +38,30 @@ from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
 from ._precision import pdot
 from .linalg import power_iteration_lmax, weighted_moments
+from .pallas_logistic import binomial_data_term, eval_gate, eval_plan
 
 LINESEARCH_MAX_STEPS = 20  # reference classification.py:1046-1052
 LBFGS_MEMORY = 10
 
 
-def _binomial_loss_fn(X, y, w, scale, reg_l2, fit_intercept):
-    """Returns f(params) for params = [coef_s (d,), intercept]. y in {0,1}."""
+def _binomial_loss_fn(X, y, w, scale, reg_l2, fit_intercept, fused=None):
+    """Returns f(params) for params = [coef_s (d,), intercept]. y in {0,1}.
+    `fused` (static) is None for the two `pdot` passes an evaluation, or
+    `pallas_logistic.eval_plan`'s description for the one-read sweep: the data
+    term alone changes hands; the `/scale` of standardization and the ridge
+    term stay here, in plain jnp, differentiated as ever."""
     wsum = jnp.sum(w)
 
     def loss(params):
         coef_s, b = params[:-1], params[-1]
-        z = pdot(X, coef_s / scale) + jnp.where(fit_intercept, b, 0.0)
-        # stable log-loss: softplus(z) - y*z
-        ce = jnp.sum(w * (jax.nn.softplus(z) - y * z)) / wsum
-        return ce + 0.5 * reg_l2 * jnp.sum(coef_s * coef_s)
+        beta, b = coef_s / scale, jnp.where(fit_intercept, b, 0.0)
+        if fused is None:
+            z = pdot(X, beta) + b
+            # stable log-loss: softplus(z) - y*z
+            data = jnp.sum(w * (jax.nn.softplus(z) - y * z))
+        else:
+            data = binomial_data_term(fused, X, y, w, beta, b)
+        return data / wsum + 0.5 * reg_l2 * jnp.sum(coef_s * coef_s)
 
     return loss
 
@@ -137,15 +148,16 @@ def count_lbfgs(path: str, counts) -> None:
 
 
 @compiled_kernel("logistic.qn_fit",
-                 static_argnames=("fit_intercept", "max_iter", "multinomial"))
+                 static_argnames=("fit_intercept", "max_iter", "multinomial", "fused"))
 def _qn_fit(
-    X, y_enc, w, scale, reg_l2, fit_intercept: bool, max_iter: int, tol, multinomial: bool
+    X, y_enc, w, scale, reg_l2, fit_intercept: bool, max_iter: int, tol, multinomial: bool,
+    fused=None,
 ):
     if multinomial:
         loss = _multinomial_loss_fn(X, y_enc, w, scale, reg_l2, fit_intercept)
         params0 = jnp.zeros((y_enc.shape[1], X.shape[1] + 1), X.dtype)
     else:
-        loss = _binomial_loss_fn(X, y_enc, w, scale, reg_l2, fit_intercept)
+        loss = _binomial_loss_fn(X, y_enc, w, scale, reg_l2, fit_intercept, fused)
         params0 = jnp.zeros((X.shape[1] + 1,), X.dtype)
     params, n_iter, grad, counts = _run_lbfgs(loss, params0, max_iter, tol)
     return params, n_iter, loss(params), grad, *counts
@@ -176,10 +188,10 @@ def _accelerated_prox_loop(smooth, prox, params0, step, max_iter: int, tol):
 
 
 @compiled_kernel("logistic.fista_fit",
-                 static_argnames=("fit_intercept", "max_iter", "multinomial"))
+                 static_argnames=("fit_intercept", "max_iter", "multinomial", "fused"))
 def _fista_fit(
     X, y_enc, w, scale, reg_l1, reg_l2, lipschitz, fit_intercept: bool, max_iter: int,
-    tol, multinomial: bool,
+    tol, multinomial: bool, fused=None,
 ):
     """Proximal-gradient elastic-net fit; prox applies only to coefficient entries."""
     if multinomial:
@@ -189,7 +201,7 @@ def _fista_fit(
             [jnp.ones((y_enc.shape[1], X.shape[1])), jnp.zeros((y_enc.shape[1], 1))], axis=1
         ).astype(X.dtype)
     else:
-        smooth = _binomial_loss_fn(X, y_enc, w, scale, reg_l2, fit_intercept)
+        smooth = _binomial_loss_fn(X, y_enc, w, scale, reg_l2, fit_intercept, fused)
         params0 = jnp.zeros((X.shape[1] + 1,), X.dtype)
         coef_mask = jnp.concatenate(
             [jnp.ones((X.shape[1],)), jnp.zeros((1,))]
@@ -206,10 +218,10 @@ def _fista_fit(
 
 
 @compiled_kernel("logistic.projected_fit",
-                 static_argnames=("fit_intercept", "max_iter", "multinomial"))
+                 static_argnames=("fit_intercept", "max_iter", "multinomial", "fused"))
 def _projected_fit(
     X, y_enc, w, scale, reg_l2, lipschitz, fit_intercept: bool, max_iter: int,
-    tol, multinomial: bool, lb, ub,
+    tol, multinomial: bool, lb, ub, fused=None,
 ):
     """Box-constrained fit: accelerated projected gradient (the same loop as
     _fista_fit with the prox of the box indicator = clip). `lb`/`ub` are full
@@ -222,7 +234,7 @@ def _projected_fit(
         smooth = _multinomial_loss_fn(X, y_enc, w, scale, reg_l2, fit_intercept)
         params0 = jnp.zeros((y_enc.shape[1], X.shape[1] + 1), X.dtype)
     else:
-        smooth = _binomial_loss_fn(X, y_enc, w, scale, reg_l2, fit_intercept)
+        smooth = _binomial_loss_fn(X, y_enc, w, scale, reg_l2, fit_intercept, fused)
         params0 = jnp.zeros((X.shape[1] + 1,), X.dtype)
 
     step = 1.0 / lipschitz
@@ -273,8 +285,17 @@ def logreg_fit(
     `bounds` = (lb_coef, ub_coef, lb_icpt, ub_icpt) in ORIGINAL coefficient space
     ((k_rows, d) matrices / (k_rows,) vectors, None where unbounded) switches on the
     box-constrained projected fit — the reference maps these Spark params to None
-    (unsupported, classification.py:694-698); here they run natively."""
+    (unsupported, classification.py:694-698); here they run natively.
+
+    `logistic.eval{form=fused|two_pass}` counts how the binary data term is
+    evaluated (one Pallas sweep over X, or autodiff's two passes) and
+    `logistic.eval_gate{fused=0|1,reason=}` which test of
+    `pallas_logistic.eval_gate` decided it, once a fit, whichever solver runs."""
     d = X.shape[1]
+    fused, reason = eval_gate(X, multinomial)
+    counter_inc("logistic.eval_gate", 1, fused=int(fused), reason=reason)
+    counter_inc("logistic.eval", 1, form="fused" if fused else "two_pass")
+    plan = eval_plan(X) if fused else None
     if standardize:
         _, var, _ = weighted_moments(X, w)
         scale = jnp.sqrt(var)
@@ -352,20 +373,20 @@ def logreg_fit(
         lipschitz = _lipschitz(X, w, scale, reg_l2, multinomial)
         solve = functools.partial(
             _projected_fit, X, y_enc, w, scale, reg_l2, lipschitz, bool(fit_intercept),
-            int(max_iter), float(tol), bool(multinomial), lb_full, ub_full,
+            int(max_iter), float(tol), bool(multinomial), lb_full, ub_full, fused=plan,
         )
     elif reg_l1 > 0.0:
         path = "fista"
         lipschitz = _lipschitz(X, w, scale, reg_l2, multinomial)
         solve = functools.partial(
             _fista_fit, X, y_enc, w, scale, reg_l1, reg_l2, lipschitz,
-            bool(fit_intercept), int(max_iter), float(tol), bool(multinomial),
+            bool(fit_intercept), int(max_iter), float(tol), bool(multinomial), fused=plan,
         )
     else:
         path = "qn"
         solve = functools.partial(
             _qn_fit, X, y_enc, w, scale, reg_l2, bool(fit_intercept), int(max_iter),
-            float(tol), bool(multinomial),
+            float(tol), bool(multinomial), fused=plan,
         )
 
     with span("logistic.solve"):

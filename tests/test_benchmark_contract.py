@@ -125,6 +125,7 @@ def _run_cell(cell_name):
     from spark_rapids_ml_tpu import config, profiling
     from spark_rapids_ml_tpu.observability import device
     from spark_rapids_ml_tpu.observability.export import iter_spans
+    from spark_rapids_ml_tpu.ops import pallas_logistic
 
     cell = CELLS[cell_name]
     cfg = _load(CONFIG_FILES[cell["config"]])
@@ -139,6 +140,10 @@ def _run_cell(cell_name):
     settings = {**cfg.get("program_settings", {}), "pallas_xtwx": "1"}
     for key, value in settings.items():
         config.set(key, value)
+    # likewise the quasi-Newton fit's one-read evaluation: its gate has no
+    # setting, so its platform test says what the chip would (the kernel runs
+    # interpreted here, inside the same `_qn_fit` program)
+    on_tpu, pallas_logistic._on_tpu = pallas_logistic._on_tpu, lambda: True
     device.reset_device_plane()  # the cold operation compiles, whatever ran before
     try:
         estimator = _build(cfg, int(cell["chips"]))
@@ -154,6 +159,7 @@ def _run_cell(cell_name):
         result = operate()
         after = dict(profiling.counter_totals())
     finally:
+        pallas_logistic._on_tpu = on_tpu
         for key in settings:
             config.unset(key)
     fitted = result if cell["traffic"] == "fit" else model

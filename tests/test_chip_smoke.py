@@ -148,6 +148,8 @@ def test_smoke_references_agree_with_a_tiny_fit(smoke):
     ("top-k", lambda s: s.check_topk(10, n=1500, d=16, nq=40, gate=False)),
     ("count", lambda s: s.check_count(n=2000, d=16, gate=False)),
     ("histograms", lambda s: s.check_histograms(n=2000, d=8, gate=False)),
+    # 1500 = 1024 + 476 samples past the last whole block; a width off the tiling
+    ("logistic eval", lambda s: s.check_logistic_eval(300, n=1500, gate=False)),
 ])
 def test_smoke_kernel_check_passes_tiny_in_interpret_mode(smoke, name, call):
     assert isinstance(call(smoke), str)
@@ -160,3 +162,5 @@ def test_smoke_kernel_gates_are_closed_off_tpu(smoke):
         smoke.check_assign(n=500, d=32, k=128)
     with pytest.raises(AssertionError, match="is closed"):
         smoke.check_gram(128, n=500)
+    with pytest.raises(AssertionError, match="the gate says .False, 'platform'."):
+        smoke.check_logistic_eval(300, n=500)

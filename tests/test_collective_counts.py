@@ -117,25 +117,31 @@ def test_covariance_allreduce_bytes_are_dxd_shaped(n_devices):
     assert total <= 16 * d * d * 4 + 4096, summary  # nowhere near O(n*d)
 
 
-def test_logreg_grad_allreduce_constant_per_lbfgs_iter(n_devices):
+@pytest.mark.parametrize("form", ["two_pass", "fused"])
+def test_logreg_grad_allreduce_constant_per_lbfgs_iter(form, n_devices):
     """The L-BFGS while body computes one value+grad over the sharded rows: the
     whole compiled fit must carry a small constant all-reduce count (loss+grad
     inside the loop body + standardization moments + final extras), not one that
-    scales with features or linesearch steps."""
+    scales with features or linesearch steps. The one-read form
+    (ops/pallas_logistic.py, per-shard kernels under shard_map) sums its packed
+    partials in ONE psum an evaluation site: four sites and the weights' sum."""
     from spark_rapids_ml_tpu.ops.logistic import _qn_fit
+    from spark_rapids_ml_tpu.ops.pallas_logistic import eval_plan
 
     mesh = _mesh(8)
-    X, w = _sharded_blob(mesh, 512, 32)
+    # 320 rows a shard: one 256-sample block for the kernel and 64 rows past it
+    X, w = _sharded_blob(mesh, 2560, 32)
     y = jax.device_put(
-        (np.random.default_rng(2).random(512) < 0.5).astype(np.float32),
+        (np.random.default_rng(2).random(2560) < 0.5).astype(np.float32),
         NamedSharding(mesh, P("data")),
     )
     scale = jnp.ones((32,), jnp.float32)
+    fused = eval_plan(X) if form == "fused" else None
 
     def fit(X, y, w, scale):
         return _qn_fit(
             X, y, w, scale, jnp.float32(0.1), fit_intercept=True, max_iter=5,
-            tol=jnp.float32(1e-6), multinomial=False,
+            tol=jnp.float32(1e-6), multinomial=False, fused=fused,
         )[0]
 
     counts = _count_collectives(fit, X, y, w, scale)
