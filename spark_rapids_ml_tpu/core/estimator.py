@@ -221,7 +221,7 @@ class _TpuCaller(_TpuClass, _TpuParams):
         # the Arrow fast path may defer dtype conversion (core/dataset.py); the
         # staged in-core plane materializes the whole matrix anyway, so the
         # counted host cast happens here (streamed fits cast in-program instead)
-        with _obs_span("fit.stage"):
+        with _obs_span("fit.stage", {"waits": "none"}):
             X = ensure_dtype(
                 densify(fd.features, float32=self._float32_inputs),
                 float32=self._float32_inputs,
@@ -388,7 +388,7 @@ class _TpuCaller(_TpuClass, _TpuParams):
                 # the puts of `prepare` are asynchronous: the upload is waited
                 # for here, as a phase of its own, not at whatever the fit
                 # function happens to read first
-                with _obs_span("h2d.wait", {"site": "fit"}):
+                with _obs_span("h2d.wait", {"site": "fit", "waits": "upload"}):
                     jax.block_until_ready(inputs.device_arrays())
                 result = fit_func(inputs)
         if isinstance(result, list):

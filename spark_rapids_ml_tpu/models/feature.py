@@ -123,7 +123,7 @@ class PCA(_PCAClass, _TpuEstimator, _PCAParams):
 
             from ..observability import span
 
-            with span("pca.cov"):
+            with span("pca.cov", {"waits": "device"}):
                 # waited for here, so that the span reads the covariance pass
                 # as the host sees it and `pca.eig` the eigensolve and fetch
                 cov, mean, wsum = jax.block_until_ready(

@@ -305,7 +305,7 @@ def _put_query(args: Tuple[Any, ...], query: Any) -> Tuple[Any, ...]:
             (device,) = a.devices()
             break
     placed = put_device_local(query, site="transform", device=device)
-    with span("h2d.wait", {"site": "transform"}):
+    with span("h2d.wait", {"site": "transform", "waits": "upload"}):
         jax.block_until_ready(placed)
     return tuple(placed if a is query else a for a in args)
 

@@ -37,6 +37,11 @@ import time
 import uuid
 from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence
 
+try:
+    import resource as _resource
+except ImportError:  # pragma: no cover — a platform without it: no host usage
+    _resource = None  # type: ignore[assignment]
+
 from .. import config as _config
 from ..utils import get_logger
 from .registry import DEFAULT_TIME_BUCKETS, MetricsRegistry
@@ -271,6 +276,57 @@ def convergence(algo: str, iteration: Any, **fields: Any) -> None:
     _flight().note("convergence", **{k: v for k, v in rec.items() if k != "ts"})
 
 
+# ------------------------------------------------------------------ host usage
+
+# What the host did over an interval: of a span whose attrs carry `waits`
+# ("upload", "device" or "none": what the host waits for inside it) and of
+# every run scope (`span=run`, `waits=run`: a value of its own, so no label
+# set a reader can give sums a span and the run that holds it). The
+# differences of two samples, one `getrusage(RUSAGE_SELF)` and one
+# `thread_time()` each: PROCESS totals over the interval (every thread, the
+# runtime's transfer threads among them) beside the calling thread's own CPU
+# seconds, so process minus caller is what the other threads did. Exact
+# attribution with one client; under concurrent requests read them as rates
+# (docs/design.md §6d).
+# srml-metric: host.cpu_seconds{span,waits,mode}
+# srml-metric: host.thread_cpu_seconds{span,waits}
+# srml-metric: host.page_faults{span,waits,kind}
+# srml-metric: host.ctx_switches{span,waits,kind}
+
+
+def _host_sample() -> Any:
+    """The process's rusage and the calling thread's CPU seconds, now; None
+    where the platform has no `resource` (the `waits` flag then does nothing)."""
+    if _resource is None:
+        return None
+    return _resource.getrusage(_resource.RUSAGE_SELF), time.thread_time()
+
+
+def _host_usage_add(name: str, waits: Any, before: Any) -> None:
+    """Add what the host used since `before` (a `_host_sample`) to the four
+    `host.*` counters, under the span's name and its `waits`. A difference is
+    never negative: the kernel's split of a tick between user and system time
+    may step back by one, and a counter takes no negative increment."""
+    after = _host_sample()
+    if before is None or after is None:
+        return
+    (ru0, thread0), (ru1, thread1) = before, after
+    counter_inc("host.cpu_seconds", max(ru1.ru_utime - ru0.ru_utime, 0.0),
+                span=name, waits=waits, mode="user")
+    counter_inc("host.cpu_seconds", max(ru1.ru_stime - ru0.ru_stime, 0.0),
+                span=name, waits=waits, mode="sys")
+    counter_inc("host.thread_cpu_seconds", max(thread1 - thread0, 0.0),
+                span=name, waits=waits)
+    counter_inc("host.page_faults", max(ru1.ru_minflt - ru0.ru_minflt, 0),
+                span=name, waits=waits, kind="minor")
+    counter_inc("host.page_faults", max(ru1.ru_majflt - ru0.ru_majflt, 0),
+                span=name, waits=waits, kind="major")
+    counter_inc("host.ctx_switches", max(ru1.ru_nvcsw - ru0.ru_nvcsw, 0),
+                span=name, waits=waits, kind="voluntary")
+    counter_inc("host.ctx_switches", max(ru1.ru_nivcsw - ru0.ru_nivcsw, 0),
+                span=name, waits=waits, kind="involuntary")
+
+
 # ----------------------------------------------------------------- trace spans
 
 # jax.profiler, resolved lazily and once: False = not yet resolved, None =
@@ -341,7 +397,10 @@ def span(name: str, attrs: Optional[Mapping[str, Any]] = None) -> Iterator[SpanN
     profiler session, on the device trace's clock (no jax import of its own:
     see _trace_annotation). Failure-safe by construction (try/finally): a span
     whose body raises records its elapsed time with status='error' and counts
-    toward `span.errors`."""
+    toward `span.errors`. A span that is a wait says so in its attrs
+    (`waits`: "upload", "device", or "none" for a host phase whose cost moves
+    unexplained) and gets the host's usage over its interval in the `host.*`
+    counters (see `_host_usage_add`); any other span samples nothing."""
     node = SpanNode(name, attrs, parent_id=(
         _span_stack()[-1].span_id if _span_stack() else None
     ))
@@ -354,6 +413,12 @@ def span(name: str, attrs: Optional[Mapping[str, Any]] = None) -> Iterator[SpanN
     for run in open_runs:
         run.note_span_open(node)
     _flight().note_span_open(node)
+    waits = node.attrs.get("waits")
+    # the open sample lies inside the span (taken while a transfer is in
+    # flight it runs beside it, and the wait is no longer for it); the close
+    # sample and its counter writes come after the span's seconds are fixed,
+    # so no `span.seconds` holds them
+    usage = _host_sample() if waits is not None else None
     try:
         with _trace_annotation(name):
             yield node
@@ -362,6 +427,8 @@ def span(name: str, attrs: Optional[Mapping[str, Any]] = None) -> Iterator[SpanN
         raise
     finally:
         node.duration_s = time.perf_counter() - node.t0
+        if usage is not None:
+            _host_usage_add(name, waits, usage)
         stack = _span_stack()
         if stack and stack[-1] is node:
             stack.pop()
@@ -490,6 +557,7 @@ class FitRun:
         self.status = "ok"
         self._t0: Optional[float] = None
         self._root: Optional[Any] = None
+        self._host_usage: Any = None
 
     # ---- sink surface (runs.py fan-out calls these) ----
 
@@ -690,6 +758,9 @@ class FitRun:
     def __enter__(self) -> "FitRun":
         self.started_ts = time.time()
         self._t0 = time.perf_counter()
+        # the whole operation's host cost: `host.*{span=run,waits=run}`, the
+        # denominator of the flagged spans' shares
+        self._host_usage = _host_sample()
         # root trace node: named `.fit_run` (not `.fit`) so the legacy
         # span_totals entry for the estimator's own `{Algo}.fit` kernel span
         # is not double-counted by its enclosing run scope
@@ -707,6 +778,8 @@ class FitRun:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        # while the run is still a sink, and before any report is taken
+        _host_usage_add("run", "run", self._host_usage)
         try:
             self._root.__exit__(exc_type, exc, tb)
         finally:

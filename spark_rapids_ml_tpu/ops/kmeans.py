@@ -685,7 +685,7 @@ def kmeans_fit(
         init_centers = kmeans_init(X, w, k, init, init_steps, seed, unit_weight)
         counter_inc("h2d.bytes", int(init_centers.nbytes), site="fit.centers")
         init_centers = jnp.asarray(init_centers)
-    with span("kmeans.lloyd"):
+    with span("kmeans.lloyd", {"waits": "device"}):
         return _lloyd(X, w, init_centers, k, max_iter, tol, cosine, unit_weight)
 
 

@@ -389,7 +389,7 @@ def logreg_fit(
             float(tol), bool(multinomial), fused=plan,
         )
 
-    with span("logistic.solve"):
+    with span("logistic.solve", {"waits": "device"}):
         # waited for, so that the device's solve and the host's fetch below
         # are not one number
         params, n_iter, obj, *qn = jax.block_until_ready(solve())

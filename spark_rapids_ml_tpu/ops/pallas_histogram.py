@@ -95,6 +95,7 @@ def segment_histogram_pallas(
 
     out = pl.pallas_call(
         functools.partial(_hist_kernel, seg_tile=seg_tile),
+        name="hist_segments",
         grid=(d, c_tiles, n_padded // BLOCK_ROWS),
         in_specs=[
             pl.BlockSpec((d, BLOCK_ROWS), lambda j, c, b: (0, b)),
@@ -224,6 +225,7 @@ def node_bin_histogram_pallas(
 
     out = pl.pallas_call(
         functools.partial(_nb_hist_kernel, n, d_tile, w_tile, nbins, s),
+        name="hist_node_bins",
         grid=(d_tiles, c_tiles, (n + blk - 1) // blk),
         in_specs=[
             pl.BlockSpec((d_tile, blk), lambda j, c, b: (j, b)),

@@ -235,6 +235,7 @@ def _lloyd_step_masked_jit(X, n_valid, centers, interpret: bool, blk: int, n_spl
 
     sums, counts, inertia = pl.pallas_call(
         functools.partial(_lloyd_kernel_masked, n_split),
+        name="lloyd_step_masked",
         grid=((n + blk - 1) // blk,),
         in_specs=[
             pl.BlockSpec((1, 1), lambda b: (0, 0)),
@@ -317,6 +318,7 @@ def _lloyd_step_jit(
 
     sums, counts, inertia = pl.pallas_call(
         functools.partial(_lloyd_kernel, n, n_split),
+        name="lloyd_step",
         grid=((n + blk - 1) // blk,),
         in_specs=[
             pl.BlockSpec((blk, d), lambda b: (b, 0)),

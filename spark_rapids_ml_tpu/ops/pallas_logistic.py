@@ -186,6 +186,7 @@ def _sums_pallas(X, y, w, beta, b, blk: int, interpret: bool):
     f32 = jnp.float32
     g, s = pl.pallas_call(
         _eval_kernel,
+        name="logistic_eval",
         grid=(n // blk,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -328,6 +328,7 @@ def _fused_topk_scan(
     n_t = -(-n // item_tile)
     pool_d2, pool_id = pl.pallas_call(
         functools.partial(_topk_scan_kernel, n, k, precision),
+        name="select_topk_scan",
         grid=(n_qb, n_t),
         in_specs=[
             pl.BlockSpec((q_block, d), lambda i, t: (i, 0)),
@@ -504,6 +505,7 @@ def _fused_assign(
     n_b = -(-n // block)
     out = pl.pallas_call(
         functools.partial(_assign_kernel, n, n_split),
+        name="select_assign",
         grid=(n_b,),
         in_specs=[
             pl.BlockSpec((block, d), lambda b: (b, 0)),
@@ -640,6 +642,7 @@ def _fused_count(
     n_t = -(-n // item_tile)
     counts = pl.pallas_call(
         functools.partial(_count_kernel, n, precision),
+        name="select_count",
         grid=(n_qb, n_t),
         in_specs=[
             pl.BlockSpec((q_block, d), lambda i, t: (i, 0)),

@@ -144,7 +144,7 @@ def pca_attrs_from_cov(
     cov: jax.Array, mean: jax.Array, wsum: jax.Array, k: int
 ) -> Dict[str, np.ndarray]:
     """Model attributes from a (possibly streamed, ops/streaming.py) covariance."""
-    with span("pca.eig.solve"):
+    with span("pca.eig.solve", {"waits": "device"}):
         # waited for, so that the device's eigensolve and the host's
         # conversion below are not one number
         vals, vecs, total_var = jax.block_until_ready(_pca_from_cov(cov, k))

@@ -21,6 +21,7 @@ read, as the harness reads a window after its warm-up. Counts and names only: no
 """
 
 import json
+import math
 import os
 
 import numpy as np
@@ -217,10 +218,39 @@ def _total(counters, name, labels):
     return total
 
 
+def _check_host_usage(entry, spec, counters):
+    """What a reader would sum of a `host.*` counter: the host's usage over a
+    flagged span or the run scope (observability/runs.py). The label sets that
+    include the metric's labels are there, each a finite difference that is
+    never negative; 0 is a sound reading (a toy wait takes no fault, no switch
+    and no system time), but a whole run that used no CPU is not."""
+    from spark_rapids_ml_tpu.observability import split_label_key
+
+    labels = spec.get("labels", {})
+    found = {}  # key -> (value, the key's `waits`)
+    for key, value in counters.items():
+        name, have = split_label_key(key)
+        if name == spec["counter"] and all(have.get(k) == v for k, v in labels.items()):
+            found[key] = (float(value), have["waits"])
+    assert found, (
+        f"{entry['name']}: no `{spec['counter']}` with labels {labels}: "
+        f"{sorted(k for k in counters if k.startswith(spec['counter']))}")
+    assert all(math.isfinite(v) and v >= 0 for v, _ in found.values()), found
+    assert len({w for _, w in found.values()}) == 1, (
+        f"{entry['name']} sums spans of different waits: {sorted(found)}")
+    read = sum(v for v, _ in found.values())
+    if spec["counter"] == "host.cpu_seconds" and labels.get("waits") == "run":
+        assert read > 0, found
+    return read
+
+
 def _check_report_counter(entry, spec, run, emitted):
     from spark_rapids_ml_tpu.observability import label_key
 
     assert run["traffic"] == "fit", "report_counter_per_op reads fit_report_"
+    if spec["counter"].startswith("host."):
+        _check_host_usage(entry, spec, run["report_counters"])
+        return
     labels = spec.get("labels", {})
     key = label_key(spec["counter"], labels)
     counters = run["report_counters"]
@@ -260,6 +290,14 @@ def _check_counter_delta(entry, spec, run, emitted):
 def _check_counter_delta_per_op(entry, spec, run, emitted):
     from spark_rapids_ml_tpu.observability import label_key
 
+    if spec["counter"].startswith("host."):
+        # the process's totals move by what the warm operation used
+        added = (_check_host_usage(entry, spec, run["after"])
+                 - _check_host_usage(entry, spec, run["before"]))
+        assert added >= 0, f"{entry['name']}: the warm operation added {added}"
+        if spec.get("labels", {}).get("waits") == "run":
+            assert added > 0, f"{entry['name']}: a whole transform used no CPU"
+        return
     key = label_key(spec["counter"], spec.get("labels", {}))
     assert key in run["after"], f"{entry['name']}: no `{key}` among the process's counters"
     assert run["after"][key] - run["before"].get(key, 0) > 0, key
