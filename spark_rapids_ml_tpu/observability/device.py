@@ -688,6 +688,17 @@ def sample_hbm(force: bool = False) -> Optional[int]:
     return total
 
 
+def hbm_free_bytes(device: Any) -> Optional[int]:
+    """What `device`'s allocator has left of its limit, None where it does not
+    say (the CPU backend). A direct reading for a placement that decides on
+    it, neither sampled nor rate-limited: `parallel/partitioner.py`'s chunked
+    upload needs room for a second table for a moment."""
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats or "bytes_in_use" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats["bytes_in_use"])
+
+
 def note_run_start(run: Any) -> None:
     """FitRun/TransformRun __enter__ hook: open a per-run HBM peak tracker."""
     total = sample_hbm(force=True)

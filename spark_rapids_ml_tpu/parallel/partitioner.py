@@ -34,35 +34,181 @@
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, Sharding, SingleDeviceSharding
 
 from .. import config as _config
 from .. import observability as _obs
+from ..observability.device import hbm_free_bytes as _free_bytes
 from .mesh import DATA_AXIS, FEATURE_AXIS, get_mesh
 
 ROW_MULTIPLE = 8  # float32 sublane tile; keeps per-device shards MXU-friendly
 
 
-def _put(site: Optional[str], x: Any, place: Callable[[], jax.Array]) -> jax.Array:
-    """The one choke point of host->device placement. With a `site` ("fit",
-    "transform") the DISPATCH of the transfer is the span `h2d.put` (attrs
-    `bytes`, `site`) and `h2d.bytes{site=}` counts the `nbytes` of what is put;
-    the transfer itself is asynchronous and is waited for under `h2d.wait` by
-    whoever needs the array resident (core/estimator.py, observability/
-    inference.py). Without a site the placement is neither timed nor counted:
-    the streamed tier accounts for its batches itself (`stream.ingest`,
-    `stream.upload_bytes`), and nothing is counted twice."""
+# ---------------------------------------------------------- chunked upload
+#
+# One `device_put` of a 4.29 GB table runs at 10 GB/s while the runtime's
+# transfer threads contend inside the one transfer; the same bytes as row
+# chunks, all in flight at once, run at 13 to 14 GB/s for the same CPU seconds
+# (tools/upload_probe.py; PERF.md §6, PRs 36 and 37). So a sited put of a
+# large host array onto ONE device goes up as contiguous row chunks,
+# dispatched from the calling thread, each written as it lands into a
+# preallocated array of the whole shape by a compiled `dynamic_update_slice`
+# that the array is donated to (`jit_h2d_place`): the result is the array a
+# single put would have made, in shape, dtype, sharding, committedness and
+# layout, and HBM holds the table and the chunks in flight (1.5 tables at the
+# peak, where one concatenate of the chunks holds 2.0 and ends 0.01 s later).
+# Nothing is waited for here. A placement over several devices keeps the one
+# sharded put (`reason=devices`): no path of this program that chunks a
+# device's rows has run on a host of several chips. The probe's has
+# (tools/upload_probe.py `mesh`; PERF.md §6 PR 37, ROADMAP S12(f)): there the
+# sharded put is the slow one by far, so that gate is the next to lift.
+# There is no setting: the path follows what the input shows.
+
+CHUNK_BYTES = 32 << 20       # a row chunk, at most: 0.310-0.313 s for 4.29 GB at 128, 256 and 3000
+                             # columns; 64 MiB 0.314-0.337, 16 MiB 0.306-0.309 with the caller's
+                             # dispatch at the transfer's own pace, 8 MiB behind it (0.33-0.47)
+CHUNK_MIN_BYTES = 256 << 20  # an array under this goes up in one put
+CHUNK_ALIGN_ROWS = 1024      # chunks are whole tiles of either layout, but the last (rows so
+                             # wide that 1,024 pass CHUNK_BYTES: a power of two of them)
+
+_layout_refused: set = set()  # (shape, dtype, device) whose assembly missed the layout
+
+
+def h2d_place(whole, chunk, start):
+    """`chunk` written into `whole` from row `start` on; the next chunk's row."""
+    placed = jax.lax.dynamic_update_slice_in_dim(whole, chunk, start, axis=0)
+    return placed, start + chunk.shape[0]
+
+
+_place = jax.jit(h2d_place, donate_argnums=(0, 2))
+_identity = jax.jit(lambda a: a)
+
+
+@functools.lru_cache(maxsize=None)
+def _default_layout(shape: Tuple[int, ...], dtype: Any, device: Any) -> Any:
+    """The layout the runtime gives an array of `shape` on `device`: what a
+    whole `device_put` gets and every compiled program expects."""
+    struct = jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(device))
+    return _identity.lower(struct).compile().input_formats[0][0].layout
+
+
+def _host_aliased(device: Any) -> bool:
+    """The CPU backend's `device_put` aliases host memory: no transfer to
+    keep in flight."""
+    return device.platform == "cpu"
+
+
+def _whole_put(x: Any, target: Any) -> jax.Array:
+    """ONE `device_put` of `x`: as `target` says (a sharding or a device), or
+    uncommitted on the default device where it is None."""
+    if target is None:
+        return jax.device_put(jax.numpy.asarray(x))
+    return jax.device_put(x, target)
+
+
+def _one_device(target: Any) -> Any:
+    """The device a placement on ONE device puts on (the default device for
+    None); None where `target` spreads the array over several."""
+    if not isinstance(target, Sharding):
+        return target if target is not None else jax.local_devices()[0]
+    (device, *more) = target.device_set
+    return None if more else device
+
+
+def _chunk_rows(row_bytes: int, chunk_bytes: int) -> int:
+    """Rows a chunk: `chunk_bytes` at most (but one row is the least), whole
+    multiples of CHUNK_ALIGN_ROWS, or the power of two under it where the
+    rows are so wide that fewer fit."""
+    per = max(1, chunk_bytes // row_bytes)
+    if per >= CHUNK_ALIGN_ROWS:
+        return per - per % CHUNK_ALIGN_ROWS
+    return 1 << (per.bit_length() - 1)
+
+
+def _single_put_reason(x: Any, target: Any, min_bytes: int) -> Optional[str]:
+    """Why `x` goes up in ONE put, in the order asked; None where it goes up
+    in row chunks (`h2d.chunk_gate`, docs/metrics.md)."""
+    if int(getattr(x, "nbytes", 0)) < min_bytes:
+        return "bytes"
+    if not isinstance(x, np.ndarray):
+        return "source"  # already on a device, or no buffer to slice
+    if jax.process_count() > 1:
+        return "multiprocess"
+    device = _one_device(target)
+    if device is None:
+        return "devices"
+    if _host_aliased(device):
+        return "platform"
+    free = _free_bytes(device)
+    if free is not None and free < 2 * x.nbytes:
+        return "memory"  # the array and every chunk beside it: two tables at the worst
+    if (x.shape, x.dtype, device) in _layout_refused:
+        return "layout"
+    return None
+
+
+def _put_chunked(x: np.ndarray, target: Any, chunk_bytes: int) -> Tuple[Optional[jax.Array], int]:
+    """`x` placed on the one device `target` names (`_one_device`), as
+    contiguous row chunks of at most `chunk_bytes`, written there into one
+    array of the whole shape. Returns the array and the chunks dispatched;
+    (None, chunks) where it came out in another layout than a whole put's
+    (remembered: the gate says `layout` from then on)."""
+    dtype = jax.dtypes.canonicalize_dtype(x.dtype)  # as `device_put` would
+    device = None if target is None else _one_device(target)  # None: uncommitted, as the put
+    per = _chunk_rows(x.nbytes // len(x), chunk_bytes)
+    whole = jax.numpy.empty(x.shape, dtype, device=device)
+    start = jax.numpy.zeros((), np.int32, device=device)
+    dispatched = 0
+    for s in range(0, len(x), per):
+        whole, start = _place(whole, jax.device_put(x[s:s + per], device), start)
+        dispatched += 1
+    (on,) = whole.devices()
+    if whole.format.layout != _default_layout(whole.shape, whole.dtype, on):
+        _layout_refused.add((x.shape, x.dtype, on))
+        return None, dispatched
+    if isinstance(target, Sharding):  # the same buffer, under the placement's own sharding
+        whole = jax.make_array_from_single_device_arrays(x.shape, target, [whole])
+    return whole, dispatched
+
+
+def _put(site: Optional[str], x: Any, target: Any = None,
+         place: Optional[Callable[[], jax.Array]] = None) -> jax.Array:
+    """The one choke point of host->device placement: `x` put as `target`
+    says (`_whole_put`), or by `place` where a caller has its own single put
+    to the same `target` (`shard_inputs`). With a `site` ("fit", "transform")
+    the DISPATCH of the transfer is the span `h2d.put` (attrs `bytes`, `site`)
+    and `h2d.bytes{site=}` counts the `nbytes` of what is put; the transfer
+    itself is asynchronous and is waited for under `h2d.wait` by whoever
+    needs the array resident (core/estimator.py, observability/inference.py).
+    A sited put of a large array goes up in row chunks (above):
+    `h2d.chunk_gate{site=,chunked=,reason=}` says which way it went and why,
+    `h2d.chunks{site=}` counts the chunks. Without a site the placement is
+    neither timed nor counted nor chunked: the streamed tier accounts for its
+    batches itself (`stream.ingest`, `stream.upload_bytes`), and nothing is
+    counted twice."""
+    place = place or functools.partial(_whole_put, x, target)
     if site is None:
         return place()
     nbytes = int(getattr(x, "nbytes", 0))
     with _obs.span("h2d.put", {"site": site, "bytes": nbytes}):
-        out = place()
+        reason = _single_put_reason(x, target, CHUNK_MIN_BYTES)
+        out = None
+        if reason is None:
+            out, chunks = _put_chunked(x, target, CHUNK_BYTES)
+            _obs.counter_inc("h2d.chunks", chunks, site=site)
+            reason = "ok" if out is not None else "layout"
+        chunked = out is not None
+        if not chunked:
+            out = place()
     _obs.counter_inc("h2d.bytes", nbytes, site=site)
+    _obs.counter_inc("h2d.chunk_gate", 1, site=site,
+                     chunked="true" if chunked else "false", reason=reason)
     return out
 
 
@@ -134,8 +280,7 @@ class Partitioner:
         """Place a host array on the mesh with rows on the data axis
         (single-process; for multi-process staging use `shard_inputs`).
         `site`: see `_put`."""
-        sharding = self.data_sharding(np.ndim(x))
-        return _put(site, x, lambda: jax.device_put(x, sharding))
+        return _put(site, x, self.data_sharding(np.ndim(x)))
 
     def replicate(self, x: Any) -> jax.Array:
         return jax.device_put(x, self.state_sharding())
@@ -146,11 +291,7 @@ class Partitioner:
         enter the SPMD program (the pairwise streaming device blocks) and for
         a transform's host operands (observability/inference.py), which go to
         `device` where the weights they meet are committed to one."""
-        import jax.numpy as jnp
-
-        if device is not None:
-            return _put(site, x, lambda: jax.device_put(x, device))
-        return _put(site, x, lambda: jax.device_put(jnp.asarray(x)))
+        return _put(site, x, device)
 
     def shard_inputs(self, *local_arrays: Optional[np.ndarray],
                      site: Optional[str] = None) -> List[Optional[jax.Array]]:
@@ -171,7 +312,7 @@ class Partitioner:
             sh = self.data_sharding(np.ndim(a))
             # called before the loop moves on, so the closure's late binding is safe
             out.append(_put(
-                site, a, lambda: jax.make_array_from_process_local_data(sh, a)))
+                site, a, sh, lambda: jax.make_array_from_process_local_data(sh, a)))
         return out
 
     # ------------------------------------------------------------ staging
@@ -267,8 +408,7 @@ class SPMDPartitioner(Partitioner):
     def shard_features(self, x: Any, site: Optional[str] = None) -> jax.Array:
         """Place with rows on data AND columns on feature — the wide-k kNN /
         feature-sharded covariance layout."""
-        sharding = self.feature_sharding(np.ndim(x))
-        return _put(site, x, lambda: jax.device_put(x, sharding))
+        return _put(site, x, self.feature_sharding(np.ndim(x)))
 
 
 # --------------------------------------------------------------- active mgmt

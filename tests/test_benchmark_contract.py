@@ -46,6 +46,11 @@ READS_ZERO = {
     ("fit_lloyd_assign3_per_op", "kmeans_k20_d128.fit"): {"passes": "6"},
 }
 
+# the chunked upload's sizes at toy size (parallel/partitioner.py: 256 MiB, 32
+# MiB, 1,024 rows on the chip): every toy table goes up in two to four chunks
+# and every toy weight, label and centre in one put
+CHUNKING = {"CHUNK_MIN_BYTES": 16 << 10, "CHUNK_BYTES": 8 << 10, "CHUNK_ALIGN_ROWS": 128}
+
 # kinds that read the harness's clock or the device trace, nothing of the program
 HARNESS_KINDS = {"device_busy_per_op", "mfu", "upload_floor"}
 
@@ -126,7 +131,9 @@ def _run_cell(cell_name):
     from spark_rapids_ml_tpu import config, profiling
     from spark_rapids_ml_tpu.observability import device
     from spark_rapids_ml_tpu.observability.export import iter_spans
+    from spark_rapids_ml_tpu.observability import runs as obs_runs
     from spark_rapids_ml_tpu.ops import pallas_logistic
+    from spark_rapids_ml_tpu.parallel import partitioner
 
     cell = CELLS[cell_name]
     cfg = _load(CONFIG_FILES[cell["config"]])
@@ -145,6 +152,25 @@ def _run_cell(cell_name):
     # setting, so its platform test says what the chip would (the kernel runs
     # interpreted here, inside the same `_qn_fit` program)
     on_tpu, pallas_logistic._on_tpu = pallas_logistic._on_tpu, lambda: True
+    # and the upload's: its gate has no setting either, so its platform test
+    # says what the chip would and its sizes are cut to the toy table's
+    upload = {name: getattr(partitioner, name) for name in (*CHUNKING, "_host_aliased")}
+    for name, value in {**CHUNKING, "_host_aliased": lambda device: False}.items():
+        setattr(partitioner, name, value)
+    # every wait of the operation for something on the device, with the spans
+    # open around it on the waiting thread
+    import jax
+
+    waits = []
+    block_until_ready = jax.block_until_ready
+
+    def recording(x):
+        waits.append(([(node.name, dict(node.attrs)) for node in obs_runs._span_stack()],
+                      [tuple(a.shape) for a in jax.tree_util.tree_leaves(x)
+                       if isinstance(a, jax.Array)]))
+        return block_until_ready(x)
+
+    jax.block_until_ready = recording
     device.reset_device_plane()  # the cold operation compiles, whatever ran before
     try:
         estimator = _build(cfg, int(cell["chips"]))
@@ -157,9 +183,13 @@ def _run_cell(cell_name):
         cold_before = dict(profiling.counter_totals())
         operate()
         before = dict(profiling.counter_totals())
+        del waits[:]
         result = operate()
         after = dict(profiling.counter_totals())
     finally:
+        jax.block_until_ready = block_until_ready
+        for name, value in upload.items():
+            setattr(partitioner, name, value)
         pallas_logistic._on_tpu = on_tpu
         for key in settings:
             config.unset(key)
@@ -175,6 +205,7 @@ def _run_cell(cell_name):
         "spans": {s["name"] for s in iter_spans(report)},
         "cold_before": cold_before, "before": before, "after": after,
         "programs": _programs_called(counters),
+        "table_shape": X.shape, "has_label": "labelCol" in cfg["params"], "waits": list(waits),
     }
 
 
@@ -244,12 +275,35 @@ def _check_host_usage(entry, spec, counters):
     return read
 
 
+def _check_upload_chunks(entry, spec, run, added):
+    """What a reader would sum of `h2d.chunks{site=}`: what the gate beside it
+    says was done (`h2d.chunk_gate{site=,chunked=,reason=}`, once a put). Here
+    the table alone is over the toy threshold: ONE chunked put of as many
+    chunks as the toy sizes make of it, and every other put of the operation
+    whole because of its `bytes`; `h2d.bytes` still counts each array once."""
+    site = spec["labels"]["site"]
+    rows, cols = run["table_shape"]
+    fit, tile = max(1, CHUNKING["CHUNK_BYTES"] // (4 * cols)), CHUNKING["CHUNK_ALIGN_ROWS"]
+    per = fit - fit % tile if fit >= tile else 1 << (fit.bit_length() - 1)
+    assert added("h2d.chunk_gate", {"site": site, "chunked": "true", "reason": "ok"}) == 1
+    assert added("h2d.chunks", {"site": site}) == -(-rows // per) >= 2
+    whole = added("h2d.chunk_gate", {"site": site, "chunked": "false"})
+    assert whole == added("h2d.chunk_gate", {"site": site, "chunked": "false", "reason": "bytes"})
+    assert whole == (0 if site == "transform" else 2 if run["has_label"] else 1)  # weights, label
+    assert added("h2d.bytes", {"site": site}) >= 4 * rows * cols
+    assert added("span.calls", {"span": "h2d.put"}) == 1 + whole
+
+
 def _check_report_counter(entry, spec, run, emitted):
     from spark_rapids_ml_tpu.observability import label_key
 
     assert run["traffic"] == "fit", "report_counter_per_op reads fit_report_"
     if spec["counter"].startswith("host."):
         _check_host_usage(entry, spec, run["report_counters"])
+        return
+    if spec["counter"] == "h2d.chunks":
+        _check_upload_chunks(entry, spec, run,
+                             lambda name, labels: _total(run["report_counters"], name, labels))
         return
     labels = spec.get("labels", {})
     key = label_key(spec["counter"], labels)
@@ -297,6 +351,10 @@ def _check_counter_delta_per_op(entry, spec, run, emitted):
         assert added >= 0, f"{entry['name']}: the warm operation added {added}"
         if spec.get("labels", {}).get("waits") == "run":
             assert added > 0, f"{entry['name']}: a whole transform used no CPU"
+        return
+    if spec["counter"] == "h2d.chunks":
+        _check_upload_chunks(entry, spec, run, lambda name, labels: (
+            _total(run["after"], name, labels) - _total(run["before"], name, labels)))
         return
     key = label_key(spec["counter"], spec.get("labels", {}))
     assert key in run["after"], f"{entry['name']}: no `{key}` among the process's counters"
@@ -356,3 +414,19 @@ def test_the_program_gives_the_metric_what_it_reads(entry, spec, cell, runs, emi
         pytest.fail(f"cellbench/metrics/{entry['name']}.json is of kind {spec['kind']!r}: "
                     "tests/test_benchmark_contract.py has no check for that reader")
     CHECKS[spec["kind"]](entry, spec, runs(cell), emitted)
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_upload_is_waited_for_under_h2d_wait_and_nowhere_before(cell, runs):
+    """The chunks and their assembly are dispatched inside `h2d.put` and waited
+    for by nobody there: the one wait for the table is the innermost span
+    `h2d.wait` of the cell's site, flagged `waits=upload`, which is what
+    `*_upload_wait_s` and the `waits=upload` usage counters read."""
+    run = runs(cell)
+    site = run["traffic"]
+    for stack, _ in run["waits"]:
+        assert "h2d.put" not in [name for name, _ in stack], stack
+    for_the_table = [stack for stack, shapes in run["waits"] if run["table_shape"] in shapes]
+    assert for_the_table, run["waits"]
+    name, attrs = for_the_table[0][-1]  # the first wait that holds the table
+    assert (name, attrs.get("site"), attrs.get("waits")) == ("h2d.wait", site, "upload")

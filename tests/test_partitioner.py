@@ -26,6 +26,8 @@ import pytest
 import jax
 
 from spark_rapids_ml_tpu import config as _config
+from spark_rapids_ml_tpu import observability as _obs
+from spark_rapids_ml_tpu.parallel import partitioner as _P
 from spark_rapids_ml_tpu.parallel.mesh import (
     DATA_AXIS,
     FEATURE_AXIS,
@@ -519,3 +521,341 @@ def test_two_process_partitioner_ragged_parity(tmp_path):
     # rank-0-only model payload
     model = json.loads((tmp_path / "model.json").read_text())
     assert model["writer"] == 0
+
+
+# ------------------------------------------------------------ chunked upload
+#
+# A sited put of a large host array goes up in row chunks, joined on the
+# device (parallel/partitioner.py, "chunked upload"). On the CPU the gate says
+# `platform`, so the mechanics are reached through the helper itself with a
+# small chunk, and the gate's later tests by standing in for what it asks.
+
+def _h2d_counters(scope):
+    return {k: v for k, v in scope.registry.snapshot()["counters"].items()
+            if k.startswith("h2d.")}
+
+
+def _source(kind, rows, ndim):
+    rng = np.random.default_rng(37)
+    if ndim == 1:
+        wide = rng.normal(size=(rows, 2)).astype(np.float32)
+        return np.ascontiguousarray(wide[:, 0]) if kind == "c" else wide[:, 0]
+    if kind == "c":
+        return rng.normal(size=(rows, 6)).astype(np.float32)
+    if kind == "fortran":
+        return np.asfortranarray(rng.normal(size=(rows, 6)).astype(np.float32))
+    return rng.normal(size=(rows, 12)).astype(np.float32)[:, ::2]  # strided view
+
+
+@pytest.mark.parametrize("kind", ["c", "fortran", "strided"])
+@pytest.mark.parametrize("ndim", [1, 2])
+@pytest.mark.parametrize("rows", [4096, 5000])  # 4 chunks of 1024; 4 and a rest
+def test_chunked_put_equals_the_single_put(rows, ndim, kind, n_devices):
+    if ndim == 1 and kind == "fortran":
+        pytest.skip("a 1-D array has one order")
+    x = _source(kind, rows, ndim)
+    sharding = DataParallelPartitioner(1).data_sharding(ndim)
+    want = jax.device_put(x, sharding)
+    got, chunks = _P._put_chunked(x, sharding, chunk_bytes=1024 * x.itemsize * (x.size // len(x)))
+    assert chunks == -(-rows // 1024)
+    assert (got.shape, got.dtype, got.sharding) == (want.shape, want.dtype, want.sharding)
+    assert got.committed == want.committed and got.format == want.format
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for a, b in zip(got.addressable_shards, want.addressable_shards):
+        assert (a.device, a.index) == (b.device, b.index)
+
+
+def _placements(workers):
+    """Every sited placement of the partitioners, over `workers` devices."""
+    spmd = SPMDPartitioner(workers, feature_axis=2 if workers > 1 else 1)
+    return {
+        "shard": lambda x: DataParallelPartitioner(workers).shard(x, site="fit"),
+        "shard_inputs": lambda x: DataParallelPartitioner(workers).shard_inputs(x, site="fit")[0],
+        "shard_features": lambda x: spmd.shard_features(x, site="fit"),
+    }, {
+        "shard": lambda ndim: DataParallelPartitioner(workers).data_sharding(ndim),
+        "shard_inputs": lambda ndim: DataParallelPartitioner(workers).data_sharding(ndim),
+        "shard_features": lambda ndim: spmd.feature_sharding(ndim),
+    }
+
+
+@pytest.mark.parametrize("kind", ["c", "fortran", "strided"])
+@pytest.mark.parametrize("ndim", [1, 2])
+@pytest.mark.parametrize("place", ["shard", "shard_inputs", "shard_features"])
+def test_a_placement_over_several_devices_keeps_the_single_put(place, ndim, kind, small_chunks,
+                                                               monkeypatch, n_devices):
+    """No path of the program that chunks a device's rows has run on a host
+    of several chips: such a placement goes up as it always has, however
+    large, and the gate says `devices` (ROADMAP S12(f) lifts it)."""
+    if ndim == 1 and kind == "fortran":
+        pytest.skip("a 1-D array has one order")
+    if n_devices < 4:
+        pytest.skip("needs 4 devices")
+    placements, shardings = _placements(4)
+    x = _source(kind, 16384, ndim)  # over the toy threshold, whole and a device
+    want = jax.device_put(x, shardings[place](ndim))
+    calls = _count_device_puts(monkeypatch)
+    with _obs.worker_scope() as scope:
+        got = placements[place](x)
+    # make_array_from_process_local_data puts a shard a device itself
+    assert len(calls) == (1 if place != "shard_inputs" else len(calls))
+    assert (got.shape, got.dtype, got.sharding) == (want.shape, want.dtype, want.sharding)
+    assert got.committed == want.committed and got.format == want.format
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert _h2d_counters(scope) == {
+        "h2d.bytes{site=fit}": x.nbytes,
+        "h2d.chunk_gate{chunked=false,reason=devices,site=fit}": 1,
+    }
+
+
+@pytest.mark.parametrize("place", ["shard", "shard_inputs", "shard_features"])
+def test_every_placement_on_one_device_is_chunked(place, small_chunks, n_devices):
+    placements, shardings = _placements(1)
+    x = _source("c", 5000, 2)  # 117 KiB: an 8 KiB chunk is 256 rows of 24 bytes
+    want = jax.device_put(x, shardings[place](2))
+    with _obs.worker_scope() as scope:
+        got = placements[place](x)
+    assert (got.sharding, got.committed, got.format) == (want.sharding, want.committed, want.format)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert _h2d_counters(scope) == {
+        "h2d.bytes{site=fit}": x.nbytes,
+        "h2d.chunks{site=fit}": -(-5000 // 256),
+        "h2d.chunk_gate{chunked=true,reason=ok,site=fit}": 1,
+    }
+
+
+@pytest.mark.parametrize("target", ["default", "device"])
+def test_chunked_put_keeps_the_single_puts_commitment(target, n_devices):
+    """`put_local` leaves a query uncommitted on the default device, or
+    commits it beside the weights it meets: the assembled array does the same."""
+    x = _source("c", 3000, 2)
+    device = None if target == "default" else jax.devices()[n_devices - 1]
+    want = jax.device_put(x) if device is None else jax.device_put(x, device)
+    got, chunks = _P._put_chunked(x, device, chunk_bytes=1024 * 24)
+    assert chunks == 3
+    assert got.committed == want.committed and got.sharding == want.sharding
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_chunked_put_canonicalizes_the_dtype_as_the_single_put_does(n_devices):
+    x = np.arange(4096.0).reshape(2048, 2)  # float64: float32 on the device without x64
+    want = jax.device_put(x)
+    got, _ = _P._put_chunked(x, None, chunk_bytes=1024 * 16)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _count_device_puts(monkeypatch):
+    calls = []
+    real = jax.device_put
+
+    def counting(*args, **kwargs):
+        calls.append(1)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", counting)
+    return calls
+
+
+@pytest.mark.parametrize("place", ["shard", "put_local", "put_local_device", "shard_inputs"])
+def test_under_the_threshold_one_device_put(place, monkeypatch, n_devices):
+    """The weights, labels, centres, a served request, a small transform:
+    today's single put, and the gate says `bytes`."""
+    part = active_partitioner()
+    x = np.ones((8 * n_devices, 3), np.float32)
+    calls = _count_device_puts(monkeypatch)
+    with _obs.worker_scope() as scope:
+        if place == "shard":
+            part.shard(x, site="fit")
+        elif place == "shard_inputs":
+            part.shard_inputs(x, site="fit")
+        else:
+            part.put_local(x, site="transform",
+                           device=jax.devices()[0] if place.endswith("device") else None)
+    site = "transform" if place.startswith("put_local") else "fit"
+    # make_array_from_process_local_data puts a shard a device itself
+    assert len(calls) == (1 if place != "shard_inputs" else len(calls))
+    assert _h2d_counters(scope) == {
+        f"h2d.bytes{{site={site}}}": x.nbytes,
+        f"h2d.chunk_gate{{chunked=false,reason=bytes,site={site}}}": 1,
+    }
+
+
+def test_unsited_put_is_neither_counted_nor_chunked(monkeypatch, n_devices):
+    monkeypatch.setattr(_P, "CHUNK_MIN_BYTES", 1)
+    monkeypatch.setattr(_P, "_host_aliased", lambda device: False)
+    calls = _count_device_puts(monkeypatch)
+    with _obs.worker_scope() as scope:
+        active_partitioner().shard(np.ones((1024 * 8 * n_devices, 2), np.float32))
+    assert len(calls) == 1 and _h2d_counters(scope) == {}
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    """The gate's sizes cut to toy size and its platform test standing in for
+    a chip's, so a 96 KiB array is one that goes up in 8 KiB chunks."""
+    monkeypatch.setattr(_P, "CHUNK_MIN_BYTES", 32 << 10)
+    monkeypatch.setattr(_P, "CHUNK_BYTES", 8 << 10)
+    monkeypatch.setattr(_P, "_host_aliased", lambda device: False)
+    _P._layout_refused.clear()
+    yield
+    _P._layout_refused.clear()
+
+
+def test_a_large_sited_put_is_chunked_and_counted_once(small_chunks, n_devices):
+    part = DataParallelPartitioner(1)
+    x = _source("c", 12288, 1)  # 48 KiB: six chunks of 2,048 rows
+    with _obs.worker_scope() as scope:
+        got = part.shard(x, site="fit")
+    want = jax.device_put(x, part.data_sharding(1))
+    assert got.sharding == want.sharding and got.committed == want.committed
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert _h2d_counters(scope) == {
+        "h2d.bytes{site=fit}": x.nbytes,  # the array once, to the byte
+        "h2d.chunks{site=fit}": 6,
+        "h2d.chunk_gate{chunked=true,reason=ok,site=fit}": 1,
+    }
+    spans = scope.registry.snapshot()["counters"]
+    assert spans["span.calls{span=h2d.put}"] == 1  # every chunk inside the one span
+
+
+def test_a_query_is_chunked_where_put_local_places_it(small_chunks, n_devices):
+    x = _source("c", 4096, 2)  # 96 KiB
+    with _obs.worker_scope() as scope:
+        got = active_partitioner().put_local(x, site="transform")
+    assert not got.committed
+    np.testing.assert_array_equal(np.asarray(got), x)
+    assert _h2d_counters(scope)["h2d.chunks{site=transform}"] == 16  # 256 rows of 24 bytes
+
+
+@pytest.mark.parametrize("row_bytes,chunk_bytes,rows", [
+    (512, 32 << 20, 65536), (1024, 32 << 20, 32768), (12000, 32 << 20, 2048),  # the cells' tables
+    (12000, 8 << 20, 512), (40000, 32 << 20, 512), (1 << 20, 32 << 20, 32),  # fewer than 1,024 fit
+    (24, 8 << 10, 256), (64 << 20, 32 << 20, 1)])  # a chunk is one row at the least
+def test_a_chunk_is_at_most_chunk_bytes(row_bytes, chunk_bytes, rows):
+    """Whole multiples of 1,024 rows; where rows are so wide that 1,024 of
+    them pass the chunk's bytes, the power of two that fits."""
+    per = _P._chunk_rows(row_bytes, chunk_bytes)
+    assert per == rows
+    assert per * row_bytes <= chunk_bytes or per == 1
+    assert per % _P.CHUNK_ALIGN_ROWS == 0 or per & (per - 1) == 0
+
+
+def test_rows_wider_than_a_chunk_go_up_a_row_a_chunk(n_devices):
+    x = _source("c", 7, 2)
+    got, chunks = _P._put_chunked(x, None, chunk_bytes=8)  # a row is 24 bytes
+    assert chunks == 7
+    np.testing.assert_array_equal(np.asarray(got), x)
+
+
+def _gate_source(monkeypatch):
+    monkeypatch.setattr(_P, "_host_aliased", lambda device: True)  # never reached
+    return jax.device_put(_source("c", 4096, 2))
+
+
+def _gate_multiprocess(monkeypatch):
+    monkeypatch.setattr(jax, "process_count", lambda *a, **k: 2)
+    return _source("c", 4096, 2)
+
+
+def _gate_platform(monkeypatch):
+    monkeypatch.setattr(_P, "_host_aliased", lambda device: device.platform == "cpu")
+    return _source("c", 4096, 2)
+
+
+def _gate_memory(monkeypatch):
+    # room for the table, not for a second one beside it
+    monkeypatch.setattr(_P, "_free_bytes", lambda device: 96 * 1024 * 3 // 2)
+    return _source("c", 4096, 2)
+
+
+@pytest.mark.parametrize("reason,arrange", [
+    ("source", _gate_source), ("multiprocess", _gate_multiprocess),
+    ("platform", _gate_platform), ("memory", _gate_memory)])
+def test_the_gate_keeps_the_single_put_and_says_why(reason, arrange, small_chunks,
+                                                    monkeypatch, n_devices):
+    x = arrange(monkeypatch)
+    calls = _count_device_puts(monkeypatch)
+    with _obs.worker_scope() as scope:
+        got = active_partitioner(1).shard(x, site="fit")
+    assert len(calls) == 1  # fell back, did not fail
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(x))
+    assert got.sharding == active_partitioner(1).data_sharding(2)
+    assert _h2d_counters(scope) == {
+        "h2d.bytes{site=fit}": x.nbytes,
+        f"h2d.chunk_gate{{chunked=false,reason={reason},site=fit}}": 1,
+    }
+
+
+def test_free_memory_unknown_or_ample_does_not_gate(small_chunks, monkeypatch, n_devices):
+    x = _source("c", 4096, 2)
+    for free in (None, 2 * x.nbytes):
+        monkeypatch.setattr(_P, "_free_bytes", lambda device: free)
+        assert _P._single_put_reason(x, None, _P.CHUNK_MIN_BYTES) is None
+    monkeypatch.setattr(_P, "_free_bytes", lambda device: 2 * x.nbytes - 1)
+    assert _P._single_put_reason(x, None, _P.CHUNK_MIN_BYTES) == "memory"
+
+
+def test_free_bytes_reads_the_allocator_where_it_speaks():
+    class Device:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    assert _P._free_bytes(Device(None)) is None  # the CPU backend
+    assert _P._free_bytes(Device({"bytes_in_use": 5})) is None
+    assert _P._free_bytes(Device({"bytes_limit": 16, "bytes_in_use": 5})) == 11
+    assert _P._free_bytes(jax.devices()[0]) is None
+
+
+def test_an_assembly_in_another_layout_falls_back_and_is_remembered(small_chunks, monkeypatch,
+                                                                    n_devices):
+    """Were the assembled array to come out in another layout than a whole
+    put's, every compiled fit would copy the table: that shape keeps the
+    single put, from the first time on."""
+    monkeypatch.setattr(_P, "_default_layout", lambda shape, dtype, device: "another layout")
+    x = _source("c", 4096, 2)
+    part = active_partitioner(1)
+    with _obs.worker_scope() as scope:
+        first = part.shard(x, site="fit")
+    np.testing.assert_array_equal(np.asarray(first), x)
+    assert first.sharding == part.data_sharding(2)
+    assert _h2d_counters(scope) == {  # the chunks went up before the layout showed
+        "h2d.bytes{site=fit}": x.nbytes, "h2d.chunks{site=fit}": 16,
+        "h2d.chunk_gate{chunked=false,reason=layout,site=fit}": 1}
+    calls = _count_device_puts(monkeypatch)
+    with _obs.worker_scope() as scope:
+        second = part.shard(x, site="fit")
+    assert len(calls) == 1
+    np.testing.assert_array_equal(np.asarray(second), x)
+    assert _h2d_counters(scope) == {
+        "h2d.bytes{site=fit}": x.nbytes,
+        "h2d.chunk_gate{chunked=false,reason=layout,site=fit}": 1}
+
+
+def test_default_layout_is_what_a_whole_put_gets(n_devices):
+    for shape in ((2048, 3), (4096,)):
+        placed = jax.device_put(np.zeros(shape, np.float32))
+        (device,) = placed.devices()
+        assert _P._default_layout(shape, placed.dtype, device) == placed.format.layout
+
+
+def test_chunk_sizes_are_what_the_probe_measured():
+    """32 MiB chunks of whole 1,024-row tiles; nothing under 256 MiB a device
+    is chunked (tools/upload_probe.py `assembled`, `sizes`; PERF.md §6 PR 37)."""
+    assert _P.CHUNK_BYTES == 32 << 20 and _P.CHUNK_ALIGN_ROWS == 1024
+    assert _P.CHUNK_MIN_BYTES == 8 * _P.CHUNK_BYTES
+
+
+def test_every_chunk_is_written_into_the_donated_array(n_devices, recwarn):
+    """The array is donated from chunk to chunk (a refused donation would hold
+    a table a chunk) and two programs serve every chunk: the full ones and the
+    rest."""
+    x = _source("c", 5000, 2)
+    before = _P._place._cache_size()
+    got, chunks = _P._put_chunked(x, jax.devices()[0], chunk_bytes=1024 * 24)
+    assert chunks == 5 and _P._place._cache_size() - before <= 2
+    np.testing.assert_array_equal(np.asarray(got), x)
+    assert not [w for w in recwarn.list if "donated" in str(w.message)]

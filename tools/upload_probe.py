@@ -19,13 +19,47 @@ Defaults: 357376 3000 (the wide cells' table), every part:
               unflagged and of a flagged span, outside and inside a run scope
     program   the program's own put and wait (`Partitioner.shard(site="fit")`,
               then `h2d.wait`), eight times over: the per-operation table that
-              shows a pause if one falls in it
+              shows a pause if one falls in it; since PR 37 the put goes up
+              in row chunks (`put_chunks`, `gate`), so GB/s is over put AND
+              wait, with the placed array's layout and the device's peak
     same      one put and wait of the same array, three times
     fresh     of a fresh copy of it each time (made outside the reading)
     aligned   of a page-aligned, pre-touched source (anonymous mmap, 2 MiB
               aligned, huge pages asked for)
     chunks    4, 16 and 64 row chunks, all put from one thread and then
               waited for, and put and waited for by four threads
+    assembled the same chunks made ONE device array again, which is what every
+              fit function and predict kernel takes: row chunks of 64, 32, 16 and 8
+              MiB (whole multiples of 1,024 rows, the last one the rest), all
+              put from the calling thread, then assembled on the device in
+              one of two forms and waited for: `concat`, one compiled
+              `concatenate` of the chunks (a second table in HBM until it
+              has run), and `dus`, a preallocated buffer donated to a
+              compiled `dynamic_update_slice` a chunk, each running as its
+              chunk lands (`dusc`: the same with the offset carried on the
+              device and no scalar put a chunk). Beside them `whole`, one
+              `device_put`. Every line: seconds (put, assembly and wait),
+              the seconds the dispatch alone took the caller, GB/s, the
+              host's usage, the device's `bytes_in_use` before and
+              `peak_bytes_in_use` after (a lifetime peak: the forms run in
+              the order whole, dus, dusc, concat), the result's
+              `format.layout` and the first chunk's (`rep` -1 compiles);
+              last, a form a line, whether the result equals the whole put
+              on the device, and its layout beside the whole put's
+    mesh      the table row-sharded over EVERY device of the host (one on a
+              one-chip machine, four with `chiprun --chips 4`): one sharded
+              `device_put` (`whole`), the program's put (`Partitioner.shard`:
+              chunked on one device, the same sharded put on several, where
+              its gate says `devices`) and what the program does NOT do on
+              several: each device's rows in 32 MiB chunks of their own,
+              written into that device's array and the arrays joined under
+              the sharding, device after device (`per_device`) or a chunk a
+              device in turn (`interleaved`). Three readings each, and
+              whether each equals the whole put on the devices. The reading
+              a PR needs before it lets several devices past the gate
+    sizes     where chunking starts to pay: the table's first 64 MiB to 2 GiB
+              put whole and in 16 and 64 MiB chunks assembled either way,
+              five readings each, the median a line
     second    a fit puts two arrays (the table, then its row weights): the
               seconds the SECOND put's dispatch takes by how long after the
               first it comes (0, 0.2, 2 and 20 ms), and what one
@@ -52,13 +86,16 @@ import sys
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from spark_rapids_ml_tpu import observability as obs
 from spark_rapids_ml_tpu.observability import runs
 from spark_rapids_ml_tpu.parallel.partitioner import active_partitioner
 
-PARTS = ("host", "span", "program", "same", "fresh", "aligned", "chunks", "second", "small")
+PARTS = ("host", "span", "program", "same", "fresh", "aligned", "chunks", "assembled", "mesh",
+         "sizes", "second", "small")
 HUGE = 2 << 20
 
 
@@ -145,11 +182,20 @@ def program(X, reps=8):
             placed = part.shard(X, site="fit")
             with obs.span("h2d.wait", {"site": "fit", "waits": "upload"}):
                 jax.block_until_ready(placed)
+        layout = _layout(placed)
         placed.delete()
         counters = scope.registry.snapshot()["counters"]
         wait = _usage(counters, "h2d.wait")
         put = {"put_" + k: v for k, v in _usage(counters, "h2d.put").items()}
-        _say(part="program", rep=rep, gb_per_s=X.nbytes / wait["seconds"] / 1e9, **wait, **put)
+        seconds = wait["seconds"] + put["put_seconds"]  # the chunks' dispatch is part of the upload
+        _say(part="program", rep=rep, gb_per_s=X.nbytes / seconds / 1e9, put_and_wait_s=seconds,
+             layout=layout, peak_bytes_in_use=_memory()[1], **_chunking(counters), **wait, **put)
+
+
+def _chunking(counters):
+    """What the program's put did: chunks dispatched, and the gate's word."""
+    return {"put_chunks": counters.get("h2d.chunks{site=fit}", 0),
+            "gate": [key for key in counters if key.startswith("h2d.chunk_gate")]}
 
 
 def aligned_copy(X):
@@ -178,6 +224,192 @@ def chunks(X, reps=3):
         for name, upload in (("one_thread", all_from_one_thread), ("four_threads", by_four_threads)):
             for rep in range(reps):
                 _reading(f"chunks{n}_{name}", rep, X.nbytes, lambda: upload(parts))
+
+
+def _row_chunks(X, chunk_bytes, align=1024):
+    """Contiguous row ranges of `chunk_bytes` at most, whole multiples of
+    `align` rows but the last."""
+    per = max(align, chunk_bytes // (X.nbytes // X.shape[0]) // align * align)
+    return [X[s:s + per] for s in range(0, X.shape[0], per)]
+
+
+def h2d_assemble(*chunks):
+    return jnp.concatenate(chunks, axis=0)
+
+
+def h2d_place(buf, chunk, start):
+    return lax.dynamic_update_slice_in_dim(buf, chunk, start, axis=0)
+
+
+def h2d_place_next(buf, chunk, start):
+    return h2d_place(buf, chunk, start), start + chunk.shape[0]
+
+
+_equal = jax.jit(lambda a, b: jnp.all(a == b))  # no (n, d) array of booleans beside the two
+_concat = jax.jit(h2d_assemble)
+_place = jax.jit(h2d_place, donate_argnums=0)
+_place_next = jax.jit(h2d_place_next, donate_argnums=(0, 2))
+
+
+def _upload_whole(X, chunk_bytes):
+    return jax.device_put(X), None
+
+
+def _upload_concat(X, chunk_bytes):
+    placed = [jax.device_put(c) for c in _row_chunks(X, chunk_bytes)]
+    return _concat(*placed), placed[0]
+
+
+def _upload_dus(X, chunk_bytes):
+    """The offset of each chunk goes up as a scalar of its own."""
+    out = jnp.empty(X.shape, X.dtype)
+    start, first = 0, None
+    for c in _row_chunks(X, chunk_bytes):
+        placed = jax.device_put(c)
+        first = placed if first is None else first
+        out = _place(out, placed, np.int32(start))
+        start += c.shape[0]
+    return out, first
+
+
+def _upload_dusc(X, chunk_bytes):
+    """The offset stays on the device, carried from chunk to chunk."""
+    out, start, first = jnp.empty(X.shape, X.dtype), jnp.zeros((), jnp.int32), None
+    for c in _row_chunks(X, chunk_bytes):
+        placed = jax.device_put(c)
+        first = placed if first is None else first
+        out, start = _place_next(out, placed, start)
+    return out, first
+
+
+FORMS = (("whole", _upload_whole), ("dus", _upload_dus), ("dusc", _upload_dusc),
+         ("concat", _upload_concat))
+
+
+def _layout(a):
+    lay = a.format.layout
+    return None if lay is None else {"major_to_minor": list(lay.major_to_minor),
+                                     "tiling": [list(t) for t in lay.tiling]}
+
+
+def _memory():
+    stats = jax.devices()[0].memory_stats() or {}  # noqa: fence/device-analysis-off-plane
+    return stats.get("bytes_in_use"), stats.get("peak_bytes_in_use")
+
+
+def _assembled_reading(X, chunk_bytes, upload):
+    """One upload under a flagged span: (seconds by the span, dispatch seconds,
+    the host's usage, the placed array, its first chunk)."""
+    with obs.worker_scope() as scope:
+        with obs.span("probe.upload", {"waits": "upload"}):
+            t0 = time.perf_counter()
+            out, first = upload(X, chunk_bytes)
+            dispatch_s = time.perf_counter() - t0
+            jax.block_until_ready(out)
+    line = _usage(scope.registry.snapshot()["counters"], "probe.upload")
+    line["dispatch_s"] = dispatch_s
+    return line, out, first
+
+
+def assembled(X, reps=3, chunk_mibs=(64, 32, 16, 8)):
+    for form, upload in FORMS:
+        for chunk_mib in chunk_mibs if form != "whole" else (None,):
+            chunk_bytes = (chunk_mib or 0) << 20
+            for rep in range(-1 if chunk_mib else 0, reps):  # -1 compiles
+                in_use, _ = _memory()
+                line, out, first = _assembled_reading(X, chunk_bytes, upload)
+                _say(part=f"assembled_{form}" + (f"_{chunk_mib}MiB" if chunk_mib else ""), rep=rep,
+                     chunks=len(_row_chunks(X, chunk_bytes)) if chunk_mib else 1,
+                     gb_per_s=X.nbytes / line["seconds"] / 1e9, bytes_in_use_before=in_use,
+                     peak_bytes_in_use=_memory()[1], layout=_layout(out),
+                     chunk_layout=None if first is None else _layout(first),
+                     committed=out.committed, **line)
+                out.delete()
+                del out, first
+    # last, so that no reading's peak holds a comparison's two tables
+    whole = jax.device_put(X)
+    for form, upload in FORMS[1:]:
+        out, _ = upload(X, chunk_mibs[0] << 20)
+        _say(part=f"assembled_check_{form}", equals_whole=bool(_equal(out, whole)),
+             layout=_layout(out), whole_layout=_layout(whole),
+             same_layout_as_whole=out.format.layout == whole.format.layout)
+        out.delete()
+    whole.delete()
+
+
+def _upload_per_device(X, sharding, chunk_bytes, interleaved):
+    """Each device's rows of `X` in chunks of their own, joined under
+    `sharding`: every chunk of one device before the next device's, or a
+    chunk a device in turn."""
+    shares = [(device, _row_chunks(X[index], chunk_bytes), X[index].shape)
+              for device, index in sharding.addressable_devices_indices_map(X.shape).items()]
+    state = [[jnp.empty(shape, X.dtype, device=device), jnp.zeros((), jnp.int32, device=device)]
+             for device, _, shape in shares]
+    turns = [(i, c) for i, (_, chunks_, _) in enumerate(shares) for c in range(len(chunks_))]
+    if interleaved:
+        turns.sort(key=lambda turn: (turn[1], turn[0]))
+    for i, c in turns:
+        device, chunks_, _ = shares[i]
+        state[i] = list(_place_next(state[i][0], jax.device_put(chunks_[c], device), state[i][1]))
+    return jax.make_array_from_single_device_arrays(X.shape, sharding, [out for out, _ in state])
+
+
+def mesh(X, reps=3, chunk_bytes=32 << 20):
+    part = active_partitioner()
+    rows = X[:len(X) // (8 * part.num_workers) * 8 * part.num_workers]
+    sharding = part.data_sharding(2)
+    uploads = (("whole", lambda: jax.device_put(rows, sharding)),
+               ("program", lambda: part.shard(rows, site="fit")),
+               ("per_device", lambda: _upload_per_device(rows, sharding, chunk_bytes, False)),
+               ("interleaved", lambda: _upload_per_device(rows, sharding, chunk_bytes, True)))
+    for name, upload in uploads:
+        for rep in range(-1, reps):  # -1 compiles
+            with obs.worker_scope() as scope:
+                with obs.span("probe.upload", {"waits": "upload"}):
+                    t0 = time.perf_counter()
+                    placed = upload()
+                    dispatch_s = time.perf_counter() - t0
+                    jax.block_until_ready(placed)
+            counters = scope.registry.snapshot()["counters"]
+            line = _usage(counters, "probe.upload")
+            _say(part=f"mesh_{name}", rep=rep, devices=part.num_workers, dispatch_s=dispatch_s,
+                 gb_per_s=rows.nbytes / line["seconds"] / 1e9, layout=_layout(placed),
+                 peak_bytes_in_use=_memory()[1], **_chunking(counters), **line)
+            placed.delete()
+    whole = uploads[0][1]()
+    for name, upload in uploads[1:]:
+        placed = upload()
+        _say(part=f"mesh_check_{name}", devices=part.num_workers,
+             same_sharding=whole.sharding == placed.sharding,
+             same_layout=whole.format.layout == placed.format.layout,
+             equals_whole=bool(_equal(whole, placed)))
+        placed.delete()
+    whole.delete()
+
+
+def sizes(X, reps=5):
+    import statistics
+
+    row_bytes = X.nbytes // X.shape[0]
+    for total_mib in (64, 128, 256, 512, 1024, 2048):
+        part = X[:max(1024, (total_mib << 20) // row_bytes // 1024 * 1024)]
+        if part.shape[0] == X.shape[0] and total_mib << 20 > X.nbytes:
+            break
+        for form, upload in FORMS:
+            for chunk_mib in (None,) if form == "whole" else (16, 64):
+                if chunk_mib and chunk_mib << 20 >= part.nbytes:
+                    continue
+                seconds = []
+                for rep in range(-1, reps):
+                    line, out, _ = _assembled_reading(part, (chunk_mib or 0) << 20, upload)
+                    out.delete()
+                    if rep >= 0:
+                        seconds.append(line["seconds"])
+                med = statistics.median(seconds)
+                _say(part="sizes", form=form, bytes=int(part.nbytes), chunk_mib=chunk_mib,
+                     chunks=len(_row_chunks(part, chunk_mib << 20)) if chunk_mib else 1,
+                     median_s=med, min_s=min(seconds), max_s=max(seconds),
+                     gb_per_s=part.nbytes / med / 1e9)
 
 
 def second_put(X, reps=3):
@@ -271,6 +503,12 @@ def main(argv):
         del A
     if "chunks" in parts:
         chunks(X)
+    if "assembled" in parts:
+        assembled(X)
+    if "mesh" in parts:
+        mesh(X)
+    if "sizes" in parts:
+        sizes(X)
     if "second" in parts:
         second_put(X)
     if "small" in parts:
