@@ -149,6 +149,18 @@ FUSED_TOPK_MAX_K = 32
 # segment-reduce histogram (ops/pallas_histogram.py)
 PALLAS_HISTOGRAM_BLOCK_ROWS = 512
 PALLAS_HISTOGRAM_MAX_SEG_TILE = 2048
+# grouped form (rows sorted by node, ISSUE 38): rows a work item and packed
+# words (four one-byte bin ids each) a grid step. Provenance: 512 rows x 32
+# features is 0.27e9 multiply-adds a step, ten times a grid step's fixed cost,
+# with a 2 MiB accumulator block (32 x 128 bins x 128 lanes, float32); a level
+# of 357,376 x 3000 runs in 0.224 s, 0.18 s being the MXU's floor for the bin
+# indicators' n*d*128*128 multiply-adds (my chip run, PR 38).
+PALLAS_HISTOGRAM_GROUP_BLOCK_ROWS = 512
+PALLAS_HISTOGRAM_WORDS_PER_STEP = 8
+# bytes of one feature tile's (nodes, features, bins, statistics) histogram in
+# the forest's level step (ops/trees.py): the split search holds about five
+# arrays of this size at once, and nothing of a whole level's size
+FOREST_HIST_TILE_BYTES = 256 * 1024 * 1024
 
 # ------------------------------------------------------------ ANN lifecycle
 # (ops/ann_streaming.py + ops/ann_lifecycle.py, docs/design.md §7b)

@@ -296,11 +296,14 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
         is_cls = self._is_classification
 
         def _fit(inputs: FitInputs):
-            X = inputs.host_features
-            stats, n_classes = self._row_stats(inputs)
-            d = X.shape[1]
+            from ..observability import span
             from ..parallel.partition import pad_rows
             from ..parallel.partitioner import partitioner_for
+
+            X = inputs.host_features
+            with span("forest.labels"):
+                stats, n_classes = self._row_stats(inputs)
+            d = X.shape[1]
 
             mesh = inputs.mesh
             part = partitioner_for(mesh)
@@ -308,7 +311,7 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
 
             def shard_fn(arr: np.ndarray):
                 padded, _, _ = pad_rows(arr, n_dev)
-                return part.shard(padded)
+                return part.shard(padded, site="fit")
 
             param_sets = extra_params if extra_params is not None else [base]
             results = []
@@ -331,6 +334,10 @@ class _RandomForestEstimator(_RandomForestClass, _TpuEstimatorSupervised, _Rando
                     seed=int(p["random_state"]) if p["random_state"] is not None else 0,
                     shard_fn=shard_fn,
                     mesh=mesh,
+                    # the table the normal upload already placed: binned there
+                    X_dev=inputs.features,
+                    # a classifier's statistics without a weightCol are 0 or 1
+                    unit_stats=is_cls and inputs.host_row_weight is None,
                 )
                 attrs["num_classes"] = n_classes
                 results.append(attrs)

@@ -10,10 +10,12 @@
 #     then only ever touch uint8/int32 bin ids,
 #   * trees grow LEVEL-WISE over a perfect binary heap layout (static shapes: level t
 #     has 2^t node slots),
-#   * per level, ONE segment-sum pass builds the (node, feature, bin, stat) histogram;
-#     with row-sharded inputs XLA reduces the per-shard partial histograms across the
-#     mesh — the cross-device "histogram merge" is a psum, not a treelite concat,
-#   * split selection is a cumulative-sum + argmax over the histogram (all dense math),
+#   * per level, the (node, feature, bin, stat) histogram is built and searched a
+#     FEATURE TILE at a time with a running best a node (nothing of a whole level's
+#     size exists); with row-sharded inputs XLA reduces the per-shard partial
+#     histograms across the mesh — the cross-device "histogram merge" is a psum,
+#     not a treelite concat,
+#   * split selection is a cumulative-sum + argmax over the tile (all dense math),
 #   * child statistics are carried from the winning split, so each level costs exactly
 #     one data pass.
 # Prediction walks the heap with gathers, vmapped over trees.
@@ -27,12 +29,15 @@ from __future__ import annotations
 
 import functools
 import math
+import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
 
 
@@ -41,19 +46,66 @@ from ..observability.device import compiled_kernel
 # ---------------------------------------------------------------------------
 
 
+def _sorted_quantiles(S: np.ndarray, qs: np.ndarray) -> np.ndarray:
+    """`np.quantile(S, qs, axis=1)` (method "linear", `qs` a float64 array),
+    transposed to (columns, quantiles), of rows ALREADY sorted ascending:
+    numpy's own arithmetic (the virtual index and the weight in float64, the
+    neighbours' difference in the data's dtype, its two-sided lerp, a NaN
+    anywhere makes the row NaN) without its partition, which is all but a few
+    per cent of what the call costs. `tests/test_forest_reference.py` holds it
+    to `np.quantile` to the bit."""
+    m = S.shape[1]
+    q = np.asanyarray(qs, dtype=np.float64)
+    virtual = m * q + (1 + q * (1 - 1 - 1)) - 1  # _compute_virtual_index(m, q, 1, 1)
+    prev = np.floor(virtual)
+    nxt = prev + 1
+    above, below = virtual >= m - 1, virtual < 0
+    prev[above], nxt[above] = -1, -1
+    prev[below], nxt[below] = 0, 0
+    prev, nxt = prev.astype(np.intp), nxt.astype(np.intp)
+    gamma = np.asanyarray(virtual - prev, dtype=virtual.dtype)
+    lo, hi = S[:, prev], S[:, nxt]
+    diff = hi - lo
+    out = lo + diff * gamma
+    np.subtract(hi, diff * (1 - gamma), out=out, where=np.broadcast_to(gamma >= 0.5, out.shape))
+    out[np.isnan(S[:, -1])] = np.nan
+    return out
+
+
 def quantile_bin_edges(
     X: np.ndarray, max_bins: int, sample_limit: int = 200_000, seed: int = 0
 ) -> np.ndarray:
     """Per-feature quantile thresholds, (d, max_bins-1). Bin b holds x <= edges[b]
-    (last bin open). Computed host-side on a row sample, like every histogram GBM."""
-    n = X.shape[0]
-    if n > sample_limit:
-        idx = np.random.default_rng(seed).choice(n, sample_limit, replace=False)
-        Xs = X[idx]
-    else:
-        Xs = X
+    (last bin open). Computed host-side on a row sample, like every histogram GBM.
+
+    `np.quantile(sample, qs, axis=0)` to the bit, by column blocks on the host's
+    cores (at most 16 threads): a block of the sample's columns is made contiguous, sorted (numpy's
+    sort releases the interpreter's lock and runs at a millisecond a column of
+    200,000), and read at the quantiles' positions (`_sorted_quantiles`). The
+    whole-array call partitions strided columns one after another: 39 s for
+    200,000 x 3000 on the chip tool's host, the longest phase of a forest fit
+    it was in."""
+    n, d = X.shape
+    idx = None
+    if n > sample_limit:  # a set of rows: their order is nothing to a quantile
+        idx = np.sort(np.random.default_rng(seed).choice(n, sample_limit, replace=False))
     qs = np.linspace(0, 1, max_bins + 1)[1:-1]
-    return np.quantile(Xs, qs, axis=0).T.astype(np.float32)  # (d, max_bins-1)
+    out = np.empty((d, max_bins - 1), np.float32)
+    workers = min(16, os.cpu_count() or 1)
+    cols = max(1, min((128 << 20) // (X.dtype.itemsize * min(n, sample_limit)),
+                      max(16, -(-d // workers))))
+
+    def block(lo):
+        part = X[:, lo:lo + cols]
+        if idx is not None:
+            part = np.take(part, idx, axis=0)
+        columns = part.T.copy()  # a column contiguous; the sample's own, so sorted in place
+        columns.sort(axis=1)
+        out[lo:lo + cols] = _sorted_quantiles(columns, qs)
+
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        list(pool.map(block, range(0, d, cols)))
+    return out  # (d, max_bins-1)
 
 
 def bin_features(X: np.ndarray, edges: np.ndarray) -> np.ndarray:
@@ -70,26 +122,44 @@ def bin_features(X: np.ndarray, edges: np.ndarray) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _stat_weight(stats: jax.Array, impurity: str) -> jax.Array:
+def _parts(stats: jax.Array):
+    """The statistics of an array whose LAST axis holds them, one array each."""
+    return [stats[..., i] for i in range(stats.shape[-1])]
+
+
+def _weight_of(parts, impurity: str) -> jax.Array:
     if impurity == "variance":
-        return stats[..., 0]
-    return jnp.sum(stats, axis=-1)
+        return parts[0]
+    return functools.reduce(jnp.add, parts)
+
+
+def _impurity_w_of(parts, impurity: str) -> jax.Array:
+    """w * impurity — the additive form used for gain computation — of
+    statistics given one array each (a histogram tile keeps them apart: its
+    last axis is the chip's lanes, and a trailing axis of s would be padded to
+    a lane tile)."""
+    w = _weight_of(parts, impurity)
+    safe_w = jnp.maximum(w, 1e-12)
+    if impurity == "variance":
+        wy, wyy = parts[1], parts[2]
+        return wyy - wy * wy / safe_w
+    if impurity == "gini":
+        return w - functools.reduce(jnp.add, [x * x for x in parts]) / safe_w
+    # entropy
+    shares = [x / safe_w for x in parts]
+    ent = -functools.reduce(jnp.add, [
+        jnp.where(p > 0, p * jnp.log2(jnp.maximum(p, 1e-12)), 0.0) for p in shares
+    ])
+    return w * ent
+
+
+def _stat_weight(stats: jax.Array, impurity: str) -> jax.Array:
+    return _weight_of(_parts(stats), impurity)
 
 
 def _impurity_times_w(stats: jax.Array, impurity: str) -> jax.Array:
-    """w * impurity(stats) — the additive form used for gain computation."""
-    w = _stat_weight(stats, impurity)
-    safe_w = jnp.maximum(w, 1e-12)
-    if impurity == "variance":
-        wy, wyy = stats[..., 1], stats[..., 2]
-        return wyy - wy * wy / safe_w
-    p_sq_sum = jnp.sum(stats * stats, axis=-1) / safe_w
-    if impurity == "gini":
-        return w - p_sq_sum
-    # entropy
-    p = stats / safe_w[..., None]
-    ent = -jnp.sum(jnp.where(p > 0, p * jnp.log2(jnp.maximum(p, 1e-12)), 0.0), axis=-1)
-    return w * ent
+    """`_impurity_w_of` of statistics along the last axis."""
+    return _impurity_w_of(_parts(stats), impurity)
 
 
 def _leaf_value(stats: jax.Array, impurity: str) -> jax.Array:
@@ -105,30 +175,171 @@ def _leaf_value(stats: jax.Array, impurity: str) -> jax.Array:
 # Level-wise builder
 # ---------------------------------------------------------------------------
 
+# Which histogram a level takes (a static name a level; `level_forms` decides):
+#   "xla"      jax.ops.segment_sum (off the TPU)
+#   "direct"   the one-hot kernel over every node of the level
+#   "grouped"  rows sorted by node, the node one-hot local to a row block
 
-def _histogram(
-    Xb: jax.Array,
-    values: jax.Array,
-    node_id: jax.Array,
-    n_nodes: int,
-    nbins: int,
-    use_pallas: bool = False,
-    mesh=None,
-) -> jax.Array:
-    """(n_nodes, d, nbins, s) histogram. On TPU this runs the FACTORED pallas
-    node x bin one-hot-matmul kernel (ops/pallas_histogram.py
-    node_bin_histogram_pallas — one MXU contraction per feature per row block,
-    cost independent of the flattened segment count): single-device as a plain
-    pallas_call, multi-device per-shard under shard_map with a psum merge. The
-    segment_sum fallback's replicated output makes XLA psum partial histograms
-    the same way — but note that XLA's scatter lowering has been observed to
-    crash the TPU compiler outright at >=1M rows, so on TPU the pallas path is
-    the production path, not an optimization."""
-    from .pallas_histogram import node_bin_histogram
 
-    return node_bin_histogram(
-        Xb, node_id, values, n_nodes, nbins, use_pallas, mesh=mesh
+def feature_plan(d: int):
+    """(d_pad, f_max): the width the bin matrix is padded to and the widest
+    feature tile of a level step. Tiles are 4 * 2**k features (whole packed
+    words, a power of two of them, at least `WORDS_PER_STEP`), so every level's
+    tile divides `d_pad` whatever its width."""
+    from .pallas_histogram import WORDS_PER_STEP, _round_up
+
+    q = _round_up(-(-d // 4), WORDS_PER_STEP)
+    q_max = WORDS_PER_STEP
+    while q_max < min(q, 256):
+        q_max *= 2
+    return 4 * _round_up(q, q_max), 4 * q_max
+
+
+def level_tile(width: int, d: int, nbins: int, s: int, n: int = 0) -> int:
+    """Features a tile of a level of `width` nodes: as many as keep the tile's
+    (width, F, nbins, s) float32 histogram, and the (n, F) int32 ids a form
+    that is not grouped unpacks for it, within `FOREST_HIST_TILE_BYTES`."""
+    from ..autotune.defaults import FOREST_HIST_TILE_BYTES
+    from .pallas_histogram import WORDS_PER_STEP
+
+    _, f_max = feature_plan(d)
+    per_feature = 4 * max(width * nbins * s, n)
+    f = 4 * WORDS_PER_STEP
+    while 2 * f <= f_max and 2 * f * per_feature <= FOREST_HIST_TILE_BYTES:
+        f *= 2
+    return f
+
+
+def level_forms(max_depth: int, n: int, d: int, nbins: int, s: int,
+                use_pallas: bool, devices: int = 1):
+    """The histogram form of each level, from shapes (`hist_gate`)."""
+    from .pallas_histogram import hist_gate
+
+    if not use_pallas:
+        return ("xla",) * max_depth
+    return tuple(
+        "grouped" if hist_gate(2**t, d, nbins, s, n, devices)[0] else "direct"
+        for t in range(max_depth)
     )
+
+
+def _pack_words(Xb: jax.Array) -> jax.Array:
+    """(n, d_pad) one-byte ids -> (n, Q) int32 with Q = d_pad // 4: byte k of
+    word q is feature k * Q + q. Four contiguous column ranges, so the packing
+    is elementwise (adjacent columns in a word would be a strided read, or a
+    bitcast that XLA expands to an (n, d_pad) int32 array). A small caller's
+    way in: the fit's own binning writes the words itself."""
+    q = Xb.shape[1] // 4
+    x = Xb.astype(jnp.int32)
+    return (x[:, :q] | (x[:, q:2 * q] << 8) | (x[:, 2 * q:3 * q] << 16)
+            | (x[:, 3 * q:] << 24))
+
+
+def tile_features(packed: bool, j, f: int, d_pad: int) -> jax.Array:
+    """The feature of each row of feature tile `j` (traced) of `f` features: a
+    contiguous range of columns, or, of packed words, (byte, word of the tile)
+    with byte k of word q feature k * (d_pad // 4) + q."""
+    if not packed:
+        return j * f + jnp.arange(f, dtype=jnp.int32)
+    words = f // 4
+    byte = jnp.arange(4, dtype=jnp.int32)[:, None]
+    word = jnp.arange(words, dtype=jnp.int32)[None, :]
+    return (byte * (d_pad // 4) + j * words + word).reshape(f)
+
+
+def _tile_ids(Xb, packed: bool, j, f: int) -> jax.Array:
+    """(n, f) int32 bin ids of feature tile `j`, in `tile_features`' order."""
+    if not packed:
+        return jax.lax.dynamic_slice_in_dim(Xb, j * f, f, axis=1).astype(jnp.int32)
+    w = jax.lax.dynamic_slice_in_dim(Xb, j * (f // 4), f // 4, axis=1)
+    return jnp.concatenate([(w >> (8 * k)) & 0xFF for k in range(4)], axis=1)
+
+
+def _row_bin(Xb, packed: bool, row_feat) -> jax.Array:
+    """Each row's bin id at its own feature `row_feat`, (n,) int32. No per-row
+    lane gather (the slowest op class on TPU) and no (n, d) float operand: ONE
+    fused pass over the bin matrix, a select against the column's index and a
+    row sum in integers, whatever the level's width."""
+    cols = Xb.shape[1]
+    col = row_feat % cols if packed else row_feat
+    picked = jnp.sum(
+        jnp.where(jnp.arange(cols, dtype=jnp.int32)[None, :] == col[:, None],
+                  Xb.astype(jnp.int32), 0),
+        axis=1,
+    )
+    return (picked >> (8 * (row_feat // cols))) & 0xFF if packed else picked
+
+
+def _tile_histogram(form, Xb, packed, values, node_id, width, nbins, j, f, mesh, grouped):
+    """Feature tile `j` (traced) of `f` features of the level's histogram, one
+    array a statistic: (f, width, nbins) each, or in the grouped form (f, A,
+    nbins, C) with node a * C + c at [:, a, :, c] and the bins CUMULATIVE (the
+    kernel's one-hot is "id <= bin": the split search wants the running sums and
+    nothing else). Each producer's own order: nothing of a tile's size is
+    transposed."""
+    from .pallas_histogram import (
+        grouped_histogram_tile, node_bin_histogram, segment_histogram,
+    )
+
+    s = values.shape[1]
+    if form == "grouped":
+        pt, grp, interpret = grouped
+        return grouped_histogram_tile(pt, grp, j, f // 4, width, nbins, s, interpret)
+    xt = _tile_ids(Xb, packed, j, f)
+    if form == "direct":
+        h = node_bin_histogram(xt, node_id, values, width, nbins, True, mesh=mesh,
+                               feature_major=True)
+    else:
+        h = segment_histogram(node_id[:, None] * nbins + xt, values, width * nbins)
+    return _parts(h.reshape(f, width, nbins, s))
+
+
+def _tile_best(parts, cumulative, T, allowed, feat, nbins, impurity, min_instances):
+    """The best split of every node over one feature tile: (gain, feature *
+    (nbins - 1) + bin, left statistics), the lowest (feature, bin) among equal
+    gains. parts: `_tile_histogram`'s tile (`cumulative`: its bins are running
+    sums already); T: (nodes, s) node totals; allowed: (F, nodes) bool, the
+    nodes' feature draws over this tile's features; feat: (F,) which features
+    they are. Written once for both orders: the gains are (F, nodes..., bins at
+    axis 2, ...), reduced over features and bins with the nodes' axes kept."""
+    F = parts[0].shape[0]
+    n_nodes, s = T.shape
+    if parts[0].ndim == 4:  # grouped: (F, A, nbins, C)
+        A, C = parts[0].shape[1], parts[0].shape[3]
+        T_b = [T[:, i].reshape(1, A, 1, C) for i in range(s)]
+        ok = allowed.reshape(F, A, 1, C)
+    else:  # (F, nodes, nbins)
+        T_b = [T[:, i].reshape(1, n_nodes, 1) for i in range(s)]
+        ok = allowed[:, :, None]
+    if not cumulative:
+        parts = [jnp.cumsum(p, axis=2) for p in parts]
+    L = [jax.lax.slice_in_dim(p, 0, nbins - 1, axis=2) for p in parts]  # split at bin 0..b-2
+    R = [t - l for t, l in zip(T_b, L)]
+    wL = _weight_of(L, impurity)
+    wR = _weight_of(R, impurity)
+    gain = (
+        _impurity_w_of(T_b, impurity) - _impurity_w_of(L, impurity)
+        - _impurity_w_of(R, impurity)
+    ) / jnp.maximum(_weight_of(T_b, impurity), 1e-12)
+    bins = jnp.arange(nbins - 1, dtype=jnp.int32).reshape(
+        (1, 1, nbins - 1) + (1,) * (gain.ndim - 3))
+    valid = (wL >= min_instances) & (wR >= min_instances) & ok
+    gain = jnp.where(valid, gain, -jnp.inf)
+
+    red = (0, 2)
+    best_gain = jnp.max(gain, axis=red, keepdims=True)
+    flat = feat.reshape((F,) + (1,) * (gain.ndim - 1)) * (nbins - 1) + bins
+    best = jnp.min(  # the first of the maxima, as argmax over (feature, bin)
+        jnp.where(gain == best_gain, flat, _NO_SPLIT), axis=red, keepdims=True
+    )
+    chosen = flat == best
+    Lbest = jnp.stack(  # one term a node: exact
+        [jnp.sum(jnp.where(chosen, l, 0.0), axis=red).reshape(n_nodes) for l in L], axis=1)
+    return best_gain.reshape(n_nodes), best.reshape(n_nodes), Lbest
+
+
+# what `_tile_best` reports where no gain equals the maximum (a NaN gain)
+_NO_SPLIT = np.iinfo(np.int32).max
 
 
 # Opt-in per-level wall-clock collection: a test/bench sets
@@ -152,50 +363,79 @@ def _level_step(
     k_features: int,
     min_instances: int,
     min_info_gain: float,
-    use_pallas: bool,
     mesh,
+    form: str = "xla",
+    operand: str = "float32",
+    packed: bool = False,
 ):
-    """One tree level (width = 2**t): histogram, split selection, heap writes,
-    row routing, child-stat carry. Pure state -> state so it can run either
-    INLINED inside the jitted build_tree trace (the fast path — identical
-    program to the old unrolled loop) or as its own jitted program per level
-    (timing mode: one compiled dispatch + sync per level measures real device
-    wall-clock without making the whole tree eager — a full-eager 2e7-row level
-    was measured 3-10x slower on the 1-core CPU tier and unusable)."""
+    """One tree level (width = 2**t): histogram and split search a feature tile
+    at a time with a running best a node, heap writes, row routing, child-stat
+    carry. Nothing of the whole level histogram's size (width, d, nbins, s)
+    exists: a tile holds `level_tile` features. Pure state -> state so it can
+    run either INLINED inside the jitted build_tree trace or as its own jitted
+    program per level (timing mode: one compiled dispatch + sync per level
+    measures real device wall-clock without making the whole tree eager).
+    `Xb` is (n, d_pad) bin ids with d_pad from `feature_plan`, or under `packed`
+    (n, d_pad // 4) words of four one-byte ids (`_pack_words`); `edges` carries d."""
+    from .pallas_histogram import _interpret, group_rows, node_tile
+
     (feat_arr, thr_arr, leaf_arr, val_arr, gain_arr, wgt_arr, node_id, T, key) = state
-    n, d = Xb.shape
+    n = Xb.shape[0]
+    d_pad = Xb.shape[1] * (4 if packed else 1)
+    d = edges.shape[0]
     s = values.shape[1]
     width = 2**t
-    hist = _histogram(Xb, values, node_id, width, nbins, use_pallas, mesh)  # (w, d, b, s)
-    cum = jnp.cumsum(hist, axis=2)
-    L = cum[:, :, :-1, :]  # split at bin 0..b-2
-    R = T[:, None, None, :] - L
+    f = level_tile(width, d, nbins, s, 0 if form == "grouped" else n)
+    n_tiles = d_pad // f
 
-    wT = _stat_weight(T, impurity)  # (w,)
-    wL = _stat_weight(L, impurity)  # (w, d, b-1)
-    wR = _stat_weight(R, impurity)
-    gain = (
-        _impurity_times_w(T, impurity)[:, None, None]
-        - _impurity_times_w(L, impurity)
-        - _impurity_times_w(R, impurity)
-    ) / jnp.maximum(wT, 1e-12)[:, None, None]
-
-    valid = (wL >= min_instances) & (wR >= min_instances)
+    # the feature draw: the same stream a level as ever (one split of the key,
+    # one (width, d) uniform), masked inside each tile
     if k_features < d:
         key, sub = jax.random.split(key)
         scores = jax.random.uniform(sub, (width, d))
         from .selection import top_k_max
 
         kth = top_k_max(scores, k_features)[0][:, -1]
-        valid = valid & (scores >= kth[:, None])[:, :, None]
-    gain = jnp.where(valid, gain, -jnp.inf)
+        allowed = scores >= kth[:, None]
+    else:
+        allowed = jnp.ones((width, d), bool)
 
-    flat = gain.reshape(width, -1)
-    best = jnp.argmax(flat, axis=1)
-    best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
-    best_feat = (best // (nbins - 1)).astype(jnp.int32)
-    best_bin = (best % (nbins - 1)).astype(jnp.int32)
+    grouped = None
+    n_nodes = width
+    if form == "grouped":
+        assert packed, "the grouped kernel reads four one-byte ids a word"
+        c = node_tile(s)
+        n_nodes = -(-width // c) * c
+        grp = group_rows(node_id, values, width, jnp.dtype(operand))
+        words = Xb if grp["order"] is None else jnp.take(Xb, grp["order"], axis=0)
+        grouped = (words.T, grp, _interpret())
+    # feature-major: a tile's rows are a row gather; no padded feature, no padded node
+    allowed = jnp.pad(allowed, ((0, n_nodes - width), (0, d_pad - d))).T
+    T_pad = jnp.pad(T, ((0, n_nodes - width), (0, 0)))
 
+    def tile_best(j):
+        h = _tile_histogram(form, Xb, packed, values, node_id, width, nbins, j, f, mesh,
+                            grouped)
+        feat = tile_features(packed, j, f, d_pad)
+        return _tile_best(h, form == "grouped", T_pad, jnp.take(allowed, feat, axis=0),
+                          feat, nbins, impurity, min_instances)
+
+    best = tile_best(jnp.int32(0))
+    if n_tiles > 1:
+        def merge(carry, j):
+            new = tile_best(j)
+            # an equal gain keeps the lower (feature, bin), whatever order the
+            # tiles came in: the answer of one argmax over the whole level
+            take = (new[0] > carry[0]) | ((new[0] == carry[0]) & (new[1] < carry[1]))
+            return (jnp.where(take, new[0], carry[0]), jnp.where(take, new[1], carry[1]),
+                    jnp.where(take[:, None], new[2], carry[2])), None
+
+        best, _ = jax.lax.scan(merge, best, jnp.arange(1, n_tiles, dtype=jnp.int32))
+    best_gain, best_flat, Lbest = (x[:width] for x in best)
+    best_feat = jnp.minimum(best_flat // (nbins - 1), d - 1)  # _NO_SPLIT: a leaf anyway
+    best_bin = best_flat % (nbins - 1)
+
+    wT = _stat_weight(T, impurity)
     is_leaf_t = ~(best_gain > min_info_gain)  # also catches all -inf / NaN
     slots = width + jnp.arange(width)
     feat_arr = feat_arr.at[slots].set(jnp.where(is_leaf_t, -1, best_feat))
@@ -207,40 +447,13 @@ def _level_step(
     )
     wgt_arr = wgt_arr.at[slots].set(wT)
 
-    # route rows; leaf rows stay in the left child slot (unreachable at predict).
-    # The naive per-row lane gather (take_along_axis by best_feat[node]) is the
-    # slowest op class on TPU — measured 164 ms/level at 4M x 64, w=256. Two
-    # gather-free formulations (both bit-identical to the gather on hardware):
-    #  - matmul route: G=onehot(node) bf16, picked = rowsum((G @ onehot(feat)) * X)
-    #    (23.8 ms measured) — exact while the per-row one-hot sums and the bin
-    #    ids stay <= 256 (bf16 integer range) and G (n x width) fits HBM;
-    #  - row-gather route: A[node] for A=(width,d) one-hot + mask-sum (77 ms) —
-    #    no (n, width) intermediate, used for deep/wide levels.
-    leaf_f = is_leaf_t.astype(jnp.float32)
-    # n * width bound: G is a materialized (n, width) bf16 array — cap it at
-    # ~2.5 GiB so flagship-scale fits (12M rows) fall back to the row-gather
-    # route at deep levels instead of OOMing HBM
-    if width <= 256 and nbins <= 256 and n * width * 2 <= 2_500_000_000:
-        G = jax.nn.one_hot(node_id, width, dtype=jnp.bfloat16)
-        A = jax.nn.one_hot(best_feat, d, dtype=jnp.bfloat16)
-        picked = jnp.sum(
-            jnp.matmul(G, A).astype(jnp.float32) * Xb.astype(jnp.float32), axis=1
-        )
-        thr_r = jnp.matmul(G, best_bin.astype(jnp.bfloat16)[:, None])[:, 0]
-        leaf_r = jnp.matmul(G, leaf_f.astype(jnp.bfloat16)[:, None])[:, 0] > 0.5
-        go_right = (picked > thr_r.astype(jnp.float32)) & ~leaf_r
-    else:
-        A = jax.nn.one_hot(best_feat, d, dtype=jnp.float32)
-        picked = jnp.sum(A[node_id] * Xb.astype(jnp.float32), axis=1)
-        go_right = (picked > best_bin[node_id].astype(jnp.float32)) & ~(  # noqa: fence/host-staging-copy
-            is_leaf_t[node_id]
-        )
+    # route rows; leaf rows stay in the left child slot (unreachable at predict)
+    picked = _row_bin(Xb, packed, best_feat[node_id])
+    go_right = (picked > best_bin[node_id]) & ~is_leaf_t[node_id]
     node_id = node_id * 2 + go_right.astype(jnp.int32)
 
     # children stats carried from the winning split
-    Lbest = cum[jnp.arange(width), best_feat, best_bin, :]  # (w, s)
-    Rbest = T - Lbest
-    T = jnp.stack([Lbest, Rbest], axis=1).reshape(2 * width, s)
+    T = jnp.stack([Lbest, T - Lbest], axis=1).reshape(2 * width, s)
     return (feat_arr, thr_arr, leaf_arr, val_arr, gain_arr, wgt_arr, node_id, T, key)
 
 
@@ -253,14 +466,16 @@ _level_step_jit = functools.partial(
         "k_features",
         "min_instances",
         "min_info_gain",
-        "use_pallas",
         "mesh",
+        "form",
+        "operand",
+        "packed",
     ),
 )(_level_step)
 
 
 def _build_tree_impl(
-    Xb: jax.Array,  # (n, d) int32 bins, rows may be sharded
+    Xb: jax.Array,  # (n, d) or (n, d_pad) bin ids (uint8 or int32), rows may be sharded
     values: jax.Array,  # (n, s) per-row stats already weighted (0 rows contribute 0)
     edges: jax.Array,  # (d, nbins-1) real thresholds
     key: jax.Array,  # per-tree PRNG key (feature subsets)
@@ -273,13 +488,27 @@ def _build_tree_impl(
     use_pallas: bool = False,
     mesh=None,
     level_timing=None,
+    forms=None,
+    operand: str = "float32",
+    packed: bool = False,
 ) -> Dict[str, jax.Array]:
     """Grow one tree; returns heap arrays of size 2^(max_depth+1):
-    feature (int32, -1 for leaf), threshold (f32), is_leaf (bool), value (slots, v)."""
-    n, d = Xb.shape
+    feature (int32, -1 for leaf), threshold (f32), is_leaf (bool), value (slots, v).
+    `forms` names each level's histogram (`level_forms`); without it every level
+    takes the one-hot kernel (`use_pallas`) or the segment_sum. `packed`: `Xb`
+    is the fit's (n, d_pad // 4) words of four one-byte ids, not (n, d) ids."""
+    n = Xb.shape[0]
+    d = edges.shape[0]
     s = values.shape[1]
     n_slots = 2 ** (max_depth + 1)
     v_dim = 1 if impurity == "variance" else s
+    if forms is None:
+        forms = ("direct" if use_pallas else "xla",) * max_depth
+    d_pad, _ = feature_plan(d)
+    if not packed:  # a bare caller's matrix (tests, the streamed tier's uint8)
+        Xb = jnp.pad(Xb, ((0, 0), (0, d_pad - d)))
+        if "grouped" in forms:
+            Xb, packed = _pack_words(Xb), True
 
     state = (
         jnp.full((n_slots,), -1, jnp.int32),  # feature (-1 = leaf)
@@ -298,7 +527,7 @@ def _build_tree_impl(
     step_kw = dict(
         nbins=nbins, impurity=impurity, k_features=k_features,
         min_instances=min_instances, min_info_gain=min_info_gain,
-        use_pallas=use_pallas, mesh=mesh,
+        mesh=mesh, operand=operand, packed=packed,
     )
     for t in range(max_depth):
         if level_timing is not None:
@@ -306,14 +535,14 @@ def _build_tree_impl(
             # otherwise each level's first run per process times trace+compile
             # (seconds of XLA work) instead of device wall-clock
             exe = _level_step_jit.lower(
-                state, Xb, values, edges, t=t, **step_kw
+                state, Xb, values, edges, t=t, form=forms[t], **step_kw
             ).compile()
-            t0 = time.perf_counter()
+            t0 = time.perf_counter()  # noqa: purity/time-read (timing mode never runs under a jit)
             state = exe(state, Xb, values, edges)
             state[7].block_until_ready()  # T — the sync exists only in timing mode
-            level_timing.append((t, time.perf_counter() - t0))
+            level_timing.append((t, time.perf_counter() - t0))  # noqa: purity/time-read
         else:
-            state = _level_step(state, Xb, values, edges, t, **step_kw)
+            state = _level_step(state, Xb, values, edges, t, form=forms[t], **step_kw)
     (feat_arr, thr_arr, leaf_arr, val_arr, gain_arr, wgt_arr, node_id, T, key) = state
 
     # deepest level: all leaves
@@ -410,8 +639,8 @@ def resolve_feature_subset(strategy: str, d: int, is_classification: bool) -> in
     raise ValueError(f"Unsupported featureSubsetStrategy: {strategy}")
 
 
-@functools.partial(
-    jax.jit,
+@compiled_kernel(
+    "trees.build_tree",
     static_argnames=(
         "max_depth",
         "nbins",
@@ -421,6 +650,9 @@ def resolve_feature_subset(strategy: str, d: int, is_classification: bool) -> in
         "min_info_gain",
         "use_pallas",
         "mesh",  # jax.sharding.Mesh is hashable; static so shard_map can close over it
+        "forms",
+        "operand",
+        "packed",
     ),
 )
 def build_tree(
@@ -436,6 +668,9 @@ def build_tree(
     min_info_gain: float,
     use_pallas: bool = False,
     mesh=None,
+    forms=None,
+    operand: str = "float32",
+    packed: bool = False,
 ) -> Dict[str, jax.Array]:
     """Jitted tree growth (see _build_tree_impl). The jitted path NEVER times —
     the level-timing hooks would record trace time, not device time — so
@@ -443,7 +678,43 @@ def build_tree(
     return _build_tree_impl(
         Xb, values, edges, key, max_depth, nbins, impurity, k_features,
         min_instances, min_info_gain, use_pallas, mesh, level_timing=None,
+        forms=forms, operand=operand, packed=packed,
     )
+
+
+@compiled_kernel("trees.bin_features", static_argnames=("d_pad",))
+def bin_features_device(X: jax.Array, edges: jax.Array, d_pad: int) -> jax.Array:
+    """`bin_features` on the device, from the table the fit already uploaded:
+    (n, d) floats against (d, max_bins - 1) edges -> (n, d_pad // 4) int32, four
+    one-byte ids a word as `_pack_words` lays them (byte k of word q is feature
+    k * (d_pad // 4) + q; features past d read 0). bin = #edges < x,
+    `np.searchsorted(side="left")` to the bit: a value equal to an edge stays
+    in the edge's bin, +inf takes the last bin, and so does NaN (which numpy
+    sorts past every edge). One fused pass: the table is read once and one byte
+    an id is written; the comparisons against the (d,) edge rows are unrolled,
+    max_bins - 1 of them (<= 255)."""
+    d = X.shape[1]
+    q = d_pad // 4
+    words = None
+    for k in range(4):
+        lo, hi = k * q, min((k + 1) * q, d)
+        if lo >= hi:
+            break
+        x = X[:, lo:hi]
+        count = jnp.zeros(x.shape, jnp.int32)
+        for b in range(edges.shape[1]):
+            count = count + (x > edges[lo:hi, b][None, :]).astype(jnp.int32)
+        ids = jnp.where(jnp.isnan(x), edges.shape[1], count)
+        ids = jnp.pad(ids, ((0, 0), (0, q - (hi - lo)))) << (8 * k)
+        words = ids if words is None else words | ids
+    return words
+
+
+def unpack_bins(words: np.ndarray, d: int) -> np.ndarray:
+    """(n, d) uint8 bin ids of `bin_features_device`'s words, on the host."""
+    w = np.asarray(words)
+    return np.concatenate(
+        [((w >> (8 * k)) & 0xFF).astype(np.uint8) for k in range(4)], axis=1)[:, :d]
 
 
 def forest_fit(
@@ -461,27 +732,72 @@ def forest_fit(
     seed: int,
     shard_fn=None,
     mesh=None,
+    X_dev=None,
+    unit_stats: bool = False,
 ) -> Dict[str, np.ndarray]:
     """Bin once, then grow the forest tree-by-tree (one XLA compile; trees differ
     only in their bootstrap weights and PRNG key). `shard_fn` optionally places the
-    binned arrays on the mesh so histograms psum across devices."""
+    host arrays on the mesh so histograms psum across devices. `X_dev` is the
+    table as the fit's normal upload placed it (rows padded as `shard_fn` pads):
+    with it, and max_bins <= 256, the bin ids are made ON the device, one byte
+    each, from the host-sampled edges, and no second table goes up; without it
+    the host bins (`native.bin_features`) and the ids are uploaded. `unit_stats`:
+    the caller vouches that every row statistic is 0 or 1 (a classifier without
+    a weightCol), so weighted by a tree's whole-number row weights they are
+    whole numbers, exact in the grouped kernel's narrow operands (`_operand`)."""
     if n_trees < 1:
         raise ValueError(f"numTrees must be >= 1, got {n_trees}")
     if max_depth < 0:
         raise ValueError(f"maxDepth must be >= 0, got {max_depth}")
     n, d = X_host.shape
-    edges = quantile_bin_edges(X_host, max_bins, seed=seed)
-    Xb_host = bin_features(X_host, edges)
-
-    Xb = jnp.asarray(Xb_host) if shard_fn is None else shard_fn(Xb_host)
+    with span("forest.edges"):
+        edges = quantile_bin_edges(X_host, max_bins, seed=seed)
+    on_device = X_dev is not None and max_bins <= 256
+    counter_inc("forest.bin_path", 1, path="device" if on_device else "host")
+    with span("forest.bin"):
+        if on_device:  # four one-byte ids a word
+            Xb = bin_features_device(X_dev, jnp.asarray(edges), feature_plan(d)[0])
+            Xb.block_until_ready()
+        else:
+            Xb_host = bin_features(X_host, edges)
+            Xb = jnp.asarray(Xb_host) if shard_fn is None else shard_fn(Xb_host)
     raw_stats = (
         jnp.asarray(raw_stats_host) if shard_fn is None else shard_fn(raw_stats_host)
     )
     return _grow_forest(
         Xb, raw_stats, edges, n, n_trees, max_depth, max_bins, impurity,
         feature_subset, min_instances, min_info_gain, subsampling_rate,
-        bootstrap, seed, shard_fn, mesh,
+        bootstrap, seed, shard_fn, mesh, unit_stats=unit_stats, packed=on_device,
     )
+
+
+def tree_row_weights(seed: int, n: int, n_trees: int, subsampling_rate: float = 1.0,
+                     bootstrap: bool = True):
+    """The row weights of each tree, in order (docs/api.md states the rule and
+    `cellbench/forest_ref.py` draws them again): ONE generator a fit,
+    `np.random.default_rng(seed & 0x7FFFFFFF)`; tree i takes its i-th draw of
+    n values: `poisson(subsamplingRate, n)` under bootstrap, else
+    `random(n) < subsamplingRate` when that is below 1, else ones."""
+    rng = np.random.default_rng(seed & 0x7FFFFFFF)
+    for _ in range(n_trees):
+        if bootstrap:
+            yield rng.poisson(subsampling_rate, size=n).astype(np.float32)
+        elif subsampling_rate < 1.0:
+            yield (rng.random(n) < subsampling_rate).astype(np.float32)
+        else:
+            yield np.ones((n,), np.float32)
+
+
+def _operand(unit_stats: bool, w_tree: np.ndarray) -> str:
+    """The grouped kernel's operand type for one tree: where every row statistic
+    is 0 or 1 (`unit_stats`) the weighted statistics are the tree's whole-number
+    row weights, exact in int8 up to 127 (the MXU's fastest operands on a v5e)
+    and in bfloat16 up to 256; anything else rides in float32. Sums are float32
+    (int32 under int8) either way."""
+    if not unit_stats:
+        return "float32"
+    top = float(w_tree.max(initial=0.0))
+    return "int8" if top <= 127 else "bfloat16" if top <= 256 else "float32"
 
 
 def _grow_forest(
@@ -501,54 +817,62 @@ def _grow_forest(
     seed: int,
     shard_fn=None,
     mesh=None,
+    unit_stats: bool = False,
+    packed: bool = False,
 ) -> Dict[str, np.ndarray]:
     """The per-tree growth loop over ALREADY-BINNED device arrays — shared by the
     in-core forest_fit and the out-of-core streaming_forest_fit so a parity test
     between them exercises only the ingest path. `n` is the REAL row count (the
-    binned arrays may carry padded rows whose stats are zero)."""
-    from .pallas_histogram import default_use_pallas
+    binned arrays may carry padded rows whose stats are zero). Every tree is
+    dispatched before any result is read: the trees come back in one fetch."""
+    from .pallas_histogram import default_use_pallas, hist_gate
 
     use_pallas = default_use_pallas()
+    multi = mesh is not None and mesh.devices.size > 1
+    d, s = edges.shape[0], raw_stats.shape[1]
+    devices = mesh.devices.size if multi else 1
+    gate_bins = max_bins if packed else 257  # ids not packed a byte each: no grouped form
+    forms = level_forms(max_depth, Xb.shape[0], d, gate_bins, s, use_pallas, devices)
+    for t, form in enumerate(forms):
+        grouped, reason = hist_gate(2**t, d, gate_bins, s, Xb.shape[0], devices)
+        counter_inc("forest.hist_gate", n_trees, grouped=int(grouped), reason=reason)
+        counter_inc("forest.hist_path", n_trees, path=form)
     edges_j = jnp.asarray(edges)
-    rng = np.random.default_rng(seed & 0x7FFFFFFF)
-    trees: List[Dict[str, np.ndarray]] = []
-    for i in range(n_trees):
-        if bootstrap:
-            w_tree = rng.poisson(subsampling_rate, size=n).astype(np.float32)
-        elif subsampling_rate < 1.0:
-            w_tree = (rng.random(n) < subsampling_rate).astype(np.float32)
-        else:
-            w_tree = np.ones((n,), np.float32)
-        w_j = jnp.asarray(w_tree) if shard_fn is None else shard_fn(w_tree)
-        if _LEVEL_TIMING is not None:
-            build_fn = functools.partial(_build_tree_impl, level_timing=_LEVEL_TIMING)
-        else:
-            build_fn = build_tree
-        tree = build_fn(
-            Xb,
-            raw_stats * w_j[:, None],
-            edges_j,
-            jax.random.PRNGKey((seed + 7919 * i) & 0x7FFFFFFF),
-            max_depth=max_depth,
-            nbins=max_bins,
-            impurity=impurity,
-            k_features=feature_subset,
-            min_instances=min_instances,
-            min_info_gain=min_info_gain,
-            use_pallas=use_pallas,
-            mesh=mesh if (mesh is not None and mesh.devices.size > 1) else None,
-        )
-        trees.append({k: np.asarray(v) for k, v in tree.items()})
-
-    return {
-        "feature": np.stack([t["feature"] for t in trees]),
-        "threshold": np.stack([t["threshold"] for t in trees]),
-        "is_leaf": np.stack([t["is_leaf"] for t in trees]),
-        "value": np.stack([t["value"] for t in trees]),
-        "gain": np.stack([t["gain"] for t in trees]),
-        "node_weight": np.stack([t["node_weight"] for t in trees]),
-        "bin_edges": edges,
-    }
+    trees: List[Dict[str, jax.Array]] = []
+    if _LEVEL_TIMING is not None:
+        build_fn = functools.partial(_build_tree_impl, level_timing=_LEVEL_TIMING)
+    else:
+        build_fn = build_tree
+    with span("forest.grow", {"waits": "device"}):
+        for i, w_tree in enumerate(
+            tree_row_weights(seed, n, n_trees, subsampling_rate, bootstrap)
+        ):
+            w_j = jnp.asarray(w_tree) if shard_fn is None else shard_fn(w_tree)
+            trees.append(build_fn(
+                Xb,
+                raw_stats * w_j[:, None],
+                edges_j,
+                jax.random.PRNGKey((seed + 7919 * i) & 0x7FFFFFFF),
+                max_depth=max_depth,
+                nbins=max_bins,
+                impurity=impurity,
+                k_features=feature_subset,
+                min_instances=min_instances,
+                min_info_gain=min_info_gain,
+                use_pallas=use_pallas,
+                mesh=mesh if multi else None,
+                forms=forms,
+                operand=_operand(unit_stats, w_tree),
+                packed=packed,
+            ))
+        jax.block_until_ready(trees[-1])
+    counter_inc("forest.trees", n_trees)
+    counter_inc("forest.levels", n_trees * max_depth)
+    with span("forest.fetch"):
+        fetched = jax.device_get(trees)
+    out = {k: np.stack([t[k] for t in fetched]) for k in fetched[0]}
+    out["bin_edges"] = edges
+    return out
 
 
 def streaming_forest_fit(

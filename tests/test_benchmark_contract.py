@@ -23,6 +23,7 @@ read, as the harness reads a window after its warm-up. Counts and names only: no
 import json
 import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -38,7 +39,8 @@ ROWS, COLS, MAX_K, MAX_ITER = 512, 16, 4, 3
 # multiplies only the upper column blocks of the Gram matrix from
 # `autotune/defaults.py::GRAM_TRIANGLE_MIN_COLS` = 576 columns on
 TOY = {"kmeans_wide": {"rows": 256, "cols": 4096, "max_k": 128},
-       "pca_k3_d3000": {"rows": 256, "cols": 640}}
+       "pca_k3_d3000": {"rows": 256, "cols": 640},
+       "forest": {"num_trees": 2, "max_depth": 4}}
 
 # (metric, cell) pairs whose counter reads 0 in that cell BY DESIGN: the
 # program counts the same name under another label there
@@ -76,17 +78,70 @@ def _pairs():
     return out
 
 
+# ------------------------------------------------------- the file's own form
+# what the driver refuses BENCHMARK.json for before any run (PR 38's first
+# hand-in: a `why` of 210 characters): names, units, one-line texts of at most
+# 200 characters, just the keys an entry may have
+_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}\Z")
+_UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}\Z")
+_KEYS = {"configs": {"name", "source", "file", "reduced", "why"},
+         "workloads": {"name", "config", "traffic", "chips", "why"},
+         "end_to_end": {"name", "unit", "better", "bound", "source"},
+         "per_layer": {"name", "unit", "better", "source", "layer", "moves"}}
+
+
+def _is_line(text):
+    return (isinstance(text, str) and 1 <= len(text) <= 200
+            and text.isprintable() and "\t" not in text)
+
+
+@pytest.mark.parametrize("section", sorted(_KEYS))
+def test_every_entry_of_the_benchmark_file_has_the_form_the_driver_admits(section):
+    entries = BENCH[section]
+    names = [e["name"] for e in entries]
+    assert len(set(names)) == len(names), names
+    for e in entries:
+        assert set(e) - {"workloads"} == _KEYS[section], (e["name"], sorted(e))
+        assert _NAME.match(e["name"]), e["name"]
+        for key in ("why", "source", "layer"):
+            if key in e and not (section in ("end_to_end", "per_layer") and key == "source"):
+                assert _is_line(e[key]), (
+                    f"{section} {e['name']}: `{key}` has {len(e[key])} characters, "
+                    "the driver admits 1 to 200 printable ones on one line")
+        if "unit" in e:
+            assert _UNIT.match(e["unit"]) and e["better"] in ("lower", "higher"), e
+            assert e["source"] in ("device_trace", "program_span", "program_counter",
+                                   "host_clock"), e
+        for cell in e.get("workloads", []):
+            assert cell in CELLS, (e["name"], cell)
+        for key in [e.get("config"), e.get("traffic"), *e.get("reduced", [])]:
+            assert key is None or _NAME.match(key), (e["name"], key)
+        assert len(e.get("reduced", [])) <= 16
+    if section == "configs":
+        files = [e["file"] for e in entries]
+        assert len(set(files)) == len(files)
+        for e in entries:
+            assert e["file"].split("/")[0] in BENCH["paths"], e["file"]
+            assert os.path.isfile(os.path.join(REPO, e["file"])), e["file"]
+            assert any(w["config"] == e["name"] for w in BENCH["workloads"]), e["name"]
+    if section == "workloads":
+        assert {w["config"] for w in entries} <= set(CONFIG_FILES)
+        pairs = [(w["config"], w["traffic"]) for w in entries]
+        assert len(set(pairs)) == len(pairs) and all(w["chips"] in (1, 4) for w in entries)
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 << 10
+
+
 # ------------------------------------------------------------- the toy runs
 
 
 def _build(cfg, chips):
     """The configuration's estimator with its own parameters, sizes cut."""
-    from spark_rapids_ml_tpu.classification import LogisticRegression
+    from spark_rapids_ml_tpu.classification import LogisticRegression, RandomForestClassifier
     from spark_rapids_ml_tpu.clustering import KMeans
     from spark_rapids_ml_tpu.feature import PCA
 
     families = {"kmeans": KMeans, "kmeans_wide": KMeans, "pca": PCA,
-                "logreg": LogisticRegression}
+                "logreg": LogisticRegression, "forest": RandomForestClassifier}
     if cfg["estimator"] not in families:
         pytest.fail(f"configuration names estimator family {cfg['estimator']!r}: "
                     "tests/test_benchmark_contract.py does not know how to build it")
@@ -96,6 +151,10 @@ def _build(cfg, chips):
         params["k"] = min(int(params["k"]), max_k)
     if "maxIter" in params:
         params["maxIter"] = min(int(params["maxIter"]), MAX_ITER)
+    if "numTrees" in params:
+        toy = TOY[cfg["estimator"]]
+        params["numTrees"] = min(int(params["numTrees"]), toy["num_trees"])
+        params["maxDepth"] = min(int(params["maxDepth"]), toy["max_depth"])
     if cfg.get("seed_param"):
         params[cfg["seed_param"]] = 7
     return families[cfg["estimator"]](num_workers=chips, **params)
@@ -132,7 +191,7 @@ def _run_cell(cell_name):
     from spark_rapids_ml_tpu.observability import device
     from spark_rapids_ml_tpu.observability.export import iter_spans
     from spark_rapids_ml_tpu.observability import runs as obs_runs
-    from spark_rapids_ml_tpu.ops import pallas_logistic
+    from spark_rapids_ml_tpu.ops import pallas_histogram, pallas_logistic
     from spark_rapids_ml_tpu.parallel import partitioner
 
     cell = CELLS[cell_name]
@@ -152,6 +211,9 @@ def _run_cell(cell_name):
     # setting, so its platform test says what the chip would (the kernel runs
     # interpreted here, inside the same `_qn_fit` program)
     on_tpu, pallas_logistic._on_tpu = pallas_logistic._on_tpu, lambda: True
+    # and the forest's level histogram: `hist_gate` says what the chip would,
+    # and both Pallas forms run interpreted inside the same `build_tree` program
+    hist_on_tpu, pallas_histogram._on_tpu = pallas_histogram._on_tpu, lambda: True
     # and the upload's: its gate has no setting either, so its platform test
     # says what the chip would and its sizes are cut to the toy table's
     upload = {name: getattr(partitioner, name) for name in (*CHUNKING, "_host_aliased")}
@@ -191,6 +253,7 @@ def _run_cell(cell_name):
         for name, value in upload.items():
             setattr(partitioner, name, value)
         pallas_logistic._on_tpu = on_tpu
+        pallas_histogram._on_tpu = hist_on_tpu
         for key in settings:
             config.unset(key)
     fitted = result if cell["traffic"] == "fit" else model
@@ -206,6 +269,8 @@ def _run_cell(cell_name):
         "cold_before": cold_before, "before": before, "after": after,
         "programs": _programs_called(counters),
         "table_shape": X.shape, "has_label": "labelCol" in cfg["params"], "waits": list(waits),
+        # a forest's row statistics and each tree's row weights go up too
+        "small_puts": 1 + estimator.getNumTrees() if cfg["estimator"] == "forest" else 0,
     }
 
 
@@ -289,7 +354,8 @@ def _check_upload_chunks(entry, spec, run, added):
     assert added("h2d.chunks", {"site": site}) == -(-rows // per) >= 2
     whole = added("h2d.chunk_gate", {"site": site, "chunked": "false"})
     assert whole == added("h2d.chunk_gate", {"site": site, "chunked": "false", "reason": "bytes"})
-    assert whole == (0 if site == "transform" else 2 if run["has_label"] else 1)  # weights, label
+    assert whole == (0 if site == "transform" else  # weights, label, a family's own
+                     (2 if run["has_label"] else 1) + run["small_puts"])
     assert added("h2d.bytes", {"site": site}) >= 4 * rows * cols
     assert added("span.calls", {"span": "h2d.put"}) == 1 + whole
 

@@ -226,10 +226,11 @@ def test_without_resource_spans_and_runs_still_close(monkeypatch, what):
 # table is in flight delays the next put's dispatch; nor `transform.fetch`: no
 # metric read it, and a small batch paid for it (PERF.md §6 PR 36)
 FLAGGED = {("h2d.wait", "upload"), ("kmeans.lloyd", "device"), ("pca.cov", "device"),
-           ("pca.eig.solve", "device"), ("logistic.solve", "device"), ("fit.stage", "none")}
+           ("pca.eig.solve", "device"), ("logistic.solve", "device"), ("fit.stage", "none"),
+           ("forest.grow", "device")}
 
 
-def test_only_the_six_spans_of_the_catalog_are_flagged():
+def test_only_the_seven_spans_of_the_catalog_are_flagged():
     sites = []
     for root, _, files in os.walk(PKG):
         for f in files:
@@ -245,7 +246,7 @@ def test_only_the_six_spans_of_the_catalog_are_flagged():
 
 
 def _toy_fit(family):
-    from spark_rapids_ml_tpu.classification import LogisticRegression
+    from spark_rapids_ml_tpu.classification import LogisticRegression, RandomForestClassifier
     from spark_rapids_ml_tpu.clustering import KMeans
     from spark_rapids_ml_tpu.feature import PCA
 
@@ -259,6 +260,8 @@ def _toy_fit(family):
 
     y = (X[:, 0] + 0.3 * rng.normal(size=len(X)) > 0).astype(np.float32)
     frame = pd.DataFrame({"features": list(X), "label": y})
+    if family == "forest":
+        return RandomForestClassifier(numTrees=2, maxDepth=3, seed=1).fit(frame), X
     return LogisticRegression(maxIter=3, regParam=1e-3).fit(frame), X
 
 
@@ -275,13 +278,13 @@ def fitted():
 
 
 DEVICE_WAITS = {"kmeans": {"kmeans.lloyd"}, "pca": {"pca.cov", "pca.eig.solve"},
-                "logreg": {"logistic.solve"}}
+                "logreg": {"logistic.solve"}, "forest": {"forest.grow"}}
 
 
 @pytest.mark.parametrize("labels", [{"waits": "upload"}, {"waits": "device"}, {"waits": "none"},
                                     {"waits": "run"}],
                          ids=["upload", "device", "none", "run"])
-@pytest.mark.parametrize("family", ["kmeans", "pca", "logreg"])
+@pytest.mark.parametrize("family", ["kmeans", "pca", "logreg", "forest"])
 def test_a_fit_leaves_its_waits_and_its_run_in_the_report(fitted, family, labels):
     model, _ = fitted(family)
     counters = model.fit_report_["metrics"]["counters"]
@@ -297,7 +300,7 @@ def test_a_fit_leaves_its_waits_and_its_run_in_the_report(fitted, family, labels
     assert spans == want[labels["waits"]]
 
 
-@pytest.mark.parametrize("family", ["kmeans", "pca", "logreg"])
+@pytest.mark.parametrize("family", ["kmeans", "pca", "logreg", "forest"])
 def test_a_fits_shares_nest(fitted, family):
     """What the acceptance of a `--trace 1` line asks of every fit cell."""
     counters = fitted(family)[0].fit_report_["metrics"]["counters"]
