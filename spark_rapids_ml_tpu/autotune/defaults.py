@@ -161,6 +161,22 @@ PALLAS_HISTOGRAM_WORDS_PER_STEP = 8
 # the forest's level step (ops/trees.py): the split search holds about five
 # arrays of this size at once, and nothing of a whole level's size
 FOREST_HIST_TILE_BYTES = 256 * 1024 * 1024
+# columns of a forest's 200,000-row edge sample gathered and sorted at once on
+# the device (ops/trees.py::forest_edges); the block's temporaries are about
+# 2.9 x its 200,000 x cols x 4 bytes. NOT a tunable, no knob reads it: the sort
+# sets the pace at any width. Seconds for all 3000 columns of the sample of
+# 357,376 rows on one v5e, dispatch to the order statistics on the host
+# (tools/forest_edges_probe.py; PERF.md §6, PR 40), where the host's sorted
+# column blocks take 3.59 s on 13 cores; a stable sort carries an index to
+# break ties, which equal int32 keys need not:
+#
+#   columns a block                256     512    1024    1536
+#   float32 values, stable       1.232   1.232   1.216   1.217
+#   float32 values, unstable         -   0.711       -       -
+#   int32 keys, stable               -   1.153       -   1.139
+#   int32 keys, unstable (used)      -   0.619       -   0.647
+#   temporaries, GB               0.73    1.46    2.93    4.39
+FOREST_EDGE_BLOCK_COLS = 512
 
 # ------------------------------------------------------------ ANN lifecycle
 # (ops/ann_streaming.py + ops/ann_lifecycle.py, docs/design.md §7b)

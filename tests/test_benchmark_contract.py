@@ -191,7 +191,7 @@ def _run_cell(cell_name):
     from spark_rapids_ml_tpu.observability import device
     from spark_rapids_ml_tpu.observability.export import iter_spans
     from spark_rapids_ml_tpu.observability import runs as obs_runs
-    from spark_rapids_ml_tpu.ops import pallas_histogram, pallas_logistic
+    from spark_rapids_ml_tpu.ops import pallas_histogram, pallas_logistic, trees
     from spark_rapids_ml_tpu.parallel import partitioner
 
     cell = CELLS[cell_name]
@@ -214,6 +214,9 @@ def _run_cell(cell_name):
     # and the forest's level histogram: `hist_gate` says what the chip would,
     # and both Pallas forms run interpreted inside the same `build_tree` program
     hist_on_tpu, pallas_histogram._on_tpu = pallas_histogram._on_tpu, lambda: True
+    # and its quantile edges: `edges_gate` says what the chip would, and the
+    # sample's columns are sorted by the `forest_edges` program
+    edges_on_tpu, trees._on_tpu = trees._on_tpu, lambda: True
     # and the upload's: its gate has no setting either, so its platform test
     # says what the chip would and its sizes are cut to the toy table's
     upload = {name: getattr(partitioner, name) for name in (*CHUNKING, "_host_aliased")}
@@ -254,6 +257,7 @@ def _run_cell(cell_name):
             setattr(partitioner, name, value)
         pallas_logistic._on_tpu = on_tpu
         pallas_histogram._on_tpu = hist_on_tpu
+        trees._on_tpu = edges_on_tpu
         for key in settings:
             config.unset(key)
     fitted = result if cell["traffic"] == "fit" else model

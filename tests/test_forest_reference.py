@@ -197,23 +197,121 @@ def test_device_binning_is_searchsorted_left_to_the_bit():
     assert got[5, 5] == (3 * 5) % (nbins - 1)  # equal to the edge: in the edge's bin
 
 
-@pytest.mark.parametrize("n,d,bins,limit,dtype", [
+EDGE_CASES = [
     (3000, 12, 32, 200_000, np.float32), (5000, 300, 128, 1000, np.float32),
     (777, 5, 256, 200_000, np.float32), (4001, 7, 64, 200_000, np.float64),
     (2, 3, 4, 200_000, np.float32),
-])
-def test_bin_edges_are_numpys_quantiles_to_the_bit(n, d, bins, limit, dtype):
-    """The sorted-column form of the host quantiles (thirty times faster at
-    200,000 x 3000) against the call it replaces, a row sample, NaN, inf and a
-    constant column included."""
+    (1500, 13, 128, 1000, np.float32),  # every special value, sampled
+]
+
+
+def _edge_case(n, d, bins, limit, dtype):
+    """A table with columns of three scales and, in two cases, NaN, both
+    infinities, a constant column and -0.0 beside 0.0; and `np.quantile` of
+    the seed's row sample, the call the edges replace."""
     rng = np.random.default_rng(n)
     X = (rng.normal(size=(n, d)) * rng.choice([1e-3, 1.0, 1e4], size=d)).astype(dtype)
     if n == 777:
         X[5, 2], X[7, 4], X[:, 3] = np.nan, np.inf, 1.0
+    if n == 1500:
+        X[rng.integers(0, n, 40), 0] = np.nan
+        X[rng.integers(0, n, 5), 0] = -np.nan  # the sign bit set: still last
+        X[rng.integers(0, n, 300), 1] = np.inf
+        X[rng.integers(0, n, 300), 1] = -np.inf
+        X[:, 2] = -3.5
+        X[:, 4] = np.where(rng.random(n) < 0.5, -0.0, 0.0).astype(dtype)
+        X[: n // 3, 5] = 0.0
+        X[n // 3: n // 2, 5] = -0.0
     sample = X[np.random.default_rng(9).choice(n, limit, replace=False)] if n > limit else X
     want = np.quantile(sample, np.linspace(0, 1, bins + 1)[1:-1], axis=0).T.astype(np.float32)
+    return X, want
+
+
+@pytest.mark.parametrize("n,d,bins,limit,dtype", EDGE_CASES)
+def test_bin_edges_are_numpys_quantiles_to_the_bit(n, d, bins, limit, dtype):
+    """The sorted-column form of the host quantiles (thirty times faster at
+    200,000 x 3000) against the call it replaces, a row sample, NaN, inf and a
+    constant column included."""
+    X, want = _edge_case(n, d, bins, limit, dtype)
     got = trees.quantile_bin_edges(X, bins, sample_limit=limit, seed=9)
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("n,d,bins,limit,dtype", EDGE_CASES)
+def test_device_edges_are_numpys_quantiles_to_the_bit(n, d, bins, limit, dtype, monkeypatch):
+    """The sample's columns sorted on the device (the gate bypassed), the order
+    statistics numpy reads fetched and lerped on the host: `np.quantile`
+    again, from a placed table whose rows are padded as `shard_fn` pads them,
+    in column blocks that 3, 5, 7, 12, 13 and 300 columns do not fill."""
+    monkeypatch.setattr(trees, "FOREST_EDGE_BLOCK_COLS", 4)
+    X, want = _edge_case(n, d, bins, limit, dtype)
+    with jax.enable_x64(X.dtype == np.float64):  # a float64 table placed as float64
+        X_dev = jnp.pad(jnp.asarray(X), ((0, 3), (0, 0)), constant_values=np.nan)
+        assert X_dev.dtype == X.dtype
+        got = trees.device_quantile_bin_edges(X_dev, n, bins, sample_limit=limit, seed=9)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, trees.quantile_bin_edges(X, bins, sample_limit=limit, seed=9))
+
+
+def test_the_edges_gate_reads_what_the_fit_was_handed(monkeypatch):
+    """Its five verdicts, in order: no placed table, rows over several devices,
+    another dtype on the device than on the host, not a TPU, else the device."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    X = np.zeros((16, 3), np.float32)
+    placed = jnp.asarray(X)
+    sharded = jax.device_put(X, NamedSharding(Mesh(np.array(jax.devices()[:2]), ("rows",)),
+                                              PartitionSpec("rows")))
+    monkeypatch.setattr(trees, "_on_tpu", lambda: True)
+    assert trees.edges_gate(None, X) == (False, "source")
+    assert trees.edges_gate(X, X) == (False, "source")  # a host array is no placed table
+    assert trees.edges_gate(sharded, X) == (False, "devices")
+    assert trees.edges_gate(placed, X.astype(np.float64)) == (False, "dtype")
+    assert trees.edges_gate(placed, X) == (True, "ok")
+    monkeypatch.setattr(trees, "_on_tpu", lambda: False)
+    assert trees.edges_gate(placed, X) == (False, "platform")
+
+
+def _edge_counters(model):
+    counters = model.fit_report_["metrics"]["counters"]
+    return {k: v for k, v in counters.items() if k.startswith("forest.edges_")}
+
+
+def test_a_fit_with_device_edges_is_the_host_forms_to_the_byte(monkeypatch):
+    """The public path with the platform test saying TPU: the edges come from
+    the placed table, and the edges and every tree array are the host form's
+    byte for byte."""
+    X, y = _table(600, 7, 11)
+    X[3, 2], X[4, 5] = np.nan, -np.inf
+    params = dict(numTrees=3, maxDepth=4, maxBins=32, seed=13)
+    host = _fit(X, y, **params)
+    assert _edge_counters(host) == {"forest.edges_gate{device=0,reason=platform}": 1,
+                                    "forest.edges_path{path=host}": 1}
+    monkeypatch.setattr(trees, "_on_tpu", lambda: True)
+    device = _fit(X, y, **params)
+    assert _edge_counters(device) == {"forest.edges_gate{device=1,reason=ok}": 1,
+                                      "forest.edges_path{path=device}": 1}
+    a, b = host.get_model_attributes(), device.get_model_attributes()
+    for k in ("bin_edges", "feature", "threshold", "is_leaf", "value", "gain", "node_weight"):
+        want, got = np.asarray(a[k]), np.asarray(b[k])
+        assert want.dtype == got.dtype and want.tobytes() == got.tobytes(), k
+
+
+def test_each_fit_makes_its_own_tables_edges(monkeypatch):
+    """Two tables of one shape fitted back to back in one process with the
+    same seed: each model holds its own table's edges; nothing is kept from
+    the fit before."""
+    monkeypatch.setattr(trees, "_on_tpu", lambda: True)
+    params = dict(numTrees=1, maxDepth=2, maxBins=16, seed=3)
+    for table_seed in (21, 22):
+        X, y = _table(500, 6, table_seed)
+        model = _fit(X, y, **params)
+        assert _edge_counters(model)["forest.edges_path{path=device}"] == 1
+        np.testing.assert_array_equal(np.asarray(model.get_model_attributes()["bin_edges"]),
+                                      trees.quantile_bin_edges(X, 16, seed=3))
+    first, _ = _table(500, 6, 21)
+    assert not np.array_equal(trees.quantile_bin_edges(first, 16, seed=3),
+                              np.asarray(model.get_model_attributes()["bin_edges"]))
 
 
 # ------------------------------------- (e) the level step at the published widths
