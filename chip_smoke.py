@@ -512,7 +512,7 @@ def check_normal_eq(d: int, n: int = 200_077, gate: bool = True) -> str:
     import jax.numpy as jnp
 
     from spark_rapids_ml_tpu.ops._precision import parity_precision
-    from spark_rapids_ml_tpu.ops.linear import linreg_sufficient_stats
+    from spark_rapids_ml_tpu.ops.linear import center_moments, normal_equations
     from spark_rapids_ml_tpu.ops.pallas_select import _interpret_default
     from spark_rapids_ml_tpu.ops.pallas_xtwx import normal_eq_prefix_mask
     from spark_rapids_ml_tpu.ops.pca import use_fused_gram
@@ -527,20 +527,24 @@ def check_normal_eq(d: int, n: int = 200_077, gate: bool = True) -> str:
     A_p, b_p, xbar_p, ybar_p, wsum_p, yty_p = normal_eq_prefix_mask(
         jnp.asarray(X), jnp.asarray(y), jnp.asarray(w), mesh=None,
         precision=parity_precision(), interpret=_interpret_default())
-    A_x, b_x, _, ybar_x, wsum_x = linreg_sufficient_stats(
+    # the fused pass's raw moments, centred as the solve takes them, against
+    # the in-core XLA pass's centred statistics
+    S_p, r_p, _, _, _ = center_moments(A_p, b_p, xbar_p, ybar_p, wsum_p)
+    S_x, r_x, _, ybar_x, wsum_x = normal_equations(
         jnp.asarray(X), jnp.asarray(y), jnp.asarray(w))
     Xv, yv = X[:-100].astype(np.float64), y[:-100].astype(np.float64)
     b_ref = Xv.T @ yv
+    r_ref = (Xv - Xv.mean(axis=0)).T @ (yv - yv.mean())
     # XᵀX entries ~n, Xᵀy ~n*sqrt(d): 1e-4 of the largest entry, as for Gram
-    e_A = float(np.abs(np.asarray(A_p) - np.asarray(A_x)).max()) / n
+    e_A = float(np.abs(np.asarray(S_p) - np.asarray(S_x)).max()) / n
     e_b = float(np.abs(np.asarray(b_p) - b_ref).max() / np.abs(b_ref).max())
     e_yty = abs(float(yty_p) - float(yv @ yv)) / float(yv @ yv)
     _check(e_A <= 1e-4 and e_b <= 1e-4 and e_yty <= 1e-4,
            f"normal-eq d={d}: A {e_A:.2e} b {e_b:.2e} yty {e_yty:.2e}")
     _check(float(wsum_p) == float(wsum_x) == n - 100, "normal-eq: wrong Σw")
     _check(abs(float(ybar_p) - float(ybar_x)) <= 1e-4, "normal-eq: ȳ differs")
-    _check(float(np.abs(np.asarray(b_p) - np.asarray(b_x)).max()
-                 / np.abs(b_ref).max()) <= 1e-4, "normal-eq: Xᵀy differs from XLA")
+    _check(float(np.abs(np.asarray(r_p) - np.asarray(r_x)).max()
+                 / np.abs(r_ref).max()) <= 1e-4, "normal-eq: Xᵀy differs from XLA")
     _check(float(np.abs(np.asarray(xbar_p) - Xv.mean(axis=0)).max()) <= 1e-4,
            "normal-eq: x̄ differs from numpy")
     return f"A vs XLA {e_A:.1e}, Xᵀy vs numpy {e_b:.1e}"

@@ -406,14 +406,14 @@ class LinearRegressionUpdater(PartialFitUpdater):
         return float(self._carry[1][0])
 
     def candidate(self):
-        from ..ops.linear import solve_from_stats
+        from ..ops.linear import center_moments, solve_from_stats
 
         (A, b, sx, sy, sw), _ = self._carry
         swf = float(sw)
         if swf <= 0.0:
             raise RuntimeError("partial_fit carry is empty: no update rows")
         res = solve_from_stats(
-            A, b, sx / sw, sy / sw, sw,
+            *center_moments(A, b, sx / sw, sy / sw, sw),
             reg=float(self._reg), l1_ratio=float(self._l1r),
             fit_intercept=bool(self._fi), standardize=bool(self._std),
             max_iter=self._max_iter, tol=self._tol,

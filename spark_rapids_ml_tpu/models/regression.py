@@ -282,7 +282,8 @@ class LinearRegression(_LinearRegressionClass, _TpuEstimatorSupervised, _LinearR
 
     def _streaming_fit(self, fd, chain_ops=None) -> Dict[str, Any]:
         """Out-of-core fit: stream batches, accumulate (XᵀWX, XᵀWy) on device
-        (ops/streaming.py) — numerically identical to the in-core stats pass.
+        (ops/streaming.py), centred before the one solve stage the in-core
+        pass feeds too.
         `chain_ops` carries upstream featurizer transforms when this fit is the
         terminal stage of a fused pipeline chain (pipeline.py)."""
         from .. import config as _config
@@ -309,7 +310,7 @@ class LinearRegression(_LinearRegressionClass, _TpuEstimatorSupervised, _LinearR
             inputs = self._build_fit_inputs(fd)
             return self._get_tpu_fit_func(None)(inputs)
         mesh = active_partitioner(self.num_workers).mesh
-        A, b, xbar, ybar, sw = streaming_linreg_stats(
+        S, r, xbar, ybar, sw = streaming_linreg_stats(
             _densify(fd.features, self._float32_inputs),
             fd.label,
             fd.weight,
@@ -319,7 +320,7 @@ class LinearRegression(_LinearRegressionClass, _TpuEstimatorSupervised, _LinearR
             chain_ops=chain_ops,
         )
         attrs = solve_from_stats(
-            A, b, xbar, ybar, sw,
+            S, r, xbar, ybar, sw,
             reg=float(p["alpha"]),
             l1_ratio=float(p["l1_ratio"]),
             fit_intercept=bool(p["fit_intercept"]),
@@ -328,17 +329,15 @@ class LinearRegression(_LinearRegressionClass, _TpuEstimatorSupervised, _LinearR
             tol=float(p["tol"]),
         )[0]
         # live telemetry (§6g): one convergence record for the streamed linreg —
-        # the unpenalized normal-equation residual ‖(Aβ + c·Σwx − b)/Σw‖ is the
-        # squared-loss gradient norm at the solution (≈0 for an exact l2 solve,
-        # the leftover prox residual for elastic net)
+        # the unpenalized normal-equation residual ‖(Sβ − r)/Σw + x̄(x̄·β + c − ȳ)‖
+        # is the squared-loss gradient norm at the solution (≈0 for an exact l2
+        # solve, the leftover prox residual for elastic net)
         from ..observability import convergence as obs_convergence
 
         coef = np.asarray(attrs["coefficients"], np.float64)
-        grad = (
-            np.asarray(A, np.float64) @ coef
-            + float(attrs["intercept"]) * np.asarray(xbar, np.float64) * float(sw)
-            - np.asarray(b, np.float64)
-        ) / float(sw)
+        xbar64 = np.asarray(xbar, np.float64)
+        grad = (np.asarray(S, np.float64) @ coef - np.asarray(r, np.float64)) / float(sw) + (
+            xbar64 * (xbar64 @ coef + float(attrs["intercept"]) - float(ybar)))
         obs_convergence(
             "linreg", attrs.get("n_iter", 1),
             grad_norm=float(np.linalg.norm(grad)),

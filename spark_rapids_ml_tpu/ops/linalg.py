@@ -89,39 +89,58 @@ def gram_column_blocks(d: int) -> Tuple[Tuple[int, int], ...]:
     return tuple((lo, min(lo + GRAM_BLOCK_COLS, d)) for lo in range(0, d, GRAM_BLOCK_COLS))
 
 
-def _upper_gram_panels(X: jax.Array, w: jax.Array, mean: jax.Array, blocks):
+def _upper_gram_panels(X: jax.Array, w: jax.Array, mean: jax.Array, blocks, label=None):
     """Σ w_i (x_i-μ)(x_i-μ)ᵀ over the rows held here, as one panel a column
     block I: its rows of the matrix from the block's first column to the last
     column, which are the block pairs (I, J) with I <= J. One matmul per panel
     and `GRAM_CHUNK_ROWS` rows, the partial sums added with a compensated
     (Kahan) sum, so that neither the matmul's accumulator nor the sum of the
     parts loses what float32 holds. A table shorter than one chunk is one
-    part; a single block's panel is the whole matrix."""
+    part; a single block's panel is the whole matrix.
+
+    With `label` = (y, ȳ) the same parts also form, under the same
+    compensated sum and returned after the panels in this order,
+    Σ w_i (x_i-μ)(y_i-ȳ) (one matrix-vector product a part), Σ w_i (x_i-μ) and
+    Σ w_i (y_i-ȳ): the normal equations' right-hand side and the sums that put
+    the centre where the rows' weighted mean is, whatever float32 lost of μ
+    and ȳ, ride the loop that reads X for their Gram. Without it the program
+    is the Gram's alone."""
     n = X.shape[0]
     chunk = GRAM_CHUNK_ROWS
 
-    def gram(xs, ws):
+    def gram(xs, ws, ys):
         xs = xs - mean[None, :]
         xw = xs * ws[:, None]
-        return [pdot(xw[:, lo:hi].T, xs[:, lo:]) for lo, hi in blocks]
+        terms = [pdot(xw[:, lo:hi].T, xs[:, lo:]) for lo, hi in blocks]
+        if ys is not None:
+            yc = ys - label[1]
+            # xwᵀ(y - ȳ), not (y - ȳ)ᵀxw: on the CPU the matrix-vector form
+            # loses seven times less over a part
+            terms += [pdot(xw.T, yc), jnp.sum(xw, axis=0), jnp.sum(yc * ws)]
+        return terms
 
+    y = None if label is None else label[0]
     if n <= chunk:
-        return gram(X, w)
+        return gram(X, w, y)
 
     def add(carry, terms):
         accs, comps = zip(*(kahan_add(a, c, t) for a, c, t in zip(*carry, terms)))
         return list(accs), list(comps)
 
+    def part(a, i):
+        return None if a is None else jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk, 0)
+
     def body(i, carry):
-        xs = jax.lax.dynamic_slice_in_dim(X, i * chunk, chunk, 0)
-        ws = jax.lax.dynamic_slice_in_dim(w, i * chunk, chunk, 0)
-        return add(carry, gram(xs, ws))
+        return add(carry, gram(part(X, i), part(w, i), part(y, i)))
 
     zeros = [jnp.zeros((hi - lo, X.shape[1] - lo), X.dtype) for lo, hi in blocks]
+    if y is not None:
+        zeros += [jnp.zeros((X.shape[1],), X.dtype)] * 2 + [jnp.zeros((), X.dtype)]
     full = n // chunk
     carry = jax.lax.fori_loop(0, full, body, (zeros, zeros))
     if n % chunk:
-        carry = add(carry, gram(X[full * chunk:], w[full * chunk:]))
+        rest = slice(full * chunk, None)
+        carry = add(carry, gram(X[rest], w[rest], None if y is None else y[rest]))
     return carry[0]
 
 
@@ -137,18 +156,41 @@ def _mirror_upper_panels(panels, blocks) -> jax.Array:
     return jnp.where(jnp.tri(G.shape[0], k=-1, dtype=bool), G.T, G)
 
 
-def _centered_gram(X: jax.Array, w: jax.Array, mean: jax.Array, axis=None) -> jax.Array:
+def _centered_gram(X: jax.Array, w: jax.Array, mean: jax.Array, axis=None, label=None):
     """Σ w_i (x_i-μ)(x_i-μ)ᵀ, symmetric to the bit from `GRAM_TRIANGLE_MIN_COLS`
     columns on, where only its upper column blocks are computed: `w` is one
     scalar a row, so block (J, I) is the transpose of block (I, J) and a second
     matmul would only compute the same numbers again. With `axis` (inside a
     `shard_map`) the shards' upper panels are added by one psum before the
-    mirror."""
+    mirror. With `label` = (y, ȳ) it returns that matrix followed by the three
+    sums `_upper_gram_panels` forms beside it, added by the same psum."""
     blocks = gram_column_blocks(X.shape[1])
-    panels = _upper_gram_panels(X, w, mean, blocks)
+    panels = _upper_gram_panels(X, w, mean, blocks, label)
     if axis is not None:
         panels = jax.lax.psum(panels, axis)
-    return _mirror_upper_panels(panels, blocks)
+    if label is None:
+        return _mirror_upper_panels(panels, blocks)
+    return (_mirror_upper_panels(panels[:len(blocks)], blocks), *panels[len(blocks):])
+
+
+def centered_normal_equations(X: jax.Array, y: jax.Array, w: jax.Array, mean: jax.Array,
+                              ybar: jax.Array, axis=None):
+    """(Σ w(x-μ)(x-μ)ᵀ, Σ w(x-μ)(y-ȳ), Σ w(x-μ), Σ w(y-ȳ)) about a centre (μ, ȳ)
+    near the weighted means: the normal equations' statistics, PCA's Gram with
+    the rest riding its loop."""
+    return _centered_gram(X, w, mean, axis, label=(y, ybar))
+
+
+def rows_split(mesh) -> bool:
+    """Whether a `mesh` splits the rows over several devices and the columns
+    not at all: the one layout a pass sums shard by shard under `shard_map`.
+    Otherwise (a single device, a mesh that also splits the columns, or a
+    caller that leaves the partitioning to XLA) the rows are taken as they
+    come."""
+    from ..parallel.mesh import DATA_AXIS, FEATURE_AXIS
+
+    return (mesh is not None and mesh.shape.get(DATA_AXIS, 1) > 1
+            and mesh.shape.get(FEATURE_AXIS, 1) == 1)
 
 
 @compiled_kernel("linalg.weighted_covariance", static_argnames=("mesh",))
@@ -157,18 +199,15 @@ def weighted_covariance(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Centered covariance C = Σ w_i (x_i-μ)(x_i-μ)ᵀ / (Σw - 1): the weighted
     mean first, then the Gram matrix of the centred rows in short partial sums
-    (`_centered_gram`). With a `mesh` of several devices each shard sums its own
-    rows and one psum adds the shards; without one (a single device, a mesh
-    that also splits the columns, or a caller that leaves the partitioning to
-    XLA) the rows are taken as they come."""
-    from ..parallel.mesh import DATA_AXIS, FEATURE_AXIS
-
+    (`_centered_gram`). With a `mesh` that splits the rows alone (`rows_split`)
+    each shard sums its own rows and one psum adds the shards."""
     wsum = jnp.sum(w)
     mean = pdot(w, X) / wsum
-    if (mesh is not None and mesh.shape.get(DATA_AXIS, 1) > 1
-            and mesh.shape.get(FEATURE_AXIS, 1) == 1):
+    if rows_split(mesh):
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
+
+        from ..parallel.mesh import DATA_AXIS
 
         S2 = shard_map(
             lambda x, ws, m: _centered_gram(x, ws, m, axis=DATA_AXIS),
@@ -178,16 +217,6 @@ def weighted_covariance(
     else:
         S2 = _centered_gram(X, w, mean)
     return S2 / (wsum - 1.0), mean, wsum
-
-
-@compiled_kernel("linalg.gram_and_xty")
-def gram_and_xty(
-    X: jax.Array, y: jax.Array, w: jax.Array
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Normal-equation sufficient statistics: (XᵀWX, XᵀWy, Σw) in one sharded pass —
-    the TPU form of the reference's LinearRegressionMG/RidgeMG allreduce."""
-    Xw = X * w[:, None]
-    return pdot(Xw.T, X), pdot(Xw.T, y), jnp.sum(w)
 
 
 def power_iteration_lmax(G: jax.Array, n_steps: int = 16) -> jax.Array:

@@ -5,10 +5,14 @@
 # gradient/Gram allreduce happens inside cuML over NCCL).
 #
 # TPU formulation: ONE sharded data pass builds the normal-equation sufficient
-# statistics (XᵀWX, XᵀWy) — the contraction over the sharded row axis is where XLA
-# inserts the psum (the cuML NCCL allreduce's place). Everything after is d×d and
-# replicated:
-#   * no L1  -> direct solve of (XᵀWX/n + λI) w = XᵀWy/n   (OLS: λ=0; Ridge)
+# statistics, CENTRED about the weighted means: S = Σ w(x-x̄)(x-x̄)ᵀ and
+# r = Σ w(x-x̄)(y-ȳ), beside x̄, ȳ and Σw — the contraction over the sharded row
+# axis is where the psum goes (the cuML NCCL allreduce's place). That tuple is the
+# one interface of the solve stage (`solve_from_stats`), whoever produced it: the
+# in-core XLA pass (`normal_equations`), the fused Pallas pass at 512 columns or
+# fewer and the streamed tier, whose raw moments are centred before they are
+# handed over (`center_moments`). Everything after is d×d and replicated:
+#   * no L1  -> direct solve of (S/n + λI) β = r/n   (OLS: λ=0; Ridge), b = ȳ - x̄·β
 #   * L1 > 0 -> FISTA proximal gradient on the Gram form — all matrix-vector work,
 #     MXU/VPU-friendly with a statically-bounded lax.while_loop, where the reference
 #     uses cuML's sequential coordinate descent (CD's per-coordinate data dependence is
@@ -28,37 +32,71 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
 from ._precision import pdot
-from .linalg import power_iteration_lmax
+from .linalg import centered_normal_equations, gram_column_blocks, power_iteration_lmax, rows_split
 
 
-@compiled_kernel("linear.sufficient_stats")
-def linreg_sufficient_stats(
-    X: jax.Array, y: jax.Array, w: jax.Array
+@compiled_kernel("linear.normal_equations", static_argnames=("mesh",))
+def normal_equations(
+    X: jax.Array, y: jax.Array, w: jax.Array, mesh=None
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
-    """One sharded pass: (XᵀWX, XᵀWy, x̄, ȳ, Σw). The only distributed step."""
+    """The centred statistics (S, r, x̄, ȳ, Σw) of the module header: the
+    weighted means first, one matrix-vector pass, then S and r in one pass over
+    the rows centred by them, in short parts under a compensated sum and, from
+    `GRAM_TRIANGLE_MIN_COLS` columns on, over the upper column blocks of S
+    alone (`linalg.centered_normal_equations`: PCA's Gram with r riding its
+    loop). Centring first is what float32 needs: the raw XᵀWX of a table whose
+    column means are not zero loses the centred matrix in its cancellation,
+    and a whole-table contraction loses more in the matmul's accumulator
+    (PERF.md §6). The same loop sums the centred rows, whose mean δ is
+    what the first pass's float32 lost of x̄ (and of ȳ): the intercept
+    ȳ - x̄·β is first order in it, the moments second order (S - Σw·δδᵀ). With
+    a `mesh` that splits the rows alone each shard sums its own rows and one
+    psum adds the shards."""
     wsum = jnp.sum(w)
+    y = y.astype(X.dtype)
     xbar = pdot(w, X) / wsum
     ybar = jnp.sum(w * y) / wsum
-    Xw = X * w[:, None]
-    A = pdot(Xw.T, X)
-    b = pdot(Xw.T, y)
-    return A, b, xbar, ybar, wsum
+    if rows_split(mesh):
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        from ..parallel.mesh import DATA_AXIS
+
+        S, r, dx, dy = shard_map(
+            lambda x, ys, ws, m, mb: centered_normal_equations(x, ys, ws, m, mb, axis=DATA_AXIS),
+            mesh=mesh, in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS), P(), P()),
+            out_specs=(P(), P(), P(), P()), check_vma=False,
+        )(X, y, w, xbar, ybar)
+    else:
+        S, r, dx, dy = centered_normal_equations(X, y, w, xbar, ybar)
+    dx, dy = dx / wsum, dy / wsum
+    return (S - wsum * jnp.outer(dx, dx), r - wsum * dx * dy, xbar + dx, ybar + dy, wsum)
 
 
-def _center_stats(A, b, xbar, ybar, n, fit_intercept):
-    """Convert raw moments to centered (about the weighted mean) moments."""
+@compiled_kernel("linear.center_moments")
+def center_moments(A, b, xbar, ybar, n):
+    """Raw moments (XᵀWX, XᵀWy, x̄, ȳ, Σw), as the fused Pallas pass and the
+    streamed tier accumulate them, made the centred statistics the solve
+    takes: S = XᵀWX - n·x̄x̄ᵀ, r = XᵀWy - n·x̄ȳ."""
+    return A - n * jnp.outer(xbar, xbar), b - n * xbar * ybar, xbar, ybar, n
+
+
+def _about_origin(S, r, xbar, ybar, n, fit_intercept):
+    """The moments one parameter map solves with: the centred ones where it
+    fits an intercept, the moments about the origin where it does not (a
+    fitMultiple may ask for both from one pass)."""
     if fit_intercept:
-        A = A - n * jnp.outer(xbar, xbar)
-        b = b - n * xbar * ybar
-    return A, b
+        return S, r
+    return S + n * jnp.outer(xbar, xbar), r + n * xbar * ybar
 
 
 @compiled_kernel("linear.solve_l2", static_argnames=("fit_intercept",))
 def solve_l2(
-    A: jax.Array,
-    b: jax.Array,
+    S: jax.Array,
+    r: jax.Array,
     xbar: jax.Array,
     ybar: jax.Array,
     n: jax.Array,
@@ -66,25 +104,32 @@ def solve_l2(
     reg: float,
     fit_intercept: bool,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Closed-form OLS/Ridge in (optionally σ-scaled) space; returns (coef, intercept)
-    in the ORIGINAL feature space."""
-    Ac, bc = _center_stats(A, b, xbar, ybar, n, fit_intercept)
-    # scale to standardized space: As = D⁻¹ Ac D⁻¹, bs = D⁻¹ bc, D = diag(scale)
-    As = Ac / jnp.outer(scale, scale)
-    bs = bc / scale
+    """Closed-form OLS/Ridge from the centred statistics in (optionally
+    σ-scaled) space; returns (coef, intercept) in the ORIGINAL feature space.
+
+    The left-hand side is symmetric positive (semi)definite, so it is solved
+    by its Cholesky factors, whose blocked updates run at `highest`: on a v5e
+    at 3000 columns 2.4 ms against LU's 7.2 ms, and a gradient 1.6e-6 of the
+    one at zero off float64 against LU's 2.6e-6 (PERF.md §6)."""
+    from jax.scipy.linalg import cho_factor, cho_solve
+
+    A, b = _about_origin(S, r, xbar, ybar, n, fit_intercept)
+    # scale to standardized space: As = D⁻¹ A D⁻¹, bs = D⁻¹ b, D = diag(scale)
+    As = A / jnp.outer(scale, scale)
+    bs = b / scale
     d = As.shape[0]
     lhs = As / n + reg * jnp.eye(d, dtype=As.dtype)
-    coef_s = jnp.linalg.solve(lhs, bs / n)
+    coef_s = cho_solve(cho_factor(lhs), bs / n)
     coef = coef_s / scale
-    intercept = jnp.where(fit_intercept, ybar - jnp.dot(xbar, coef), 0.0)
+    intercept = jnp.where(fit_intercept, ybar - pdot(xbar, coef), 0.0)
     return coef, intercept
 
 
 @compiled_kernel("linear.solve_elastic_net",
                  static_argnames=("fit_intercept", "max_iter"))
 def solve_elastic_net(
-    A: jax.Array,
-    b: jax.Array,
+    S: jax.Array,
+    r: jax.Array,
     xbar: jax.Array,
     ybar: jax.Array,
     n: jax.Array,
@@ -97,10 +142,11 @@ def solve_elastic_net(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """FISTA on  f(β) = 1/(2n)·βᵀAβ - bᵀβ/n (+ L2)  with prox for λ·α‖β‖₁.
 
-    Returns (coef, intercept, n_iter) in the original feature space."""
-    Ac, bc = _center_stats(A, b, xbar, ybar, n, fit_intercept)
-    As = (Ac / jnp.outer(scale, scale)) / n
-    bs = (bc / scale) / n
+    Takes the centred statistics; returns (coef, intercept, n_iter) in the
+    original feature space."""
+    A, b = _about_origin(S, r, xbar, ybar, n, fit_intercept)
+    As = (A / jnp.outer(scale, scale)) / n
+    bs = (b / scale) / n
     l1 = reg * l1_ratio
     l2 = reg * (1.0 - l1_ratio)
 
@@ -128,7 +174,7 @@ def solve_elastic_net(
     state = (w0, w0, jnp.array(1.0, As.dtype), 0, jnp.array(jnp.inf, As.dtype))
     coef_s, _, _, n_iter, _ = jax.lax.while_loop(cond, body, state)
     coef = coef_s / scale
-    intercept = jnp.where(fit_intercept, ybar - jnp.dot(xbar, coef), 0.0)
+    intercept = jnp.where(fit_intercept, ybar - pdot(xbar, coef), 0.0)
     return coef, intercept, n_iter
 
 
@@ -153,23 +199,39 @@ def linreg_fit(
     concatenated data (regression.py:657-674); here the data pass itself is shared.
     Returns one attribute dict per model.
 
-    Unit-weight fits on TPU take the fused one-X-read pallas stats pass
-    (ops/pallas_xtwx.py::normal_eq_prefix_mask — halves the HBM traffic of the
-    XLA two-read Gram); the same `use_fused_gram` gate as the PCA covariance."""
+    Unit-weight fits on TPU up to `MAX_FUSED_COLS` columns take the fused
+    one-X-read pallas pass (ops/pallas_xtwx.py::normal_eq_prefix_mask), whose
+    raw moments are centred before the solve; the same `use_fused_gram` gate as
+    the PCA covariance. Every other fit takes `normal_equations`.
+    `linear.stats_form{form=centred_triangle|centred_full|fused}` counts which
+    producer ran, from the same shape tests, and the span `linear.stats` is
+    the pass from its dispatch to its result on the device."""
     from .pca import use_fused_gram
 
-    if use_fused_gram(X.shape[1], unit_weight, dtype=X.dtype):
-        from ._precision import parity_precision
-        from .pallas_xtwx import normal_eq_prefix_mask
-
-        interpret = jax.devices()[0].platform != "tpu"
-        A, b, xbar, ybar, n, _yty = normal_eq_prefix_mask(
-            X, y, w, mesh=mesh, precision=parity_precision(), interpret=interpret
-        )
+    fused = use_fused_gram(X.shape[1], unit_weight, dtype=X.dtype)
+    if fused:
+        form = "fused"
+    elif len(gram_column_blocks(X.shape[1])) > 1:
+        form = "centred_triangle"
     else:
-        A, b, xbar, ybar, n = linreg_sufficient_stats(X, y, w)
+        form = "centred_full"
+    counter_inc("linear.stats_form", 1, form=form)
+    with span("linear.stats", {"waits": "device"}):
+        if fused:
+            from ._precision import parity_precision
+            from .pallas_xtwx import normal_eq_prefix_mask
+
+            interpret = jax.devices()[0].platform != "tpu"
+            raw = normal_eq_prefix_mask(
+                X, y, w, mesh=mesh, precision=parity_precision(), interpret=interpret
+            )
+            stats = center_moments(*raw[:5])
+        else:
+            stats = normal_equations(X, y, w, mesh=mesh)
+        # waited for, so that the pass and the solve below are not one number
+        stats = jax.block_until_ready(stats)
     return solve_from_stats(
-        A, b, xbar, ybar, n,
+        *stats,
         reg=reg, l1_ratio=l1_ratio, fit_intercept=fit_intercept,
         standardize=standardize, max_iter=max_iter, tol=tol,
         extra_param_sets=extra_param_sets,
@@ -177,8 +239,8 @@ def linreg_fit(
 
 
 def solve_from_stats(
-    A: jax.Array,
-    b: jax.Array,
+    S: jax.Array,
+    r: jax.Array,
     xbar: jax.Array,
     ybar: jax.Array,
     n: jax.Array,
@@ -190,12 +252,14 @@ def solve_from_stats(
     tol: float,
     extra_param_sets: Optional[List[Dict[str, Any]]] = None,
 ) -> List[Dict[str, Any]]:
-    """Solve per param map from sufficient statistics (shared by the in-core and
-    streaming out-of-core paths; ops/streaming.py accumulates the same stats). The
-    column std for standardization comes from diag(A): var = (ΣwX² - n·x̄²)/(n-1)."""
+    """Solve per param map from the CENTRED statistics (S, r, x̄, ȳ, Σw) of the
+    module header, whichever pass produced them. The column std for
+    standardization comes from the centred diagonal: var = diag(S)/(n-1).
+    Each map counts `linear.solver{path=l2|elastic_net}`; `linear.solve` is its
+    compiled solve, waited for, and `linear.fetch` the result to the host."""
     if standardize:
         # unbiased column std, Spark's Summarizer convention (reference utils.py:876-982)
-        var = (jnp.diagonal(A) - n * xbar * xbar) / jnp.maximum(n - 1.0, 1.0)
+        var = jnp.diagonal(S) / jnp.maximum(n - 1.0, 1.0)
         scale = jnp.sqrt(jnp.maximum(var, 0.0))
         scale = jnp.where(scale <= 0.0, 1.0, scale)
     else:
@@ -212,21 +276,24 @@ def solve_from_stats(
         p_fi = bool(p.get("fit_intercept", fit_intercept))
         p_mi = int(p.get("max_iter", max_iter))
         p_tol = float(p.get("tol", tol))
-        if p_reg == 0.0 or p_l1r == 0.0:
-            coef, intercept = solve_l2(A, b, xbar, ybar, n, scale, p_reg, p_fi)
-            n_iter = 1
-        else:
-            coef, intercept, n_iter = solve_elastic_net(
-                A, b, xbar, ybar, n, scale, p_reg, p_l1r, p_fi, p_mi, p_tol
+        l2 = p_reg == 0.0 or p_l1r == 0.0
+        counter_inc("linear.solver", 1, path="l2" if l2 else "elastic_net")
+        with span("linear.solve"):
+            if l2:
+                out = solve_l2(S, r, xbar, ybar, n, scale, p_reg, p_fi)
+            else:
+                out = solve_elastic_net(
+                    S, r, xbar, ybar, n, scale, p_reg, p_l1r, p_fi, p_mi, p_tol
+                )
+            out = jax.block_until_ready(out)
+        with span("linear.fetch"):
+            results.append(
+                {
+                    "coefficients": np.asarray(out[0]),
+                    "intercept": float(out[1]),
+                    "n_iter": int(out[2]) if len(out) > 2 else 1,
+                }
             )
-            n_iter = int(n_iter)
-        results.append(
-            {
-                "coefficients": np.asarray(coef),
-                "intercept": float(intercept),
-                "n_iter": int(n_iter),
-            }
-        )
     return results
 
 

@@ -139,8 +139,7 @@ def _xtxy_kernel(n_split, nv_ref, s_ref, x_ref, y_ref, s2_ref, s1_ref, xty_ref, 
     and NOT as a column appended to X ([X|y] would widen the X block to d+1,
     breaking 128-lane alignment and paying a second lane-tile of VMEM+DMA per
     row). XᵀY is one (1,blk)x(blk,d) MXU matmul at the same multipass-bf16
-    precision as S2. Covers `gram_and_xty`'s role for unit-weight fits (the
-    header's "unwirable" note predates this layout)."""
+    precision as S2."""
     b = pl.program_id(0)
 
     @pl.when(b == 0)
@@ -240,10 +239,11 @@ def normal_eq_prefix_mask(
     cse_guard=0.0,
 ):
     """Fused normal-equation sufficient statistics for UNIT-WEIGHT data under the
-    repo's padding contract: returns (A=XᵀX, b=Xᵀy, x̄, ȳ, Σw, Σy²) — the tuple
-    `ops/linear.py::linreg_sufficient_stats` produces, plus yᵀy (for R²/objective
-    without another pass) — while reading X from HBM ONCE instead of the XLA
-    path's twice (lhs and rhs stream independently; see module header).
+    repo's padding contract: returns the raw moments (A=XᵀX, b=Xᵀy, x̄, ȳ, Σw,
+    Σy²) — the first five are what `ops/linear.py::center_moments` centres for
+    the solve; yᵀy serves R²/objective without another pass — while reading X
+    from HBM ONCE instead of twice (lhs and rhs stream independently; see module
+    header).
 
     Same eligibility contract as `covariance_prefix_mask`: w must be a {0,1}
     prefix mask per shard (parallel/partition.py::pad_rows places padding at the

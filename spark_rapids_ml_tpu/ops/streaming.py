@@ -25,6 +25,7 @@ import numpy as np
 
 from ..observability import (
     convergence as obs_convergence,
+    counter_inc as obs_counter_inc,
     progress as obs_progress,
     span as obs_span,
 )
@@ -282,13 +283,18 @@ def streaming_linreg_stats(
     float32: bool = True,
     chain_ops=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Streamed (XᵀWX, XᵀWy, x̄, ȳ, Σw): the same statistics as
-    ops/linear.linreg_sufficient_stats but with O(batch) device residency.
-    Each batch is device_put (sharded over the mesh when given) and accumulated.
-    dtype follows float32 (float64 additionally needs jax x64 mode, matching the
+    """Streamed normal-equation statistics with O(batch) device residency:
+    each batch is device_put (sharded over the mesh when given) and its raw
+    moments (XᵀWX, XᵀWy, Σwx, Σwy, Σw) accumulated; the sums are centred once
+    at the end (`ops/linear.py::center_moments`), so the solve gets the
+    centred (S, r, x̄, ȳ, Σw) the in-core `normal_equations` gives it. dtype
+    follows float32 (float64 additionally needs jax x64 mode, matching the
     in-core path's device behavior). `chain_ops` fuses a featurize prefix into
     the per-batch program (docs/design.md §6k)."""
     from .device_cache import batch_cache
+    from .linear import center_moments
+
+    obs_counter_inc("linear.stats_form", 1, form="streamed")
 
     dt = np.float32 if float32 else np.float64
     n = X.shape[0]
@@ -328,7 +334,7 @@ def streaming_linreg_stats(
             progress_phase="linreg.batches",
         )
     A, b, sx, sy, sw = carry
-    return A, b, sx / sw, sy / sw, sw
+    return center_moments(A, b, sx / sw, sy / sw, sw)
 
 
 def streaming_covariance(

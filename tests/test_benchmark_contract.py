@@ -37,9 +37,11 @@ ROWS, COLS, MAX_K, MAX_ITER = 512, 16, 4, 3
 # `pca_k3_d3000` leaves the Pallas Gram kernel for the XLA program past
 # `ops/pallas_xtwx.py::MAX_FUSED_COLS` = 512 columns, and that program
 # multiplies only the upper column blocks of the Gram matrix from
-# `autotune/defaults.py::GRAM_TRIANGLE_MIN_COLS` = 576 columns on
+# `autotune/defaults.py::GRAM_TRIANGLE_MIN_COLS` = 576 columns on, which the
+# normal equations of `linreg_l2_d3000` share
 TOY = {"kmeans_wide": {"rows": 256, "cols": 4096, "max_k": 128},
        "pca_k3_d3000": {"rows": 256, "cols": 640},
+       "linreg_l2_d3000": {"rows": 256, "cols": 640},
        "forest": {"num_trees": 2, "max_depth": 4}}
 
 # (metric, cell) pairs whose counter reads 0 in that cell BY DESIGN: the
@@ -139,9 +141,11 @@ def _build(cfg, chips):
     from spark_rapids_ml_tpu.classification import LogisticRegression, RandomForestClassifier
     from spark_rapids_ml_tpu.clustering import KMeans
     from spark_rapids_ml_tpu.feature import PCA
+    from spark_rapids_ml_tpu.regression import LinearRegression
 
     families = {"kmeans": KMeans, "kmeans_wide": KMeans, "pca": PCA,
-                "logreg": LogisticRegression, "forest": RandomForestClassifier}
+                "logreg": LogisticRegression, "forest": RandomForestClassifier,
+                "linreg": LinearRegression}
     if cfg["estimator"] not in families:
         pytest.fail(f"configuration names estimator family {cfg['estimator']!r}: "
                     "tests/test_benchmark_contract.py does not know how to build it")

@@ -227,7 +227,7 @@ def test_without_resource_spans_and_runs_still_close(monkeypatch, what):
 # metric read it, and a small batch paid for it (PERF.md §6 PR 36)
 FLAGGED = {("h2d.wait", "upload"), ("kmeans.lloyd", "device"), ("pca.cov", "device"),
            ("pca.eig.solve", "device"), ("logistic.solve", "device"), ("fit.stage", "none"),
-           ("forest.grow", "device")}
+           ("forest.grow", "device"), ("linear.stats", "device")}
 
 
 def test_only_the_seven_spans_of_the_catalog_are_flagged():
@@ -262,6 +262,10 @@ def _toy_fit(family):
     frame = pd.DataFrame({"features": list(X), "label": y})
     if family == "forest":
         return RandomForestClassifier(numTrees=2, maxDepth=3, seed=1).fit(frame), X
+    if family == "linreg":
+        from spark_rapids_ml_tpu.regression import LinearRegression
+
+        return LinearRegression(regParam=1e-3).fit(frame), X
     return LogisticRegression(maxIter=3, regParam=1e-3).fit(frame), X
 
 
@@ -278,13 +282,14 @@ def fitted():
 
 
 DEVICE_WAITS = {"kmeans": {"kmeans.lloyd"}, "pca": {"pca.cov", "pca.eig.solve"},
-                "logreg": {"logistic.solve"}, "forest": {"forest.grow"}}
+                "logreg": {"logistic.solve"}, "forest": {"forest.grow"},
+                "linreg": {"linear.stats"}}
 
 
 @pytest.mark.parametrize("labels", [{"waits": "upload"}, {"waits": "device"}, {"waits": "none"},
                                     {"waits": "run"}],
                          ids=["upload", "device", "none", "run"])
-@pytest.mark.parametrize("family", ["kmeans", "pca", "logreg", "forest"])
+@pytest.mark.parametrize("family", ["kmeans", "pca", "logreg", "forest", "linreg"])
 def test_a_fit_leaves_its_waits_and_its_run_in_the_report(fitted, family, labels):
     model, _ = fitted(family)
     counters = model.fit_report_["metrics"]["counters"]
@@ -300,7 +305,7 @@ def test_a_fit_leaves_its_waits_and_its_run_in_the_report(fitted, family, labels
     assert spans == want[labels["waits"]]
 
 
-@pytest.mark.parametrize("family", ["kmeans", "pca", "logreg", "forest"])
+@pytest.mark.parametrize("family", ["kmeans", "pca", "logreg", "forest", "linreg"])
 def test_a_fits_shares_nest(fitted, family):
     """What the acceptance of a `--trace 1` line asks of every fit cell."""
     counters = fitted(family)[0].fit_report_["metrics"]["counters"]

@@ -195,9 +195,10 @@ def test_xtxy_ragged_and_non_lane_multiple():
 
 
 def test_normal_eq_matches_xla_stats_sharded(n_devices):
-    """normal_eq_prefix_mask under an 8-device mesh vs linreg_sufficient_stats:
-    the fused one-read pass must reproduce (A, b, x̄, ȳ, Σw) and add Σy²."""
-    from spark_rapids_ml_tpu.ops.linear import linreg_sufficient_stats
+    """normal_eq_prefix_mask under an 8-device mesh vs the in-core XLA pass:
+    the fused one-read pass's raw moments, centred as the solve takes them
+    (`center_moments`), must reproduce its (S, r, x̄, ȳ, Σw), and it adds Σy²."""
+    from spark_rapids_ml_tpu.ops.linear import center_moments, normal_equations
     from spark_rapids_ml_tpu.ops.pallas_xtwx import normal_eq_prefix_mask
     from spark_rapids_ml_tpu.parallel.mesh import get_mesh, shard_array
     from spark_rapids_ml_tpu.parallel.partition import pad_rows
@@ -212,11 +213,12 @@ def test_normal_eq_matches_xla_stats_sharded(n_devices):
     A_f, b_f, xbar_f, ybar_f, n_f, yty_f = normal_eq_prefix_mask(
         Xd, yd, wd, mesh=mesh, interpret=True
     )
-    A_r, b_r, xbar_r, ybar_r, n_r = linreg_sufficient_stats(
+    S_f, r_f, _, _, _ = center_moments(A_f, b_f, xbar_f, ybar_f, n_f)
+    S_r, r_r, xbar_r, ybar_r, n_r = normal_equations(
         jnp.asarray(Xp), jnp.asarray(yp), jnp.asarray(w)
     )
-    np.testing.assert_allclose(np.asarray(A_f), np.asarray(A_r), rtol=2e-3, atol=1e-2)
-    np.testing.assert_allclose(np.asarray(b_f), np.asarray(b_r), rtol=2e-3, atol=1e-2)
+    np.testing.assert_allclose(np.asarray(S_f), np.asarray(S_r), rtol=2e-3, atol=1e-2)
+    np.testing.assert_allclose(np.asarray(r_f), np.asarray(r_r), rtol=2e-3, atol=1e-2)
     np.testing.assert_allclose(np.asarray(xbar_f), np.asarray(xbar_r), rtol=1e-5, atol=1e-6)
     assert float(ybar_f) == pytest.approx(float(ybar_r), rel=1e-5)
     assert float(n_f) == pytest.approx(1000.0)
