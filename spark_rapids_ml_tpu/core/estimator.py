@@ -67,6 +67,10 @@ class FitInputs:
     # kernels may then take prefix-mask fast paths (ops/pallas_xtwx.py) that avoid
     # streaming a weight vector entirely
     unit_weight: bool = False
+    # the fit's one pass already summed as the table's chunks landed
+    # (`_TpuCaller._upload_fold`, ops/linalg.py::GramFold): the statistics on
+    # the device, not waited for, or None where the fit runs its own pass
+    folded: Optional[Any] = None
 
     def device_arrays(self) -> List[Any]:
         """The arrays placed on the mesh for this fit."""
@@ -114,6 +118,14 @@ class _TpuCaller(_TpuClass, _TpuParams):
 
     def _repartition_needed(self) -> bool:
         return True
+
+    def _upload_fold(self, n_cols: int, unit_weight: bool, dtype: Any, row_weight: Any,
+                     label: Optional[Any]) -> Optional[Any]:
+        """The consumer of the table's row chunks as they land
+        (`parallel/partitioner.py::_put_chunked`) for an estimator whose fit is
+        one pass that sums chunk by chunk (`ops/linalg.py::GramFold`), given
+        the placed row weights and label; None: nothing rides the upload."""
+        return None
 
     # ---- data prep + execution ----
 
@@ -245,10 +257,16 @@ class _TpuCaller(_TpuClass, _TpuParams):
             padded_m=Xp.shape[0],
         )
 
+        # the weights and label go up first: a fold reads them as the table's
+        # chunks land
+        row_weight = part.shard(row_weight, site="fit")
+        label = part.shard(label_p, site="fit") if label_p is not None else None
+        fold = self._upload_fold(Xp.shape[1], sw_p is None, Xp.dtype, row_weight, label)
+        features = part.shard(Xp, site="fit", consume=fold)
         return FitInputs(
-            features=part.shard(Xp, site="fit"),
-            row_weight=part.shard(row_weight, site="fit"),
-            label=part.shard(label_p, site="fit") if label_p is not None else None,
+            features=features,
+            row_weight=row_weight,
+            label=label,
             desc=desc,
             mesh=mesh,
             params=dict(self._tpu_params),
@@ -258,6 +276,7 @@ class _TpuCaller(_TpuClass, _TpuParams):
             host_row_weight=fd.weight,
             row_id=fd.row_id,
             unit_weight=sw_p is None,
+            folded=None if fold is None else fold.close(),
         )
 
     def _build_fit_inputs_from_global(

@@ -103,6 +103,11 @@ class PCA(_PCAClass, _TpuEstimator, _PCAParams):
         # tiny replicated eigh (P6 pattern)
         return True
 
+    def _upload_fold(self, n_cols, unit_weight, dtype, row_weight, label):
+        from ..ops.pca import covariance_fold
+
+        return covariance_fold(n_cols, unit_weight, dtype, row_weight)
+
     def _get_tpu_fit_func(self, extra_params: Optional[List[Dict[str, Any]]] = None):
         base_k = self.getOrDefault("k")
 
@@ -132,6 +137,7 @@ class PCA(_PCAClass, _TpuEstimator, _PCAParams):
                         inputs.row_weight,
                         mesh=inputs.mesh,
                         unit_weight=inputs.unit_weight,
+                        folded=inputs.folded,
                     )
                 )
             with span("pca.eig"):

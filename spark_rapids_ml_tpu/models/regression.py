@@ -203,6 +203,13 @@ class LinearRegression(_LinearRegressionClass, _TpuEstimatorSupervised, _LinearR
         # matrix-free ELL normal-equation solver in ops/sparse.py
         return True
 
+    def _upload_fold(self, n_cols, unit_weight, dtype, row_weight, label):
+        if self._tpu_params.get("loss", "squared_loss") == "huber":
+            return None  # no sufficient statistics: the loss reads X every iteration
+        from ..ops.linear import normal_equations_fold
+
+        return normal_equations_fold(n_cols, unit_weight, dtype, row_weight, label)
+
     def _get_tpu_fit_func(self, extra_params: Optional[List[Dict[str, Any]]] = None):
         p = dict(self._tpu_params)
 
@@ -269,7 +276,8 @@ class LinearRegression(_LinearRegressionClass, _TpuEstimatorSupervised, _LinearR
                 else:
                     sqres = linreg_fit(
                         inputs.features, inputs.label, inputs.row_weight,
-                        mesh=inputs.mesh, unit_weight=inputs.unit_weight, **common
+                        mesh=inputs.mesh, unit_weight=inputs.unit_weight,
+                        folded=inputs.folded, **common
                     )
                 for j, i in enumerate(sq):
                     results[i] = sqres[j]

@@ -35,7 +35,16 @@ import numpy as np
 from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
 from ._precision import pdot
-from .linalg import centered_normal_equations, gram_column_blocks, power_iteration_lmax, rows_split
+from .linalg import (
+    centered_normal_equations,
+    fold_finish,
+    fold_part,
+    gram_column_blocks,
+    open_fold,
+    power_iteration_lmax,
+    recentre,
+    rows_split,
+)
 
 
 @compiled_kernel("linear.normal_equations", static_argnames=("mesh",))
@@ -72,8 +81,30 @@ def normal_equations(
         )(X, y, w, xbar, ybar)
     else:
         S, r, dx, dy = centered_normal_equations(X, y, w, xbar, ybar)
-    dx, dy = dx / wsum, dy / wsum
-    return (S - wsum * jnp.outer(dx, dx), r - wsum * dx * dy, xbar + dx, ybar + dy, wsum)
+    return (*recentre(S, dx, wsum, xbar, r, dy, ybar), wsum)
+
+
+@compiled_kernel("linear.normal_equations_part", donate_argnums=(0,))
+def normal_equations_part(state, chunk: jax.Array, w: jax.Array, y: jax.Array):
+    """The normal equations' fold step (`linalg.fold_part` with the label)
+    under their program name: one chunk of the upload as it lands."""
+    return fold_part(state, chunk, w, y)
+
+
+@compiled_kernel("linear.normal_equations_finish")
+def normal_equations_finish(state, w: jax.Array):
+    """(S, r, x̄, ȳ, Σw) of the folded sums, as `normal_equations` returns them."""
+    return fold_finish(state, w)
+
+
+def normal_equations_fold(n_cols: int, unit_weight: bool, dtype, w: jax.Array, y: jax.Array):
+    """The upload's consumer that sums the normal equations' statistics as
+    the table's chunks land (`linalg.GramFold`), for a fit whose producer
+    would be `normal_equations`; None where it is the fused Pallas pass."""
+    from .pca import use_fused_gram
+
+    return open_fold("normal_equations", use_fused_gram(n_cols, unit_weight, dtype=dtype),
+                     normal_equations_part, normal_equations_finish, w, y)
 
 
 @compiled_kernel("linear.center_moments")
@@ -191,6 +222,7 @@ def linreg_fit(
     extra_param_sets: Optional[List[Dict[str, Any]]] = None,
     mesh=None,
     unit_weight: bool = False,
+    folded=None,
 ) -> List[Dict[str, Any]]:
     """Full fit: one distributed stats pass, then per-param-map host-replicated solves.
 
@@ -202,10 +234,13 @@ def linreg_fit(
     Unit-weight fits on TPU up to `MAX_FUSED_COLS` columns take the fused
     one-X-read pallas pass (ops/pallas_xtwx.py::normal_eq_prefix_mask), whose
     raw moments are centred before the solve; the same `use_fused_gram` gate as
-    the PCA covariance. Every other fit takes `normal_equations`.
+    the PCA covariance. Every other fit takes `normal_equations`, unless the
+    upload already summed its statistics chunk by chunk (`folded`:
+    `normal_equations_fold`'s, the same step as the resident pass).
     `linear.stats_form{form=centred_triangle|centred_full|fused}` counts which
     producer ran, from the same shape tests, and the span `linear.stats` is
-    the pass from its dispatch to its result on the device."""
+    the pass from its dispatch to its result on the device (for a folded fit
+    what is left of it once the table has landed)."""
     from .pca import use_fused_gram
 
     fused = use_fused_gram(X.shape[1], unit_weight, dtype=X.dtype)
@@ -226,6 +261,8 @@ def linreg_fit(
                 X, y, w, mesh=mesh, precision=parity_precision(), interpret=interpret
             )
             stats = center_moments(*raw[:5])
+        elif folded is not None:
+            stats = folded
         else:
             stats = normal_equations(X, y, w, mesh=mesh)
         # waited for, so that the pass and the solve below are not one number

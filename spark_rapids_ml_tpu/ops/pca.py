@@ -34,7 +34,13 @@ import numpy as np
 
 from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
-from .linalg import gram_column_blocks, weighted_covariance
+from .linalg import (
+    gram_column_blocks,
+    open_fold,
+    weighted_covariance,
+    weighted_covariance_finish,
+    weighted_covariance_part,
+)
 
 
 @compiled_kernel("pca.from_cov", static_argnames=("k",))
@@ -95,12 +101,22 @@ def use_fused_gram(n_cols: int, unit_weight: bool, dtype=jnp.float32) -> bool:
     return gram_gate(n_cols, unit_weight, dtype)[0]
 
 
+def covariance_fold(n_cols: int, unit_weight: bool, dtype, w: jax.Array):
+    """The upload's consumer that sums the covariance as the table's chunks
+    land (`linalg.GramFold`), for a fit whose producer would be the XLA
+    pass; None where `gram_gate` picks the fused Pallas kernel."""
+    return open_fold("gram", use_fused_gram(n_cols, unit_weight, dtype=dtype),
+                     weighted_covariance_part, weighted_covariance_finish, w)
+
+
 def covariance_for_fit(
-    X: jax.Array, w: jax.Array, mesh=None, unit_weight: bool = False
+    X: jax.Array, w: jax.Array, mesh=None, unit_weight: bool = False, folded=None
 ):
     """Covariance dispatch for estimator fits: the fused pallas kernel when the
     measured win applies (see gram_gate), else the XLA sufficient-statistics
-    pass. Both return (cov, mean, wsum) with identical semantics.
+    pass, or what the upload already summed of it (`folded`:
+    `covariance_fold`'s, the same step as the resident pass). All return
+    (cov, mean, wsum) with identical semantics.
 
     `pca.gram_path{path=xla|pallas}` counts which one ran and
     `pca.gram_gate{fused=0|1,reason=}` which test decided it: beyond
@@ -127,6 +143,8 @@ def covariance_for_fit(
         counter_inc("pca.gram_form", 1, form="triangle", blocks=blocks)
     else:
         counter_inc("pca.gram_form", 1, form="full")
+    if folded is not None:
+        return folded
     return weighted_covariance(X, w, mesh=mesh)
 
 

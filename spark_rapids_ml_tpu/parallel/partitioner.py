@@ -153,12 +153,18 @@ def _single_put_reason(x: Any, target: Any, min_bytes: int) -> Optional[str]:
     return None
 
 
-def _put_chunked(x: np.ndarray, target: Any, chunk_bytes: int) -> Tuple[Optional[jax.Array], int]:
+def _put_chunked(x: np.ndarray, target: Any, chunk_bytes: int,
+                 consume: Optional[Callable[[jax.Array], None]] = None
+                 ) -> Tuple[Optional[jax.Array], int]:
     """`x` placed on the one device `target` names (`_one_device`), as
     contiguous row chunks of at most `chunk_bytes`, written there into one
     array of the whole shape. Returns the array and the chunks dispatched;
     (None, chunks) where it came out in another layout than a whole put's
-    (remembered: the gate says `layout` from then on)."""
+    (remembered: the gate says `layout` from then on). `consume`, where
+    given, is handed each chunk's own device buffer, in row order, right
+    after its put and placement are dispatched (a fit's fold of its one pass,
+    `ops/linalg.py::GramFold`): never a slice of the array being assembled,
+    which each placement donates to the next."""
     dtype = jax.dtypes.canonicalize_dtype(x.dtype)  # as `device_put` would
     device = None if target is None else _one_device(target)  # None: uncommitted, as the put
     per = _chunk_rows(x.nbytes // len(x), chunk_bytes)
@@ -166,7 +172,10 @@ def _put_chunked(x: np.ndarray, target: Any, chunk_bytes: int) -> Tuple[Optional
     start = jax.numpy.zeros((), np.int32, device=device)
     dispatched = 0
     for s in range(0, len(x), per):
-        whole, start = _place(whole, jax.device_put(x[s:s + per], device), start)
+        chunk = jax.device_put(x[s:s + per], device)
+        whole, start = _place(whole, chunk, start)
+        if consume is not None:
+            consume(chunk)
         dispatched += 1
     (on,) = whole.devices()
     if whole.format.layout != _default_layout(whole.shape, whole.dtype, on):
@@ -178,7 +187,8 @@ def _put_chunked(x: np.ndarray, target: Any, chunk_bytes: int) -> Tuple[Optional
 
 
 def _put(site: Optional[str], x: Any, target: Any = None,
-         place: Optional[Callable[[], jax.Array]] = None) -> jax.Array:
+         place: Optional[Callable[[], jax.Array]] = None,
+         consume: Optional[Callable[[jax.Array], None]] = None) -> jax.Array:
     """The one choke point of host->device placement: `x` put as `target`
     says (`_whole_put`), or by `place` where a caller has its own single put
     to the same `target` (`shard_inputs`). With a `site` ("fit", "transform")
@@ -188,7 +198,8 @@ def _put(site: Optional[str], x: Any, target: Any = None,
     needs the array resident (core/estimator.py, observability/inference.py).
     A sited put of a large array goes up in row chunks (above):
     `h2d.chunk_gate{site=,chunked=,reason=}` says which way it went and why,
-    `h2d.chunks{site=}` counts the chunks. Without a site the placement is
+    `h2d.chunks{site=}` counts the chunks, and `consume` sees each of them
+    (`_put_chunked`; a put that is not chunked hands it nothing). Without a site the placement is
     neither timed nor counted nor chunked: the streamed tier accounts for its
     batches itself (`stream.ingest`, `stream.upload_bytes`), and nothing is
     counted twice."""
@@ -200,7 +211,7 @@ def _put(site: Optional[str], x: Any, target: Any = None,
         reason = _single_put_reason(x, target, CHUNK_MIN_BYTES)
         out = None
         if reason is None:
-            out, chunks = _put_chunked(x, target, CHUNK_BYTES)
+            out, chunks = _put_chunked(x, target, CHUNK_BYTES, consume)
             _obs.counter_inc("h2d.chunks", chunks, site=site)
             reason = "ok" if out is not None else "layout"
         chunked = out is not None
@@ -276,11 +287,12 @@ class Partitioner:
 
     # ------------------------------------------------------------ placement
 
-    def shard(self, x: Any, site: Optional[str] = None) -> jax.Array:
+    def shard(self, x: Any, site: Optional[str] = None,
+              consume: Optional[Callable[[jax.Array], None]] = None) -> jax.Array:
         """Place a host array on the mesh with rows on the data axis
         (single-process; for multi-process staging use `shard_inputs`).
-        `site`: see `_put`."""
-        return _put(site, x, self.data_sharding(np.ndim(x)))
+        `site`, `consume`: see `_put`."""
+        return _put(site, x, self.data_sharding(np.ndim(x)), consume=consume)
 
     def replicate(self, x: Any) -> jax.Array:
         return jax.device_put(x, self.state_sharding())

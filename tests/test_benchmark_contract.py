@@ -504,3 +504,22 @@ def test_the_upload_is_waited_for_under_h2d_wait_and_nowhere_before(cell, runs):
     assert for_the_table, run["waits"]
     name, attrs = for_the_table[0][-1]  # the first wait that holds the table
     assert (name, attrs.get("site"), attrs.get("waits")) == ("h2d.wait", site, "upload")
+
+
+@pytest.mark.parametrize("cell,program,other", [
+    ("pca_k3_d3000.fit", "weighted_covariance", "normal_equations"),
+    ("linreg_l2_d3000.fit", "normal_equations", "weighted_covariance")])
+def test_the_grams_programs_are_read_where_the_upload_folds_them(cell, program, other, runs):
+    """In the two cells whose one pass is the centred Gram the toy table's
+    chunks carry it (`h2d.fold{engaged=true}`, `fit_gram_under_upload_per_op`
+    1.0): every program of the pass the run called is a fold program whose
+    name holds what `cov_roofline`, `linreg_stats_roofline` and
+    `fit_linreg_stats_device_s` match by substring, and no program of the
+    one cell's pass holds the other's name."""
+    run = runs(cell)
+    assert _total(run["report_counters"], "h2d.fold", {"engaged": "true"}) == 1
+    modules = {line.split()[1].rstrip(",") for text in run["programs"] for line in text.splitlines()
+               if line.startswith("HloModule ")}
+    read = {m for m in modules if program in m}
+    assert read and read <= {f"jit_{program}_part", f"jit_{program}_finish"}, modules
+    assert not {m for m in modules if other in m}, modules

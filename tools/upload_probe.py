@@ -73,6 +73,17 @@ Defaults: 357376 3000 (the wide cells' table), every part:
               `_host_sample` gives None, which is all a platform without
               `resource` does). PERF.md's readings are of a narrow table:
               `python -m tools.upload_probe 4096 128 small`
+    fold      a fit's one pass riding the upload (ROADMAP S12(c)): the
+              program's put and wait of the table alone (`none`); the same put
+              with the centred Gram's part step dispatched on each chunk as it
+              lands (`ops/linalg.py::GramFold`, PCA's `gram` and the normal
+              equations' form), then the wait for the table and for the
+              statistics; the resident pass after the upload (`after`:
+              `weighted_covariance`, `normal_equations`); and the fold's parts
+              alone over chunks already on the device (`resident`). Five
+              readings each (`rep` -1 compiles): seconds of dispatch, of the
+              upload (put and wait), of what the statistics took past it, and
+              of the whole
 """
 
 from __future__ import annotations
@@ -95,7 +106,7 @@ from spark_rapids_ml_tpu.observability import runs
 from spark_rapids_ml_tpu.parallel.partitioner import active_partitioner
 
 PARTS = ("host", "span", "program", "same", "fresh", "aligned", "chunks", "assembled", "mesh",
-         "sizes", "second", "small")
+         "sizes", "second", "small", "fold")
 HUGE = 2 << 20
 
 
@@ -468,6 +479,61 @@ def small_batch(X, reps=400, blocks=8):
         registry.close()
 
 
+def fold(X, reps=5):
+    from spark_rapids_ml_tpu.ops import linalg, linear
+
+    part = active_partitioner(1)
+    w = part.shard(np.ones(len(X), np.float32))
+    y = part.shard(X[:, 0] * 2.0 + 1.0)
+    forms = {"gram": (linalg.weighted_covariance_part, linalg.weighted_covariance_finish, None,
+                      lambda a: linalg.weighted_covariance(a, w)),
+             "normal_equations": (linear.normal_equations_part, linear.normal_equations_finish, y,
+                                  lambda a: linear.normal_equations(a, y, w))}
+    jax.block_until_ready((w, y))
+
+    def timed(form, rep, put, then):
+        t0 = time.perf_counter()
+        placed, stats, parts = put()
+        t1 = time.perf_counter()
+        jax.block_until_ready(placed)
+        t2 = time.perf_counter()
+        stats = then(placed, stats)
+        jax.block_until_ready(stats)
+        t3 = time.perf_counter()
+        placed.delete()
+        _say(part="fold", form=form, rep=rep, parts=parts, dispatch_s=t1 - t0, upload_s=t2 - t0,
+             stats_past_upload_s=t3 - t2, total_s=t3 - t0, gb_per_s=X.nbytes / (t2 - t0) / 1e9)
+
+    def folded(step, finish, label):
+        def put():
+            consumer = linalg.GramFold(form, step, finish, w, label)
+            placed = part.shard(X, site="fit", consume=consumer)
+            return placed, consumer.close(), consumer.parts
+        return put
+
+    for rep in range(-1, reps):
+        timed("none", rep, lambda: (part.shard(X, site="fit"), None, 0), lambda a, s: None)
+        for form, (step, finish, label, resident) in forms.items():
+            timed(form, rep, folded(step, finish, label), lambda a, s: s)
+            timed(form + ".after", rep, lambda: (part.shard(X, site="fit"), None, 0),
+                  lambda a, s, resident=resident: resident(a))
+    per = 2048 if X.shape[1] == 3000 else 1024
+    chunks = [jax.device_put(X[s:s + per]) for s in range(0, len(X), per)]
+    jax.block_until_ready(chunks)
+    for form, (step, finish, label, _) in forms.items():
+        for rep in range(-1, reps):
+            t0 = time.perf_counter()
+            consumer = linalg.GramFold(form, step, finish, w, label)
+            for c in chunks:
+                consumer(c)
+            jax.block_until_ready(consumer.close())
+            seconds = time.perf_counter() - t0
+            _say(part="fold", form=form + ".resident", rep=rep, parts=len(chunks),
+                 total_s=seconds, part_ms=1e3 * seconds / len(chunks))
+    for c in chunks:
+        c.delete()
+
+
 def main(argv):
     on_chip = jax.devices()[0].platform == "tpu"
     if not on_chip and len(argv) < 2:
@@ -513,6 +579,8 @@ def main(argv):
         second_put(X)
     if "small" in parts:
         small_batch(X)
+    if "fold" in parts:
+        fold(X)
     return 0
 
 
