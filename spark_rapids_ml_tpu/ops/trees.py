@@ -355,6 +355,52 @@ def tile_features(packed: bool, j, f: int, d_pad: int) -> jax.Array:
     return (byte * (d_pad // 4) + j * words + word).reshape(f)
 
 
+def compact_gate(width: int, d: int, k_features: int):
+    """Whether a level of `width` nodes histograms only the columns its nodes
+    drew, and which test decided it, from shapes alone: `all_features` (every
+    node may split on every column), `width` (the union of the level's draws,
+    width * k_features columns at most, pads to as many columns as the whole
+    matrix: from width * k_features >= d on, and at a few widths under it),
+    else `ok`."""
+    if k_features >= d:
+        return False, "all_features"
+    if feature_plan(width * k_features)[0] >= feature_plan(d)[0]:
+        return False, "width"
+    return True, "ok"
+
+
+def _take_columns(words: jax.Array, cols) -> jax.Array:
+    """The packed words of the features `cols` ((F,) int32, each below d):
+    feature-major words in, (Q, n), and out, (F // 4, n), with feature i at
+    byte i // (F // 4) of word i % (F // 4), as `_pack_words` lays them; F // 4
+    is a multiple of `WORDS_PER_STEP` (`feature_plan`'s widths). A result word
+    is four dynamic row slices, a byte each, of the words laid out as (Q, n /
+    128, 128), where a row is whole tiles (a row of (Q, n) is one sublane of
+    every tile it crosses); `WORDS_PER_STEP` result words at a time, so nothing
+    but the result is of its size. No gather: the TPU's compiler lowers one
+    through a chunked copy of the whole operand."""
+    from .pallas_histogram import WORDS_PER_STEP
+
+    q, n = words.shape
+    qc = cols.shape[0] // 4
+    word, shift = cols % q, 8 * (cols // q)
+    rows = jnp.pad(words, ((0, 0), (0, -n % 128))).reshape(q, -1, 128)
+
+    def block(b):  # the result's words [b * WORDS_PER_STEP, (b + 1) * WORDS_PER_STEP)
+        out = []
+        for i in range(WORDS_PER_STEP):
+            w = 0
+            for k in range(4):
+                at = k * qc + b * WORDS_PER_STEP + i
+                row = jax.lax.dynamic_slice_in_dim(rows, word[at], 1, axis=0)
+                w = w | (((row >> shift[at]) & 0xFF) << (8 * k))
+            out.append(w)
+        return jnp.concatenate(out, axis=0)
+
+    blocks = jax.lax.map(block, jnp.arange(qc // WORDS_PER_STEP))
+    return blocks.reshape(qc, -1)[:, :n]
+
+
 def _tile_ids(Xb, packed: bool, j, f: int) -> jax.Array:
     """(n, f) int32 bin ids of feature tile `j`, in `tile_features`' order."""
     if not packed:
@@ -484,17 +530,23 @@ def _level_step(
     program per level (timing mode: one compiled dispatch + sync per level
     measures real device wall-clock without making the whole tree eager).
     `Xb` is (n, d_pad) bin ids with d_pad from `feature_plan`, or under `packed`
-    (n, d_pad // 4) words of four one-byte ids (`_pack_words`); `edges` carries d."""
+    (n, d_pad // 4) words of four one-byte ids (`_pack_words`); `edges` carries d.
+
+    Where `compact_gate` says so the histogram and the search run over the
+    columns some node of the level drew and no other: the union of the draws,
+    in ascending order, gathered into a narrow bin matrix of `feature_plan(width
+    * k_features)` columns whose features keep their own ids, so every gain,
+    tie rule and split is the whole-width scan's. A union wider than that
+    matrix (float ties in the draw admit more than k_features to a node) runs
+    the level whole-width and adds one to the state's overflow count."""
     from .pallas_histogram import _interpret, group_rows, node_tile
 
-    (feat_arr, thr_arr, leaf_arr, val_arr, gain_arr, wgt_arr, node_id, T, key) = state
+    (feat_arr, thr_arr, leaf_arr, val_arr, gain_arr, wgt_arr, node_id, T, key,
+     overflows) = state
     n = Xb.shape[0]
-    d_pad = Xb.shape[1] * (4 if packed else 1)
     d = edges.shape[0]
     s = values.shape[1]
     width = 2**t
-    f = level_tile(width, d, nbins, s, 0 if form == "grouped" else n)
-    n_tiles = d_pad // f
 
     # the feature draw: the same stream a level as ever (one split of the key,
     # one (width, d) uniform), masked inside each tile
@@ -508,37 +560,77 @@ def _level_step(
     else:
         allowed = jnp.ones((width, d), bool)
 
-    grouped = None
+    grp = None
     n_nodes = width
+    words = Xb
     if form == "grouped":
         assert packed, "the grouped kernel reads four one-byte ids a word"
         c = node_tile(s)
         n_nodes = -(-width // c) * c
         grp = group_rows(node_id, values, width, jnp.dtype(operand))
-        words = Xb if grp["order"] is None else jnp.take(Xb, grp["order"], axis=0)
-        grouped = (words.T, grp, _interpret())
-    # feature-major: a tile's rows are a row gather; no padded feature, no padded node
-    allowed = jnp.pad(allowed, ((0, n_nodes - width), (0, d_pad - d))).T
+        if grp["order"] is not None:  # rows sorted by node
+            words = jnp.take(Xb, grp["order"], axis=0)
     T_pad = jnp.pad(T, ((0, n_nodes - width), (0, 0)))
 
-    def tile_best(j):
-        h = _tile_histogram(form, Xb, packed, values, node_id, width, nbins, j, f, mesh,
-                            grouped)
-        feat = tile_features(packed, j, f, d_pad)
-        return _tile_best(h, form == "grouped", T_pad, jnp.take(allowed, feat, axis=0),
-                          feat, nbins, impurity, min_instances)
+    def scan(X, cols):
+        """The best split a node over the bin matrix `X` (`Xb`'s layout, rows
+        in the level's order; the grouped form's feature-major words) whose
+        feature i is feature cols[i] ((F,) int32, d for a padding slot), or,
+        cols None, every feature of `Xb`."""
+        cols_pad = X.shape[0 if form == "grouped" else 1] * (4 if packed else 1)
+        f = level_tile(width, d if cols is None else cols.shape[0], nbins, s,
+                       0 if form == "grouped" else n)
+        ok = allowed if cols is None else (
+            jnp.take(allowed, jnp.minimum(cols, d - 1), axis=1) & (cols < d))
+        # feature-major: a tile's rows are a row gather; no padded feature, no padded node
+        ok = jnp.pad(ok, ((0, n_nodes - width), (0, cols_pad - ok.shape[1]))).T
+        grouped = None if grp is None else (X, grp, _interpret())
 
-    best = tile_best(jnp.int32(0))
-    if n_tiles > 1:
-        def merge(carry, j):
-            new = tile_best(j)
-            # an equal gain keeps the lower (feature, bin), whatever order the
-            # tiles came in: the answer of one argmax over the whole level
-            take = (new[0] > carry[0]) | ((new[0] == carry[0]) & (new[1] < carry[1]))
-            return (jnp.where(take, new[0], carry[0]), jnp.where(take, new[1], carry[1]),
-                    jnp.where(take[:, None], new[2], carry[2])), None
+        def tile_best(j):
+            h = _tile_histogram(form, X, packed, values, node_id, width, nbins, j, f, mesh,
+                                grouped)
+            at = tile_features(packed, j, f, cols_pad)
+            return _tile_best(h, form == "grouped", T_pad, jnp.take(ok, at, axis=0),
+                              at if cols is None else cols[at], nbins, impurity,
+                              min_instances)
 
-        best, _ = jax.lax.scan(merge, best, jnp.arange(1, n_tiles, dtype=jnp.int32))
+        best = tile_best(jnp.int32(0))
+        if cols_pad > f:
+            def merge(carry, j):
+                new = tile_best(j)
+                # an equal gain keeps the lower (feature, bin), whatever order the
+                # tiles came in: the answer of one argmax over the whole level
+                take = (new[0] > carry[0]) | ((new[0] == carry[0]) & (new[1] < carry[1]))
+                return (jnp.where(take, new[0], carry[0]), jnp.where(take, new[1], carry[1]),
+                        jnp.where(take[:, None], new[2], carry[2])), None
+
+            best, _ = jax.lax.scan(merge, best, jnp.arange(1, cols_pad // f, dtype=jnp.int32))
+        if cols is not None:
+            # a node with no candidate names (feature 0, bin 0), as the
+            # whole-width scan's first tile does: the leaf's threshold
+            best = (best[0], jnp.where(best[0] == -jnp.inf, 0, best[1]), best[2])
+        return best
+
+    def whole():  # the bin matrix as the tiles read it
+        return words.T if form == "grouped" else words
+
+    def narrowed(cols):  # the same, of the features `cols` alone
+        if not packed:
+            return jnp.take(words, cols, axis=1)
+        fm = _take_columns(words.T, cols)
+        return fm if form == "grouped" else fm.T
+
+    if compact_gate(width, d, k_features)[0]:
+        narrow = feature_plan(width * k_features)[0]
+        drawn = allowed.any(axis=0)
+        fits = jnp.sum(drawn) <= narrow
+        (cols,) = jnp.nonzero(drawn, size=narrow, fill_value=d)
+        cols = cols.astype(jnp.int32)
+        best = jax.lax.cond(fits, lambda: scan(narrowed(jnp.minimum(cols, d - 1)), cols),
+                            lambda: scan(whole(), None))
+        overflows = overflows + (~fits).astype(jnp.int32)
+    else:
+        best = scan(whole(), None)
     best_gain, best_flat, Lbest = (x[:width] for x in best)
     best_feat = jnp.minimum(best_flat // (nbins - 1), d - 1)  # _NO_SPLIT: a leaf anyway
     best_bin = best_flat % (nbins - 1)
@@ -562,7 +654,8 @@ def _level_step(
 
     # children stats carried from the winning split
     T = jnp.stack([Lbest, T - Lbest], axis=1).reshape(2 * width, s)
-    return (feat_arr, thr_arr, leaf_arr, val_arr, gain_arr, wgt_arr, node_id, T, key)
+    return (feat_arr, thr_arr, leaf_arr, val_arr, gain_arr, wgt_arr, node_id, T, key,
+            overflows)
 
 
 _level_step_jit = functools.partial(
@@ -601,7 +694,9 @@ def _build_tree_impl(
     packed: bool = False,
 ) -> Dict[str, jax.Array]:
     """Grow one tree; returns heap arrays of size 2^(max_depth+1):
-    feature (int32, -1 for leaf), threshold (f32), is_leaf (bool), value (slots, v).
+    feature (int32, -1 for leaf), threshold (f32), is_leaf (bool), value (slots, v),
+    and `compact_overflows`, the levels `compact_gate` narrowed that ran
+    whole-width because the union of the draws overflowed (int32 scalar).
     `forms` names each level's histogram (`level_forms`); without it every level
     takes the one-hot kernel (`use_pallas`) or the segment_sum. `packed`: `Xb`
     is the fit's (n, d_pad // 4) words of four one-byte ids, not (n, d) ids."""
@@ -630,6 +725,7 @@ def _build_tree_impl(
         jnp.zeros((n,), jnp.int32),  # node_id
         jnp.sum(values, axis=0)[None, :],  # (1, s) root stats
         key,
+        jnp.zeros((), jnp.int32),  # narrowed levels that ran whole-width
     )
 
     step_kw = dict(
@@ -651,7 +747,8 @@ def _build_tree_impl(
             level_timing.append((t, time.perf_counter() - t0))  # noqa: purity/time-read
         else:
             state = _level_step(state, Xb, values, edges, t, form=forms[t], **step_kw)
-    (feat_arr, thr_arr, leaf_arr, val_arr, gain_arr, wgt_arr, node_id, T, key) = state
+    (feat_arr, thr_arr, leaf_arr, val_arr, gain_arr, wgt_arr, node_id, T, _,
+     overflows) = state
 
     # deepest level: all leaves
     width = 2**max_depth
@@ -666,6 +763,7 @@ def _build_tree_impl(
         "value": val_arr,
         "gain": gain_arr,
         "node_weight": wgt_arr,
+        "compact_overflows": overflows,
     }
 
 
@@ -948,10 +1046,15 @@ def _grow_forest(
     devices = mesh.devices.size if multi else 1
     gate_bins = max_bins if packed else 257  # ids not packed a byte each: no grouped form
     forms = level_forms(max_depth, Xb.shape[0], d, gate_bins, s, use_pallas, devices)
+    narrowed = 0  # levels a tree that histogram only the columns their nodes drew
     for t, form in enumerate(forms):
         grouped, reason = hist_gate(2**t, d, gate_bins, s, Xb.shape[0], devices)
         counter_inc("forest.hist_gate", n_trees, grouped=int(grouped), reason=reason)
         counter_inc("forest.hist_path", n_trees, path=form)
+        engaged, reason = compact_gate(2**t, d, feature_subset)
+        narrowed += engaged
+        if not engaged:
+            counter_inc("forest.hist_compact", n_trees, engaged="false", reason=reason)
     edges_j = jnp.asarray(edges)
     trees: List[Dict[str, jax.Array]] = []
     if _LEVEL_TIMING is not None:
@@ -986,6 +1089,12 @@ def _grow_forest(
     with span("forest.fetch"):
         fetched = jax.device_get(trees)
     out = {k: np.stack([t[k] for t in fetched]) for k in fetched[0]}
+    overflows = int(out.pop("compact_overflows").sum())
+    if narrowed:
+        counter_inc("forest.hist_compact", n_trees * narrowed - overflows, engaged="true",
+                    reason="ok")
+    if overflows:
+        counter_inc("forest.hist_compact", overflows, engaged="false", reason="overflow")
     out["bin_edges"] = edges
     return out
 

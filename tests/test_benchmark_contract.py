@@ -38,11 +38,13 @@ ROWS, COLS, MAX_K, MAX_ITER = 512, 16, 4, 3
 # `ops/pallas_xtwx.py::MAX_FUSED_COLS` = 512 columns, and that program
 # multiplies only the upper column blocks of the Gram matrix from
 # `autotune/defaults.py::GRAM_TRIANGLE_MIN_COLS` = 576 columns on, which the
-# normal equations of `linreg_l2_d3000` share
+# normal equations of `linreg_l2_d3000` share; a forest narrows a level to the
+# columns its nodes drew only where that matrix pads narrower than the whole
+# (`ops/trees.py::compact_gate`): at 64 columns and 8 a node, widths 1 to 4
 TOY = {"kmeans_wide": {"rows": 256, "cols": 4096, "max_k": 128},
        "pca_k3_d3000": {"rows": 256, "cols": 640},
        "linreg_l2_d3000": {"rows": 256, "cols": 640},
-       "forest": {"num_trees": 2, "max_depth": 4}}
+       "forest": {"num_trees": 2, "max_depth": 4, "cols": 64}}
 
 # (metric, cell) pairs whose counter reads 0 in that cell BY DESIGN: the
 # program counts the same name under another label there
@@ -523,3 +525,22 @@ def test_the_grams_programs_are_read_where_the_upload_folds_them(cell, program, 
     read = {m for m in modules if program in m}
     assert read and read <= {f"jit_{program}_part", f"jit_{program}_finish"}, modules
     assert not {m for m in modules if other in m}, modules
+
+
+def test_the_forest_narrows_the_levels_its_draws_cannot_fill(runs):
+    """The toy forest (64 columns, `auto`: 8 a node) narrows the levels of
+    one to four nodes, as the cell's narrows those of 1 to 32 (54 of 3000):
+    `fit_forest_hist_compact_per_op` reads numTrees x those levels, and every
+    other tree-level says why it ran whole-width."""
+    from spark_rapids_ml_tpu.ops import trees
+
+    run = runs("rf_cls_depth13_d3000.fit")
+    model, counters = run["model"], run["report_counters"]
+    n_trees, depth, d = model.getNumTrees(), TOY["forest"]["max_depth"], run["table_shape"][1]
+    k = trees.resolve_feature_subset("auto", d, True)
+    gates = [trees.compact_gate(2**t, d, k) for t in range(depth)]
+    assert [g for g, _ in gates] == [True, True, True, False]
+    assert _total(counters, "forest.hist_compact", {"engaged": "true"}) == 3 * n_trees
+    assert _total(counters, "forest.hist_compact", {"engaged": "false", "reason": "width"}) == (
+        n_trees)
+    assert _total(counters, "forest.hist_compact", {}) == depth * n_trees

@@ -325,6 +325,13 @@ def _avals(jaxpr):
             yield from _avals(sub)
 
 
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
 def test_level_step_at_published_widths_holds_no_whole_level_histogram(monkeypatch):
     """Width 4096, 3000 columns, 128 bins, 2 classes, 357,376 rows, from shapes
     alone: no intermediate of the traced level step has width*d*nbins*s
@@ -350,7 +357,8 @@ def test_level_step_at_published_widths_holds_no_whole_level_histogram(monkeypat
     S = jax.ShapeDtypeStruct
     state = (S((slots,), jnp.int32), S((slots,), jnp.float32), S((slots,), jnp.bool_),
              S((slots, s), jnp.float32), S((slots,), jnp.float32), S((slots,), jnp.float32),
-             S((n,), jnp.int32), S((width, s), jnp.float32), S((2,), jnp.uint32))
+             S((n,), jnp.int32), S((width, s), jnp.float32), S((2,), jnp.uint32),
+             S((), jnp.int32))
 
     def step(state, words, values, edges):
         return trees._level_step(state, words, values, edges, t, nbins, "gini", 54, 1, 0.0,
@@ -363,6 +371,130 @@ def test_level_step_at_published_widths_holds_no_whole_level_histogram(monkeypat
     # the largest thing a level holds is the bin matrix's own words (moved when
     # the rows are grouped): an eleventh of a whole level histogram here
     assert largest == n * (d_pad // 4) and largest * 8 <= whole, (largest, whole)
+    assert "cond" not in {e.primitive.name for e in jaxpr.jaxpr.eqns}  # 4096 x 54 >= 3000
+
+    # width 32: 32 x 54 = 1,728 columns at most, padded to 2,048: the level's
+    # tiles read a narrow matrix of 2,048 columns (two tiles of 1,024), and only
+    # an overflowing union reads all 3,072 (three)
+    t, narrow = 5, trees.feature_plan(32 * 54)[0]
+    assert trees.compact_gate(2**t, d, 54) == (True, "ok") and (narrow, d_pad) == (2048, 3072)
+    state = state[:7] + (S((2**t, s), jnp.float32),) + state[8:]
+    jaxpr = jax.make_jaxpr(step)(state, S((n, d_pad // 4), jnp.int32), S((n, s), jnp.float32),
+                                 S((d, nbins - 1), jnp.float32))
+    (cond,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "cond"]
+    whole_branch, narrow_branch = cond.params["branches"]  # index 1: the union fits
+    f = trees.level_tile(2**t, 32 * 54, nbins, s)
+    for branch, cols in ((narrow_branch, narrow), (whole_branch, d_pad)):
+        read = {a.shape for e in _eqns(branch.jaxpr) if e.primitive.name == "pallas_call"
+                for a in (v.aval for v in e.invars) if a.shape[-1:] == (n,)}
+        assert read == {(cols // 4, n)}, (cols, read)
+        (loop,) = [e for e in _eqns(branch.jaxpr) if e.primitive.name == "scan" and any(
+            x.primitive.name == "pallas_call" for x in _eqns(e.params["jaxpr"].jaxpr))]
+        assert loop.params["length"] == cols // f - 1  # the tiles after the first
+
+
+# ------------------------------------ (e2) a level narrowed to the drawn columns
+
+
+@pytest.mark.parametrize("strategy,cls,engaged", [
+    ("all", True, 0), ("onethird", True, 2), ("sqrt", True, 6), ("auto", True, 6),
+    ("auto", False, 2),
+])
+def test_the_narrowing_gate_reads_shapes_alone(strategy, cls, engaged):
+    """At 3000 columns and depth 13: every node may split on every column
+    under `all` (`all_features`); else a level narrows while its width times
+    the features a node draws stays under the columns, and the deeper levels
+    can draw them all (`width`): 54 a node (sqrt, and auto for a classifier)
+    at widths 1 to 32, 1000 (onethird, and auto for a regressor) at 1 and 2."""
+    k = trees.resolve_feature_subset(strategy, 3000, cls)
+    got = [trees.compact_gate(2**t, 3000, k) for t in range(13)]
+    if strategy == "all":
+        assert got == [(False, "all_features")] * 13
+    else:
+        assert got == [(True, "ok")] * engaged + [(False, "width")] * (13 - engaged)
+
+
+def _level_inputs(form, n=3000, d=37, nbins=32, t=1, seed=23):
+    rng = np.random.default_rng(seed)
+    width, slots, s = 2**t, 2**(t + 2), 2
+    d_pad, _ = trees.feature_plan(d)
+    Xb = jnp.pad(jnp.asarray(rng.integers(0, nbins, size=(n, d)).astype(np.uint8)),
+                 ((0, 0), (0, d_pad - d)))
+    if form == "grouped":
+        Xb = trees._pack_words(Xb)
+    node = jnp.asarray(rng.integers(0, width, n).astype(np.int32))
+    values = jnp.asarray(rng.poisson(1.0, (n, s)).astype(np.float32))
+    T = jax.ops.segment_sum(values, node, num_segments=width)
+    state = (jnp.full((slots,), -1, jnp.int32), jnp.zeros((slots,), jnp.float32),
+             jnp.zeros((slots,), bool), jnp.zeros((slots, s), jnp.float32),
+             jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.float32),
+             node, T, jax.random.PRNGKey(seed), jnp.zeros((), jnp.int32))
+    edges = jnp.asarray(np.sort(rng.normal(size=(d, nbins - 1)), axis=1).astype(np.float32))
+    return state, Xb, values, edges
+
+
+@pytest.mark.parametrize("form", ["xla", "grouped"])
+@pytest.mark.parametrize("tied,empty,overflows", [(20, False, 0), (20, True, 0), (37, False, 1)])
+def test_a_level_whose_draws_tie_splits_as_the_whole_width_step(form, tied, empty, overflows,
+                                                                 monkeypatch):
+    """Float ties at the k-th score admit more than k features to a node. Two
+    nodes drawing 6 of 37 narrow to `feature_plan(12)` = 32 columns: with the
+    last 20 features tied at the top every node allows all 20, a union past
+    2 x 6 that the 32 columns still hold; with all 37 tied the union overflows
+    them, the level runs whole-width and counts one overflow. Either way every
+    output is the whole-width step's on the same draw (the gate turned off),
+    an empty node's too: a leaf with no candidate, whose threshold is feature
+    0's first edge though feature 0 was not drawn."""
+    state, Xb, values, edges = _level_inputs(form)
+    if empty:  # every row at node 0: node 1 holds none
+        state = state[:6] + (jnp.zeros_like(state[6]), jnp.stack([values.sum(0), 0 * values[0]])) \
+            + state[8:]
+    top = jnp.arange(37) >= 37 - tied
+    monkeypatch.setattr(jax.random, "uniform",
+                        lambda key, shape: jnp.broadcast_to(jnp.where(top, 1.0, 0.5), shape))
+    kw = dict(t=1, nbins=32, impurity="gini", k_features=6, min_instances=1,
+              min_info_gain=0.0, mesh=None, form=form, operand="float32",
+              packed=form == "grouped")
+    got = trees._level_step(state, Xb, values, edges, **kw)
+    assert int(got[9]) == overflows
+    monkeypatch.setattr(trees, "compact_gate", lambda *a: (False, "width"))
+    want = trees._level_step(state, Xb, values, edges, **kw)
+    assert int(want[9]) == 0
+    assert (np.asarray(got[0])[2:4] >= 0).tolist() == [True, not empty]
+    if empty:
+        assert np.asarray(got[1])[3] == np.asarray(edges)[0, 0]
+    for i in range(9):
+        np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want[i]), err_msg=str(i))
+
+
+def test_a_narrowed_tree_on_eight_devices_is_the_one_devices():
+    """Rows sharded over eight devices take the one-hot kernel a shard with a
+    psum, on the fit's packed words; the levels of one to four nodes narrow
+    (6 of 37 features a node) through the words' transpose: every heap array
+    is the single device's segment_sum tree's."""
+    from spark_rapids_ml_tpu.parallel.mesh import get_mesh, shard_array
+
+    rng = np.random.default_rng(29)
+    n, d, nbins, depth = 4096, 37, 16, 5
+    Xb = rng.integers(0, nbins, size=(n, d)).astype(np.uint8)
+    y = ((Xb[:, 0] > 5) ^ (Xb[:, 9] > 10) ^ (rng.random(n) < 0.2)).astype(int)
+    vals = np.zeros((n, 2), np.float32)
+    vals[np.arange(n), y] = rng.poisson(1.0, n)
+    edges = jnp.asarray(np.sort(rng.normal(size=(d, nbins - 1)), axis=1).astype(np.float32))
+    kw = dict(max_depth=depth, nbins=nbins, impurity="gini", k_features=6, min_instances=1,
+              min_info_gain=0.0)
+    assert [trees.compact_gate(2**t, d, 6)[0] for t in range(depth)] == [True] * 3 + [False] * 2
+    want = trees.build_tree(jnp.asarray(Xb), jnp.asarray(vals), edges, jax.random.PRNGKey(5),
+                            forms=("xla",) * depth, **kw)
+    mesh = get_mesh()
+    d_pad, _ = trees.feature_plan(d)
+    words = np.asarray(trees._pack_words(jnp.pad(jnp.asarray(Xb), ((0, 0), (0, d_pad - d)))))
+    got = trees.build_tree(shard_array(words, mesh), shard_array(vals, mesh), edges,
+                           jax.random.PRNGKey(5), use_pallas=True, mesh=mesh,
+                           forms=("direct",) * depth, packed=True, **kw)
+    assert mesh.devices.size == 8
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(want[k]), np.asarray(got[k]), err_msg=k)
 
 
 # ------------------------------------------------ (f) the parent's seeded trees
@@ -382,11 +514,28 @@ def _digest(model):
     ("classifier", "58cbf9e1a2364a3b"),
     ("classifier_all_features", "aacab197b3680254"),
     ("regressor", "a4a331367f0fd342"),
+    ("classifier_grouped_d300", "69025c90664a43f9"),
 ])
-def test_seeded_trees_are_the_parents(case, want):
+def test_seeded_trees_are_the_parents(case, want, monkeypatch):
     """Every node on every tree (feature, threshold, value, gain, weight, to the
     bit) as commit 6a31db5 grew it for the same seed: the tiled split search,
-    the device binning and the new routing change no tree."""
+    the device binning and the new routing change no tree. The 300-column case
+    (recorded at commit d610c1f, before a level histogrammed only the columns
+    its nodes drew) takes the grouped kernel, interpreted, at every level, and
+    draws 17 of 300 features a node: four of its seven levels narrow."""
+    if case == "classifier_grouped_d300":
+        monkeypatch.setattr(ph, "_on_tpu", lambda: True)
+        X = np.random.default_rng(7).normal(size=(3000, 300)).astype(np.float32)
+        y = (X[:, 0] * X[:, 1] + X[:, 150] - 0.5 * np.abs(X[:, 299]) > 0.1)
+        est = RandomForestClassifier(numTrees=2, maxDepth=7, maxBins=32, seed=19)
+        est.num_workers = 1
+        model = est.fit(pd.DataFrame({"features": list(X), "label": y.astype(np.float64)}))
+        counters = model.fit_report_["metrics"]["counters"]
+        assert counters["forest.hist_path{path=grouped}"] == 2 * 7
+        assert counters["forest.hist_compact{engaged=true,reason=ok}"] == 2 * 4
+        assert counters["forest.hist_compact{engaged=false,reason=width}"] == 2 * 3
+        assert _digest(model) == want
+        return
     X = np.random.default_rng(7).normal(size=(3000, 12)).astype(np.float32)
     frame = {"features": list(X)}
     if case == "regressor":
@@ -436,7 +585,8 @@ def test_level_step_compiles_for_a_v5e_at_the_published_lanes(form, t, one_chip,
 
     state = (S((slots,), jnp.int32), S((slots,), jnp.float32), S((slots,), jnp.bool_),
              S((slots, s), jnp.float32), S((slots,), jnp.float32), S((slots,), jnp.float32),
-             S((n,), jnp.int32), S((2**t, s), jnp.float32), S((2,), jnp.uint32))
+             S((n,), jnp.int32), S((2**t, s), jnp.float32), S((2,), jnp.uint32),
+             S((), jnp.int32))
     compiled = trees._level_step_jit.lower(
         state, S((n, d_pad // 4), jnp.int32), S((n, s), jnp.float32),
         S((d, nbins - 1), jnp.float32), t=t, nbins=nbins, impurity="gini", k_features=54,

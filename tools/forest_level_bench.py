@@ -9,7 +9,12 @@ rest on. One JSON line a reading; refuses a CPU backend.
     chiprun -- python -m tools.forest_level_bench [rows cols [part ...]]
 
 Defaults: 357376 3000 (the `rf_cls_depth13_d3000` cell's table), 128 bins, two
-classes, 54 features a node; parts `bin levels tree`. The table is unit noise
+classes, 54 features a node; parts `bin levels tree`, and `narrow`: the grouped
+int8 level at widths 1 to 64 as the program runs it (narrowed to the columns
+its nodes drew where `ops/trees.py::compact_gate` says so; `cols` the width
+its tiles read) against the same level whole-width (the gate turned off: the
+program before the narrowing), with both outputs compared; `tree` times a
+whole tree both ways. The table is unit noise
 made on the device from a seed, the label a noisy threshold on two columns.
 A level is timed on a made-up state: every row at a node drawn uniformly, the
 nodes' totals summed from the rows, so every node of the level holds rows;
@@ -48,6 +53,30 @@ def _peak():
     return int((stats or {}).get("peak_bytes_in_use", 0))
 
 
+def _whole_width(fn):
+    """`fn` traced with `compact_gate` saying `width` at every level: a fresh
+    function, so no trace of the program as it is is reused."""
+    def run(*args, **kw):
+        gate = trees.compact_gate
+        trees.compact_gate = lambda *a: (False, "width")
+        try:
+            return fn(*args, **kw)
+        finally:
+            trees.compact_gate = gate
+    return run
+
+
+def _on_tree(tree):
+    """The heap slots on the tree: the root and the children of every split."""
+    feat, leaf = tree["feature"], tree["is_leaf"]
+    on = np.zeros(feat.shape[0], bool)
+    on[1] = True
+    for p in range(1, feat.shape[0] // 2):
+        if on[p] and not leaf[p] and feat[p] >= 0:
+            on[2 * p] = on[2 * p + 1] = True
+    return on
+
+
 def _least(fn, reps=3):
     best = None
     for _ in range(reps):
@@ -65,7 +94,7 @@ def main(argv):
         return 3
     rows = int(argv[0]) if argv else 357376
     cols = int(argv[1]) if len(argv) > 1 else 3000
-    parts = argv[2:] or ["bin", "levels", "tree"]
+    parts = argv[2:] or ["bin", "levels", "narrow", "tree"]
     k_features = max(1, int(np.sqrt(cols)))
     key = jax.random.PRNGKey(38)
     X = jax.random.normal(key, (rows, cols), jnp.float32)
@@ -90,16 +119,47 @@ def main(argv):
     kw = dict(nbins=NBINS, impurity="gini", k_features=k_features, min_instances=1,
               min_info_gain=0.0, mesh=None, packed=True)
 
+    slots = 2 ** (DEPTH + 1)
+
+    def level_state(t):
+        width = 2**t
+        node = jax.random.randint(jax.random.fold_in(key, 100 + t), (rows,), 0, width)
+        T = jax.ops.segment_sum(values, node, num_segments=width)
+        return (jnp.full((slots,), -1, jnp.int32), jnp.zeros((slots,), jnp.float32),
+                jnp.zeros((slots,), bool), jnp.zeros((slots, CLASSES), jnp.float32),
+                jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.float32),
+                node, T, jax.random.PRNGKey(t), jnp.zeros((), jnp.int32))
+
+    if "narrow" in parts:
+        static = ("t", "nbins", "impurity", "k_features", "min_instances", "min_info_gain",
+                  "mesh", "form", "operand", "packed")
+        for t in range(7):
+            width, state = 2**t, level_state(t)
+            engaged = trees.compact_gate(width, cols, k_features)[0]
+            found = {}
+            for variant, fn in (("narrow", lambda *a, **k: trees._level_step(*a, **k)),
+                                ("whole", _whole_width(trees._level_step))):
+                t0 = time.perf_counter()
+                step = jax.jit(fn, static_argnames=static).lower(
+                    state, words, values, edges, t=t, form="grouped", operand="int8",
+                    **kw).compile()
+                compile_s = time.perf_counter() - t0
+                seconds, out = _least(lambda: step(state, words, values, edges))
+                found[variant] = [np.asarray(x) for x in out[:9]]
+                _say(part="narrow", t=t, width=width, variant=variant,
+                     cols=trees.feature_plan(width * k_features)[0]
+                     if engaged and variant == "narrow" else d_pad,
+                     seconds=seconds, compile_s=compile_s,
+                     overflows=int(out[9]), peak_bytes=_peak(),
+                     temp_bytes=int(
+                         step.memory_analysis()  # noqa: fence/device-analysis-off-plane
+                         .temp_size_in_bytes))
+            _say(part="narrow_agree", t=t, agree=bool(all(
+                np.array_equal(a, b) for a, b in zip(found["narrow"], found["whole"]))))
+
     if "levels" in parts:
-        slots = 2 ** (DEPTH + 1)
         for t in sorted({t for levels in LEVELS.values() for t in levels}):
-            width = 2**t
-            node = jax.random.randint(jax.random.fold_in(key, 100 + t), (rows,), 0, width)
-            T = jax.ops.segment_sum(values, node, num_segments=width)
-            state = (jnp.full((slots,), -1, jnp.int32), jnp.zeros((slots,), jnp.float32),
-                     jnp.zeros((slots,), bool), jnp.zeros((slots, CLASSES), jnp.float32),
-                     jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.float32),
-                     node, T, jax.random.PRNGKey(t))
+            width, state = 2**t, level_state(t)
             found = {}
             for (form, operand), levels in LEVELS.items():
                 if t not in levels:
@@ -129,16 +189,28 @@ def main(argv):
         tkw = dict(max_depth=DEPTH, nbins=NBINS, impurity="gini", k_features=k_features,
                    min_instances=1, min_info_gain=0.0, use_pallas=True, mesh=None,
                    forms=forms, operand="int8", packed=True)
-        t0 = time.perf_counter()
-        tree = trees.build_tree(*args, **tkw)
-        jax.block_until_ready(tree)
-        first = time.perf_counter() - t0
-        seconds, tree = _least(lambda: trees.build_tree(*args, **tkw), reps=2)
-        feat = np.asarray(tree["feature"])
-        _say(part="tree", forms=forms, first_call_s=first, seconds=seconds,
-             internal_nodes=int((feat >= 0).sum()),
-             deepest_level_nodes=int((feat[2 ** (DEPTH - 1):2 ** DEPTH] >= 0).sum()),
-             peak_bytes=_peak())
+        grown = {}
+        for variant, fn in (("narrow", lambda *a, **k: trees._build_tree_impl(*a, **k)),
+                            ("whole", _whole_width(trees._build_tree_impl))):
+            build = jax.jit(fn, static_argnames=tuple(tkw))
+            t0 = time.perf_counter()
+            tree = build(*args, **tkw)
+            jax.block_until_ready(tree)
+            first = time.perf_counter() - t0
+            seconds, tree = _least(lambda: build(*args, **tkw), reps=2)
+            feat = np.asarray(tree["feature"])
+            grown[variant] = {k: np.asarray(v) for k, v in tree.items()
+                              if k != "compact_overflows"}
+            _say(part="tree", variant=variant, forms=forms, first_call_s=first,
+                 seconds=seconds, overflows=int(tree["compact_overflows"]),
+                 internal_nodes=int((feat >= 0).sum()),
+                 deepest_level_nodes=int((feat[2 ** (DEPTH - 1):2 ** DEPTH] >= 0).sum()),
+                 peak_bytes=_peak())
+        on = _on_tree(grown["whole"])
+        _say(part="tree_agree", agree={  # every node on the tree, to the bit
+            k: bool(np.array_equal(_on_tree(grown["narrow"]), on)
+                    and np.array_equal(grown["narrow"][k][on], v[on]))
+            for k, v in grown["whole"].items()})
     return 0
 
 
