@@ -554,7 +554,7 @@ def check_lloyd(unit_mask: bool, n: int = 262_181, d: int = 128, k: int = 128,
                 gate: bool = True) -> str:
     import jax.numpy as jnp
 
-    from spark_rapids_ml_tpu.autotune.defaults import LLOYD_FUSED_MIN_K
+    from spark_rapids_ml_tpu.defaults import LLOYD_FUSED_MIN_K
     from spark_rapids_ml_tpu.ops._precision import parity_precision
     from spark_rapids_ml_tpu.ops.kmeans import lloyd_fit
     from spark_rapids_ml_tpu.ops.pallas_kmeans import (

@@ -956,69 +956,6 @@ print("PALLAS SELECT SMOKE OK: fused scan bit-identical over the 8-device "
 PY
 rm -rf "$SRML_PALLAS_SMOKE_DIR"
 
-# autotune smoke (perf tier, docs/design.md §6i): the offline CLI searches
-# two selection knobs on the 8-device CPU mesh and must persist a versioned
-# tuning table; then a FRESH process in the default `load` mode must resolve
-# from that table with ZERO searches and — in steady state — ZERO extra
-# compiles, asserted from the exported JSONL run report's counters (and its
-# new `autotune` section), read back like a dashboard would. Tuned outputs
-# are asserted bit-identical to the default path (the §6i exactness
-# contract for bit-class knobs).
-SRML_AUTOTUNE_SMOKE_DIR="$(mktemp -d)"
-SRML_TPU_TUNE_DIR="$SRML_AUTOTUNE_SMOKE_DIR/tables" \
-python -m spark_rapids_ml_tpu.autotune \
-  --knobs selection.strategy,selection.tile --shape 20000,24,10 --replicates 3
-SRML_TPU_TUNE_DIR="$SRML_AUTOTUNE_SMOKE_DIR/tables" \
-SRML_TPU_METRICS_DIR="$SRML_AUTOTUNE_SMOKE_DIR/metrics" python - <<'PY'
-import glob, json, os
-import numpy as np, jax.numpy as jnp
-from spark_rapids_ml_tpu import config
-from spark_rapids_ml_tpu.observability import fit_run, load_run_reports
-from spark_rapids_ml_tpu.ops.knn import exact_knn_single
-
-tables = glob.glob(os.path.join(os.environ["SRML_TPU_TUNE_DIR"], "tuning_*.json"))
-assert tables, "autotune CLI wrote no tuning table"
-doc = json.load(open(tables[0]))
-assert doc["version"] == 1 and doc["entries"], doc
-knobs = sorted({e["knob"] for e in doc["entries"].values()})
-assert knobs == ["selection.strategy", "selection.tile"], knobs
-assert all("provenance" in e and e["speedup"] >= 1.0
-           for e in doc["entries"].values()), doc["entries"]
-
-rng = np.random.default_rng(0)
-X = jnp.asarray(rng.normal(size=(20000, 24)).astype(np.float32))
-Q, ones = X[:64], jnp.ones((20000,), bool)
-# default-path reference (table ignored) for the bit-parity check
-config.set("autotune.mode", "off")
-d_ref, i_ref = [np.asarray(a) for a in exact_knn_single(Q, X, ones, 10)]
-config.unset("autotune.mode")
-# warm pass in load mode: compiles whatever signature the tuned path picked
-with fit_run(algo="AutotuneSmokeWarm", site="ci"):
-    exact_knn_single(Q, X, ones, 10)
-# steady state: table hits, zero searches, zero extra compiles
-with fit_run(algo="AutotuneSmoke", site="ci"):
-    d_t, i_t = [np.asarray(a) for a in exact_knn_single(Q, X, ones, 10)]
-np.testing.assert_array_equal(i_t, i_ref)
-np.testing.assert_array_equal(d_t, d_ref)
-rep = load_run_reports(os.environ["SRML_TPU_METRICS_DIR"])[-1]
-assert rep["algo"] == "AutotuneSmoke", rep["algo"]
-c = rep["metrics"]["counters"]
-hits = sum(v for k, v in c.items() if k.startswith("autotune.table_hit"))
-searches = sum(v for k, v in c.items() if k.startswith("autotune.searches"))
-compiles = sum(v for k, v in c.items() if k.startswith("device.compile{"))
-assert hits > 0, c
-assert searches == 0, c
-assert compiles == 0, c
-at = rep.get("autotune") or {}
-assert at["mode"] == "load" and at["table_version"] == 1, at
-assert at["table_status"] == "loaded" and at["searches"] == 0, at
-assert any(v.get("source") == "table" for v in at["knobs"].values()), at
-print("AUTOTUNE SMOKE OK: table persisted+reloaded; steady-state load run: "
-      f"{hits} table hits, 0 searches, 0 extra compiles; tuned == default "
-      "bit-for-bit")
-PY
-rm -rf "$SRML_AUTOTUNE_SMOKE_DIR"
-
 # JVM half: attempt compile+test where a Scala toolchain exists; always record
 # the outcome (ci/jvm_build_status.json) — reference CI runs run_plugin_test.sh
 # unconditionally (ci/test.sh:46-47)

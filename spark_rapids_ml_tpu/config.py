@@ -183,7 +183,7 @@ _DEFAULTS: Dict[str, Any] = {
     "serving.request_timeout_s": 30.0,
     # fault-tolerant serving fleet (serving/fleet.py + serving/router.py,
     # docs/design.md §7c). replicas: dispatcher replicas per served model
-    # (0 = auto: tuning table, else 1 — the single-dispatcher plane);
+    # (0 = auto: 1 — the single-dispatcher plane);
     # heartbeat_timeout_s: how long a replica may go without a dispatcher
     # heartbeat before the health monitor marks it DEAD and replays its queue
     # onto survivors; hedge_after_p99_frac: issue a duplicate of a still-
@@ -194,17 +194,18 @@ _DEFAULTS: Dict[str, Any] = {
     "serving.heartbeat_timeout_s": 2.0,
     # ANN index lifecycle (ops/ann_streaming.py + ops/ann_lifecycle.py,
     # docs/design.md §7b). build_batch_rows: row-batch geometry of the
-    # pipelined out-of-core builds; 0 = auto (tuning table, else
-    # stream_batch_rows). prefetch_depth: staged batches kept in flight so
-    # host staging of batch i+1 overlaps device execution of batch i; 0 runs
-    # the serial (pre-pipeline) loop — the bench baseline mode
+    # pipelined out-of-core builds; 0 = auto (an explicitly set
+    # stream_batch_rows, else defaults.py). prefetch_depth: staged batches
+    # kept in flight so host staging of batch i+1 overlaps device execution
+    # of batch i; 0 runs the serial (pre-pipeline) loop — the bench baseline
+    # mode
     "ann.build_batch_rows": 0,
     "ann.prefetch_depth": 1,
     # incremental maintenance: IVF list capacity rounds UP to a power-of-two
     # bucket >= list_bucket_rows so in-slack adds never change the search
-    # executable's shapes (0 = auto: tuning table, else the defaults-module
-    # floor); compaction re-layouts the lists once tombstoned slots exceed
-    # this percentage of occupied slots
+    # executable's shapes (0 = auto: the defaults-module floor); compaction
+    # re-layouts the lists once tombstoned slots exceed this percentage of
+    # occupied slots
     "ann.list_bucket_rows": 0,
     "ann.compact_tombstone_pct": 30,
     # lazy device residency of loaded/served indexes (ops/ann_lifecycle.py::
@@ -217,7 +218,7 @@ _DEFAULTS: Dict[str, Any] = {
     # every batch slice staged through np.ascontiguousarray, the pre-§6k path
     "ingest.zero_copy": True,
     # staging-buffer pool geometry (rows per pooled buffer) for the counted
-    # copy fallback; 0 = auto (tuning table, else autotune/defaults.py).
+    # copy fallback; 0 = auto (defaults.py).
     # Buffer REUSE engages only on backends whose device_put copies (TPU/GPU);
     # CPU jax aliases host memory, so reuse there would corrupt cached batches
     "ingest.staging_pool_rows": 0,
@@ -227,24 +228,22 @@ _DEFAULTS: Dict[str, Any] = {
     # Bit-parity with the staged path is the contract; off = staged fits
     "pipeline.fuse": True,
     # rows below which fusion is skipped (staged fit overhead is negligible
-    # and the staged trace is simpler to debug); 0 = auto (tuning table, else
-    # autotune/defaults.py)
+    # and the staged trace is simpler to debug); 0 = auto (defaults.py)
     "pipeline.fuse_min_rows": 0,
     # partitioner plane (parallel/partitioner.py, docs/design.md §10): the
     # single owner of mesh + shardings. feature_axis: width of the 2-D
     # SPMDPartitioner's feature axis (wide-k kNN / feature-sharded
-    # covariance); 0 = auto (tuning table per (n, d) bucket, else 1 = pure
-    # data-parallel). batch_rows_per_process: LOCAL rows each process stages
-    # per streamed batch on multi-host runs; 0 = auto (tuning table, else
-    # stream_batch_rows split evenly across the pod). Both resolve at host
-    # resolution points only — never inside a trace
+    # covariance); 0 = auto (1 = pure data-parallel). batch_rows_per_process:
+    # LOCAL rows each process stages per streamed batch on multi-host runs;
+    # 0 = auto (stream_batch_rows split evenly across the pod). Both resolve
+    # at host resolution points only — never inside a trace
     "partition.feature_axis": 0,
     "partition.batch_rows_per_process": 0,
     # continuous-learning plane (spark_rapids_ml_tpu/continual/, docs/
     # design.md §7d): streamed partial_fit + drift detection + governed
     # promotion. decay: per-update discount on the persistent sufficient-
     # statistics carry (1.0 = infinite memory, the 1505.06807 a=1 default;
-    # 0.0 = auto: tuning table, else autotune/defaults.py). update_batch_rows:
+    # 0.0 = auto: defaults.py). update_batch_rows:
     # fixed block geometry of partial_fit ingest — every update batch is
     # re-blocked to this row count (zero-weight padding) so a steady update
     # stream re-enters ONE compiled executable per kernel (0 = auto).
@@ -269,27 +268,6 @@ _DEFAULTS: Dict[str, Any] = {
     "tracing.sample_rate": 1.0,
     "tracing.ring_traces": 256,
     "tracing.slow_frac": 0.05,
-    # closed-loop autotuner (spark_rapids_ml_tpu/autotune/, docs/design.md
-    # §6i): telemetry-driven knob search persisted as per-platform tuning
-    # tables. mode:
-    #   off    never consult tables (every knob resolves to its built-in
-    #          default unless config pins it)
-    #   load   (default) consult the tuning table at the host-wrapper
-    #          resolution points; misses fall through to defaults
-    #   search on first sight of an uncovered (knob, shape-bucket) at a
-    #          searchable knob, run the measurement loop, persist the winner,
-    #          and use it — the opt-in online mode
-    "autotune.mode": "load",
-    # tuning-table directory (versioned tuning_<platform>_<device_kind>.json
-    # files, atomic writes). None = in-memory tables only: lookups/searches
-    # work for the life of the process but nothing persists
-    "autotune.dir": None,
-    # measurement-loop replication: timed reps per candidate (round-robin
-    # across candidates so warming drift cannot favor late candidates), and
-    # how many MADs of separation a challenger needs to displace the default
-    # (judging two noise samples against each other is not a win)
-    "autotune.replicates": 5,
-    "autotune.noise_mads": 3.0,
 }
 
 _ENV_KEYS: Dict[str, str] = {
@@ -367,10 +345,6 @@ _ENV_KEYS: Dict[str, str] = {
     "tracing.sample_rate": "SRML_TPU_TRACING_SAMPLE_RATE",
     "tracing.ring_traces": "SRML_TPU_TRACING_RING_TRACES",
     "tracing.slow_frac": "SRML_TPU_TRACING_SLOW_FRAC",
-    "autotune.mode": "SRML_TPU_AUTOTUNE_MODE",
-    "autotune.dir": "SRML_TPU_TUNE_DIR",
-    "autotune.replicates": "SRML_TPU_AUTOTUNE_REPLICATES",
-    "autotune.noise_mads": "SRML_TPU_AUTOTUNE_NOISE_MADS",
 }
 
 _overrides: Dict[str, Any] = {}
@@ -401,9 +375,8 @@ def get(key: str) -> Any:
 
 def source(key: str) -> str:
     """Where `get(key)` currently resolves from: 'set' (programmatic
-    override), 'env', or 'default'. The autotuner's tuning tables slot in
-    BETWEEN env and default (docs/design.md §6i): a knob's table entry is
-    consulted only when this returns 'default' — set() and env always win."""
+    override), 'env', or 'default'. A call site whose default lives in
+    defaults.py takes it only when this returns 'default'."""
     if key not in _DEFAULTS:
         raise KeyError(f"Unknown config key '{key}'; known: {sorted(_DEFAULTS)}")
     if key in _overrides:

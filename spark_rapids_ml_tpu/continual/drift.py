@@ -3,8 +3,8 @@
 # per-row signal (inertia / loss / residual) the fit-time distribution's
 # noise, or a new distribution?
 #
-# The judgment is the one `autotune.noise_mads` makes: a robust location (median) plus a MAD noise floor,
-# and a challenger only counts as DIFFERENT beyond `continual.drift_mads`
+# The judgment is a robust location (median) plus a MAD noise floor, and a
+# challenger only counts as DIFFERENT beyond `continual.drift_mads`
 # MADs of separation. The baseline seeds from the fit-time convergence tail
 # when a fit report is available (`baseline_from_convergence`); otherwise the
 # detector self-calibrates on the first `continual.min_baseline` observations
@@ -36,17 +36,13 @@ _ABS_FLOOR = 1e-12
 
 
 def resolve_drift_mads() -> float:
-    """`continual.drift_mads` resolution: config pin, then tuning table, then
-    the defaults-module constant (CONTINUAL_DRIFT_MADS, 3.0)."""
-    from .. import autotune as _autotune
-    from ..autotune.defaults import CONTINUAL_DRIFT_MADS
+    """`continual.drift_mads` resolution: config pin, then the
+    defaults-module constant (CONTINUAL_DRIFT_MADS, 3.0)."""
+    from ..defaults import CONTINUAL_DRIFT_MADS
 
     pinned = float(_config.get("continual.drift_mads") or 0.0)
     if pinned > 0.0:
         return pinned
-    tuned = _autotune.lookup("continual.drift_mads")
-    if tuned:
-        return float(tuned)
     return float(CONTINUAL_DRIFT_MADS)
 
 

@@ -49,37 +49,28 @@ from ..reliability.checkpoint import copy_carry
 _EPS_COUNT = 1e-12
 
 
-# ------------------------------------------------------------ knob resolution
+# ------------------------------------------------------------ config resolution
 
 
 def resolve_decay() -> float:
     """`continual.decay` resolution: a non-auto config pin wins, then the
-    tuning table, then the defaults-module constant (1.0 — forgetting is
-    opt-in)."""
-    from .. import autotune as _autotune
-    from ..autotune.defaults import CONTINUAL_DECAY
+    defaults-module constant (1.0 — forgetting is opt-in)."""
+    from ..defaults import CONTINUAL_DECAY
 
     pinned = float(_config.get("continual.decay") or 0.0)
     if pinned > 0.0:
         return pinned
-    tuned = _autotune.lookup("continual.decay")
-    if tuned:
-        return float(tuned)
     return float(CONTINUAL_DECAY)
 
 
-def resolve_update_batch_rows(n: int, d: int) -> int:
-    """`continual.update_batch_rows` resolution: config pin, then tuning table
-    per (n, d) bucket, then the defaults-module fixed block geometry."""
-    from .. import autotune as _autotune
-    from ..autotune.defaults import CONTINUAL_UPDATE_BATCH_ROWS
+def resolve_update_batch_rows() -> int:
+    """`continual.update_batch_rows` resolution: config pin, then the
+    defaults-module fixed block geometry."""
+    from ..defaults import CONTINUAL_UPDATE_BATCH_ROWS
 
     pinned = int(_config.get("continual.update_batch_rows") or 0)
     if pinned > 0:
         return pinned
-    tuned = _autotune.lookup("continual.update_batch_rows", n=n, d=d)
-    if tuned:
-        return int(tuned)
     return int(CONTINUAL_UPDATE_BATCH_ROWS)
 
 
@@ -240,9 +231,9 @@ class PartialFitUpdater:
         self.rows = int(snap["rows"])
 
     # -- the update fold --------------------------------------------------
-    def update_batch_rows(self, n: int, d: int) -> int:
+    def update_batch_rows(self) -> int:
         if self._ubr is None:
-            self._ubr = resolve_update_batch_rows(n, d)
+            self._ubr = resolve_update_batch_rows()
         return self._ubr
 
     def _fold(self, carry, accum, X, y, w, block_rows):
@@ -260,7 +251,7 @@ class PartialFitUpdater:
         signal (the drift detector's observation)."""
         X = np.asarray(X)
         n = int(X.shape[0])
-        block_rows = self.update_batch_rows(n, X.shape[1])
+        block_rows = self.update_batch_rows()
         with obs_span("continual.update",
                       {"model": self.name, "rows": n}):
             if self.decay != 1.0:
@@ -349,7 +340,7 @@ class KMeansUpdater(PartialFitUpdater):
         carry = self._fold(
             carry,
             lambda c, b: _accum_kmeans(c, centers, b[0], b[1]),
-            np.asarray(X), None, w, self.update_batch_rows(X.shape[0], d),
+            np.asarray(X), None, w, self.update_batch_rows(),
         )
         return float(carry[2]) / max(_wsum(X, w), _EPS_COUNT)
 
@@ -434,7 +425,7 @@ class LinearRegressionUpdater(PartialFitUpdater):
             lambda c, b: _accum_resid_linear(c, b[0], b[1], b[2], coef,
                                              intercept),
             np.asarray(X), np.asarray(y), w,
-            self.update_batch_rows(X.shape[0], X.shape[1]),
+            self.update_batch_rows(),
         )
         return float(carry[0]) / max(_wsum(X, w), _EPS_COUNT)
 
@@ -568,7 +559,7 @@ class LogisticRegressionUpdater(PartialFitUpdater):
 
         carry = self._fold(
             carry, accum, np.asarray(X), np.asarray(y), w,
-            self.update_batch_rows(X.shape[0], X.shape[1]),
+            self.update_batch_rows(),
         )
         reg_l1 = float(self._reg) * float(self._l1r)
         reg_l2 = float(self._reg) * (1.0 - float(self._l1r))
@@ -633,7 +624,7 @@ class PCAUpdater(PartialFitUpdater):
             carry,
             lambda c, b: _accum_resid_pca(c, b[0], b[1], comps, mean),
             np.asarray(X), None, w,
-            self.update_batch_rows(X.shape[0], X.shape[1]),
+            self.update_batch_rows(),
         )
         return float(carry[0]) / max(_wsum(X, w), _EPS_COUNT)
 

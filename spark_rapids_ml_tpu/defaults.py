@@ -1,19 +1,13 @@
 #
-# Knob-registry defaults — THE home for the numeric tile/block/threshold
-# defaults the closed-loop autotuner (docs/design.md §6i) overrides with
-# measured per-platform tuning-table entries.
+# The one home of the numeric tile and threshold defaults, set from the
+# `tools/` probes. Each constant carries the measurement that set it; to
+# re-tune for a chip, run the probe under `tools/` and edit the value here.
+# The analyzer's fence/hardcoded-tunable rule keeps new numeric literals out
+# of ops/, so the split cannot silently regress.
 #
-# These used to live as magic constants scattered through the ops/ host
-# wrappers, each justified by a one-off measurement baked into a comment.
-# Now: the DEFAULT lives here (one module, import-light, no jax), the
-# MEASURED choice lives in a tuning table entry whose `provenance` field
-# records the search that produced it (platform, device_kind, shape bucket,
-# trial stats), and the analyzer's fence/hardcoded-tunable rule bans new
-# literals in ops/ so the split cannot silently regress.
-#
-# Nothing here reads config or the tables — that is knobs.lookup()'s job.
-# Callers fall through to these values when autotune is off, the table has
-# no entry for the bucket, or the table failed to load (corrupt/stale).
+# Nothing here reads config: a call site that has a config key reads it
+# first and falls back to these values when the key is unset or holds its
+# "choose for me" sentinel (auto / 0).
 #
 
 from __future__ import annotations
@@ -21,15 +15,14 @@ from __future__ import annotations
 # --------------------------------------------------------- selection plane
 # exact_tiled tile width (ops/selection.py::_auto_tile): on TPU small fixed
 # tiles vectorize the per-tile select on the VPU; on CPU each XLA TopK custom
-# call pays per-call overhead, so few large tiles win (see the tuning table
-# for any measured per-bucket override of this folklore).
+# call pays per-call overhead, so few large tiles win.
 TPU_SELECT_TILE = 2048
 CPU_SELECT_TILE_FLOOR = 8192
 CPU_SELECT_TILE_DENOM = 4  # CPU tile = max(floor, ceil(n / denom))
 
 
 def default_select_tile(n: int, backend: str) -> int:
-    """The pre-autotuner platform tile heuristic, verbatim."""
+    """The platform tile heuristic."""
     if backend == "tpu":
         return TPU_SELECT_TILE
     return max(CPU_SELECT_TILE_FLOOR, -(-int(n) // CPU_SELECT_TILE_DENOM))
@@ -57,7 +50,7 @@ LLOYD_FUSED_MIN_K = 128
 # The XLA Lloyd program (ops/kmeans.py::lloyd_fit) ranks its assignment at
 # three MXU passes, with a six-pass second look at the rows three passes
 # cannot rank, from LLOYD_FUSED_MIN_K centres on where centres x columns
-# reaches this. NOT a tunable, no knob reads it. What the mechanism saves grows
+# reaches this. No config key reads it. What the mechanism saves grows
 # with k.d (three passes of 2.k.d FLOP a row); what it adds does not (a sort
 # of the rows, a gather, a wider reduction): on a v5e it is 2 to 3 times
 # SLOWER at k.d of 2,560 to 32,768, 1.10 to 1.19 at 60,000 to 384,000
@@ -77,7 +70,7 @@ LLOYD_RECHECK_SHARE = 16
 # cuts the columns into blocks of GRAM_BLOCK_COLS from GRAM_TRIANGLE_MIN_COLS
 # columns on, multiplies block I against the columns from I's first to the
 # last only (the block pairs I <= J of the symmetric matrix), and mirrors the
-# result once. NOT tunables, no knob reads them. Seconds a 4,096-row part as a
+# result once. No config key reads them. Seconds a 4,096-row part as a
 # share of the single matmul's, one v5e, six passes, by columns
 # (tools/gram_triangle_bench.py; PERF.md §6, PR 33):
 #
@@ -96,7 +89,7 @@ LLOYD_RECHECK_SHARE = 16
 GRAM_TRIANGLE_MIN_COLS = 576
 GRAM_BLOCK_COLS = 384
 
-# Rows per centre (ops/kmeans.py::assign_counts). NOT tunables, no knob reads
+# Rows per centre (ops/kmeans.py::assign_counts). No config key reads
 # them. Up to COUNT_DEVICE_MAX_CENTERS centres the device compares every row
 # with every centre id and sums the hits in one fusion: work of n x centres,
 # 1.5 ms at 81 centres and 75 ms at 8,193 for 8.4M rows on a v5e, where
@@ -112,7 +105,7 @@ COUNT_BLOCK_SPLIT = 64
 
 # The quasi-Newton LogisticRegression fit's one-read evaluation
 # (ops/pallas_logistic.py): samples of one feature-major block (d, blk) of the
-# table. NOT tunables, no knob reads them. A block holds at most
+# table. No config key reads them. A block holds at most
 # LOGISTIC_EVAL_BLOCK_BYTES of X (the pipeline holds two: the call raises its
 # scoped-VMEM limit past the 16 MiB default, v5e has 128 MiB), a power of two
 # of samples between LOGISTIC_EVAL_MIN_BLOCK_ROWS (a width whose 256-sample
@@ -163,7 +156,7 @@ PALLAS_HISTOGRAM_WORDS_PER_STEP = 8
 FOREST_HIST_TILE_BYTES = 256 * 1024 * 1024
 # columns of a forest's 200,000-row edge sample gathered and sorted at once on
 # the device (ops/trees.py::forest_edges); the block's temporaries are about
-# 2.9 x its 200,000 x cols x 4 bytes. NOT a tunable, no knob reads it: the sort
+# 2.9 x its 200,000 x cols x 4 bytes. No config key reads it: the sort
 # sets the pace at any width. Seconds for all 3000 columns of the sample of
 # 357,376 rows on one v5e, dispatch to the order statistics on the host
 # (tools/forest_edges_probe.py; PERF.md §6, PR 40), where the host's sorted
@@ -182,7 +175,7 @@ FOREST_EDGE_BLOCK_COLS = 512
 # (ops/ann_streaming.py + ops/ann_lifecycle.py, docs/design.md §7b)
 #
 # ANN_BUILD_BATCH_ROWS: the pipelined build's row-batch geometry when neither
-# config (`ann.build_batch_rows`) nor a tuning-table entry decides. Provenance:
+# config (`ann.build_batch_rows`) decides. Provenance:
 # 64k f32 rows at the BASELINE 256-col shape is a 64 MiB staging buffer — two
 # in flight (prefetch depth 1) stay far under the 2 GiB default cache budget
 # while each batch still amortizes dispatch overhead; the streamed-fit default
@@ -235,10 +228,9 @@ CONTINUAL_DECAY = 1.0
 # accumulator kernel.
 CONTINUAL_UPDATE_BATCH_ROWS = 1 << 14
 # CONTINUAL_DRIFT_MADS: MADs of separation above the baseline median a fresh
-# per-row signal needs to fire drift. Provenance: the same separation
-# `autotune.noise_mads` (3.0) demands before calling two samples different;
-# drift is the same judgment (is this batch's loss a new distribution or
-# the old one's noise?).
+# per-row signal needs to fire drift. Provenance: the usual 3-MAD separation
+# before calling two samples different; drift is that judgment (is this
+# batch's loss a new distribution or the old one's noise?).
 CONTINUAL_DRIFT_MADS = 3.0
 
 # ------------------------------------------------------------- trace plane
@@ -250,7 +242,6 @@ CONTINUAL_DRIFT_MADS = 3.0
 # is already bounded (tracing.ring_traces docs) and a finished trace document
 # costs ~1-2 KiB to assemble, so at bench-measured request rates keeping
 # everything sits inside the <2% tracing_overhead budget the CI gate
-# enforces; the 0.05/0.25 grid points exist for high-QPS deployments where
-# the tuning table can dial retention down once the bench shows the document
-# build on the scatter path matters.
+# enforces; `tracing.sample_rate` dials retention down for high-QPS
+# deployments where the document build on the scatter path matters.
 TRACING_SAMPLE_RATE = 1.0

@@ -685,8 +685,8 @@ class ApproximateNearestNeighbors(_ApproxNNClass, _TpuEstimator, _NNParams):
         nlist = int(_ap(algo_params, "nlist", "n_lists", default=64))
         seed = int(algo_params.get("seed", 42))
         # batch geometry is a lifecycle knob (`ann.build_batch_rows`, §7b):
-        # config pin > tuning table > stream_batch_rows
-        batch_rows = resolve_build_batch_rows(fd.n_rows, fd.n_cols)
+        # config pin > an explicit stream_batch_rows > defaults.py
+        batch_rows = resolve_build_batch_rows()
         X = np.asarray(_densify(fd.features, self._float32_inputs))
         if algo == "cagra":
             # the BUILD streams, but cagra_search walks the graph with random

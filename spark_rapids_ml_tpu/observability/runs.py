@@ -836,20 +836,9 @@ class FitRun:
             orphans = self._orphan_snapshots
             have_workers = bool(self._workers)
         device_section = _device().device_report_section(self.registry)
-        # autotune section (docs/design.md §6i): the resolved knob values,
-        # table identity/version, and this run's table hit/miss/search counts
-        # — the join key between a perf regression and the knob choice that
-        # caused it. Best-effort: a tuner failure must never fail a report.
-        autotune_section = None
-        try:
-            from .. import autotune as _autotune
-
-            autotune_section = _autotune.report_section(self.registry)
-        except Exception as e:
-            _logger.warning("autotune report section failed: %s", e)
         # ingest section (docs/design.md §6k/§6f): this run's zero-copy vs
         # copied staging byte split and the before/after bytes-per-row cost
-        # analysis. Best-effort, like the autotune section.
+        # analysis. Best-effort: a section failure must never fail a report.
         ingest_section = None
         try:
             from ..ops import ingest as _ingest
@@ -868,7 +857,6 @@ class FitRun:
         have_ranks = bool(ranks_section and ranks_section.get("ranks"))
         return {
             **({"device": device_section} if device_section else {}),
-            **({"autotune": autotune_section} if autotune_section else {}),
             **({"ingest": ingest_section} if ingest_section else {}),
             **({"ranks": ranks_section} if have_ranks else {}),
             "schema": 1,

@@ -131,13 +131,10 @@ def _enabled() -> bool:
 
 
 def sample_rate() -> float:
-    """`tracing.sample_rate` resolution (the §6i knob order): config pin
-    (set()/env) wins, then the tuning table, then the defaults-module
-    constant. The resolved rate is cached against config.epoch() — the
-    table path costs ~30us per resolution, which at two calls per request
-    (would_keep + finish) would eat the <2% overhead budget on its own. A
-    set()/unset() re-resolves immediately; a mid-process table write shows
-    up after the next config mutation or reset_tracing()."""
+    """`tracing.sample_rate` resolution: config pin (set()/env) wins, then
+    the defaults-module constant. The resolved rate is cached against
+    config.epoch(), since it is asked twice per request (would_keep +
+    finish); a set()/unset() re-resolves immediately."""
     global _rate_cached, _rate_epoch
     ep = _config.epoch()
     if _rate_cached is not None and _rate_epoch == ep:
@@ -145,15 +142,9 @@ def sample_rate() -> float:
     if _config.source("tracing.sample_rate") != "default":
         rate = float(_config.get("tracing.sample_rate"))
     else:
-        try:
-            from .. import autotune as _autotune
-            from ..autotune.defaults import TRACING_SAMPLE_RATE
+        from ..defaults import TRACING_SAMPLE_RATE
 
-            tuned = _autotune.lookup("tracing.sample_rate")
-            rate = float(tuned) if tuned is not None \
-                else float(TRACING_SAMPLE_RATE)
-        except Exception:
-            rate = float(_config.get("tracing.sample_rate"))
+        rate = float(TRACING_SAMPLE_RATE)
     _rate_cached, _rate_epoch = rate, ep
     return rate
 

@@ -6,8 +6,8 @@
 # index itself a managed artifact instead of a fit-once in-memory dict:
 #
 #   * ON-DISK FORMAT — a versioned directory of one `.npy` file per index
-#     array plus a `MANIFEST.json` written LAST via tmp + os.replace (the
-#     autotune/table.py atomic-write discipline): the manifest IS the commit
+#     array plus a `MANIFEST.json` written LAST via tmp + os.replace (an
+#     atomic write): the manifest IS the commit
 #     point, so a reader never observes a torn index. Arrays load back as
 #     copy-on-write memmaps (`np.load(mmap_mode="c")`): load() touches no
 #     array bytes — pages fault in as searches (or mutations) reach them.
@@ -62,8 +62,7 @@ MANIFEST_NAME = "MANIFEST.json"
 
 
 def _atomic_write(path: str, data: bytes) -> None:
-    """tmp + os.replace — the autotune/table.py discipline; a reader never
-    sees a torn file."""
+    """tmp + os.replace; a reader never sees a torn file."""
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path) or ".", suffix=".tmp")
     try:
@@ -228,32 +227,25 @@ class DeviceIndexCache:
 
 
 def resolve_list_bucket_rows() -> int:
-    """`ann.list_bucket_rows` resolution: non-zero config pin > tuning table >
+    """`ann.list_bucket_rows` resolution: non-zero config pin >
     defaults-module floor."""
-    from .. import autotune as _autotune
     from .. import config as _config
-    from ..autotune.defaults import ANN_LIST_BUCKET_MIN_ROWS
+    from ..defaults import ANN_LIST_BUCKET_MIN_ROWS
 
     pinned = int(_config.get("ann.list_bucket_rows") or 0)
     if pinned > 0:
         return pinned
-    tuned = _autotune.lookup("ann.list_bucket_rows")
-    if tuned:
-        return int(tuned)
     return int(ANN_LIST_BUCKET_MIN_ROWS)
 
 
 def resolve_compact_tombstone_pct() -> int:
-    """`ann.compact_tombstone_pct` resolution (config pin > table > default)."""
-    from .. import autotune as _autotune
+    """`ann.compact_tombstone_pct` resolution (config pin > default)."""
     from .. import config as _config
-    from ..autotune.defaults import ANN_COMPACT_TOMBSTONE_PCT
+    from ..defaults import ANN_COMPACT_TOMBSTONE_PCT
 
-    src_default = ANN_COMPACT_TOMBSTONE_PCT
     if _config.source("ann.compact_tombstone_pct") != "default":
         return int(_config.get("ann.compact_tombstone_pct"))
-    tuned = _autotune.lookup("ann.compact_tombstone_pct")
-    return int(tuned) if tuned else int(src_default)
+    return int(ANN_COMPACT_TOMBSTONE_PCT)
 
 
 def bucket_capacity(rows: int, min_rows: Optional[int] = None) -> int:

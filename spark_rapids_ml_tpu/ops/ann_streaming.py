@@ -70,23 +70,19 @@ def _strided_sample_indices(n: int, sample_rows: int) -> np.ndarray:
     return (np.arange(m, dtype=np.int64) * int(n)) // m
 
 
-def resolve_build_batch_rows(n: int, d: int) -> int:
+def resolve_build_batch_rows() -> int:
     """`ann.build_batch_rows` resolution for the pipelined builds: a non-zero
-    config pin wins, then the tuning table (per (n, d) shape bucket), then an
-    EXPLICITLY-configured `stream_batch_rows` (a deployment that sized batches
-    for its streamed fits keeps that geometry), then the defaults-module build
+    config pin wins, then an EXPLICITLY-configured `stream_batch_rows` (a
+    deployment that sized batches for its streamed fits keeps that
+    geometry), then the defaults-module build
     geometry (ANN_BUILD_BATCH_ROWS — two 64k-row staging buffers in flight,
     not the 1M-row streamed-fit default)."""
-    from .. import autotune as _autotune
     from .. import config as _config
-    from ..autotune.defaults import ANN_BUILD_BATCH_ROWS
+    from ..defaults import ANN_BUILD_BATCH_ROWS
 
     pinned = int(_config.get("ann.build_batch_rows") or 0)
     if pinned > 0:
         return pinned
-    tuned = _autotune.lookup("ann.build_batch_rows", n=n, d=d)
-    if tuned:
-        return int(tuned)
     if _config.source("stream_batch_rows") != "default":
         return int(_config.get("stream_batch_rows"))
     return int(ANN_BUILD_BATCH_ROWS)

@@ -284,16 +284,8 @@ def batch_cache() -> Iterator[Optional[DeviceBatchCache]]:
     if not bool(_config.get("cache.enabled")):
         yield None
         return
-    # the byte budget (the cache-head/stream-tail prefix split) is a tuning-
-    # table knob (`cache.budget_bytes`, docs/design.md §6i); config set()/env
-    # on cache.hbm_budget_bytes still win, per the resolution-order contract
-    from .. import autotune as _autotune
-
-    tuned = _autotune.lookup("cache.budget_bytes")
-    budget = (
-        int(tuned) if tuned is not None
-        else int(_config.get("cache.hbm_budget_bytes") or 0)
-    )
+    # the byte budget is the cache-head/stream-tail prefix split
+    budget = int(_config.get("cache.hbm_budget_bytes") or 0)
     if budget <= 0:
         yield None
         return

@@ -67,20 +67,15 @@ def _device_put_copies() -> bool:
     return _device_put_copies_cache
 
 
-def resolve_staging_pool_rows(n: Optional[int] = None,
-                              d: Optional[int] = None) -> int:
+def resolve_staging_pool_rows() -> int:
     """`ingest.staging_pool_rows` resolution (host-side only, so cached traces
-    never bake a stale choice): a non-zero config pin wins, then the tuning
-    table (per (n, d) shape bucket), then the defaults-module geometry."""
-    from .. import autotune as _autotune
-    from ..autotune.defaults import INGEST_STAGING_POOL_ROWS
+    never bake a stale choice): a non-zero config pin wins, then the
+    defaults-module geometry."""
+    from ..defaults import INGEST_STAGING_POOL_ROWS
 
     pinned = int(_config.get("ingest.staging_pool_rows") or 0)
     if pinned > 0:
         return pinned
-    tuned = _autotune.lookup("ingest.staging_pool_rows", n=n, d=d)
-    if tuned:
-        return int(tuned)
     return int(INGEST_STAGING_POOL_ROWS)
 
 

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..autotune.defaults import (
+from ..defaults import (
     COUNT_BLOCK_SPLIT,
     COUNT_DEVICE_MAX_CENTERS,
     LLOYD_ASSIGN3_MIN_WORK,
@@ -754,8 +754,6 @@ def _lloyd(
     # `kmeans.lloyd_path{path=}` counts which path actually ran, and
     # `kmeans.lloyd_gate{fused=0|1,reason=}` which test decided it:
     # cosine | backend | small_k | vmem under "auto", else forced.
-    from ..autotune.defaults import LLOYD_FUSED_MIN_K as _FUSED_MIN_K
-
     _pallas_env = __import__("os").environ.get("SRML_TPU_PALLAS_KMEANS", "auto")
     if _pallas_env == "auto":
         # upper bound on the auto gate: the kernel module's own VMEM
@@ -770,27 +768,13 @@ def _lloyd(
             1 if bool(_config.get("fast_math"))
             else _N_SPLIT[parity_precision()]
         )
-        # the k-threshold of the auto gate is a tuning-table knob
-        # (`lloyd.fused_min_k`, docs/design.md §6i): a platform where the
-        # fused win boundary sits elsewhere ships a table entry instead of a
-        # code change; the default stays the measured v5e boundary. Off-TPU
-        # the gate is closed anyway, so the table is never consulted there.
-        _min_k = _FUSED_MIN_K
-        if jax.default_backend() == "tpu":
-            from .. import autotune as _autotune
-
-            _tuned_min_k = _autotune.lookup(
-                "lloyd.fused_min_k", d=int(X.shape[1])
-            )
-            if _tuned_min_k is not None:
-                _min_k = int(_tuned_min_k)
         # the tests in the order they are asked; the first that fails names
         # the reason, and a fused fit has passed the last (`reason=vmem`)
         if cosine:
             _reason = "cosine"
         elif jax.default_backend() != "tpu":
             _reason = "backend"
-        elif k < _min_k:
+        elif k < LLOYD_FUSED_MIN_K:
             _reason = "small_k"
         else:
             _reason = "vmem"

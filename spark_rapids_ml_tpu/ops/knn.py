@@ -195,7 +195,7 @@ def _exact_knn_resolved(
     """TRACE-PURE core of exact_knn_single: every knob — strategy, tile,
     recall target, fused precision, fused geometry — arrives concrete from a
     host-side resolution (exact_knn_single, or the shard_map factory
-    `_knn_local_then_merge_fn`). No config read, no tuning-table read
+    `_knn_local_then_merge_fn`). No config read
     (tools/analysis purity/*): this is the form traced bodies may call."""
     n = X.shape[0]
     k = min(int(k), n)
@@ -280,8 +280,8 @@ def exact_knn_distributed(
     k_local = min(k_eff, shard_rows)
     # telemetry AND knob resolution fire HERE, on the host: the per-shard
     # scan runs inside the shard_map trace, where counters are suppressed and
-    # config/tuning-table reads are banned (purity/* — a per-rank table read
-    # could trace DIVERGENT programs across pod hosts). The factory receives
+    # config reads are banned (purity/* — a per-rank config read could trace
+    # DIVERGENT programs across pod hosts). The factory receives
     # the fully resolved bundle. (fusable: the per-shard scan holds Q and its
     # X shard, so pallas_fused applies — one single-device pallas_call per
     # shard under shard_map)
@@ -313,7 +313,7 @@ def _knn_local_then_merge_fn(
     factory is the host boundary for the shard body: strategy/tile/recall
     (`resolved`, else resolved here) and — for pallas_fused — precision and
     scan geometry all resolve BEFORE the trace, and the body calls the
-    trace-pure _exact_knn_resolved (purity/*: a config or tuning-table read
+    trace-pure _exact_knn_resolved (purity/*: a config read
     inside shard_map would bake per-host, tracing divergent programs across
     pod ranks)."""
     strategy, tile, rt = (

@@ -19,7 +19,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ..autotune.defaults import GRAM_BLOCK_COLS, GRAM_TRIANGLE_MIN_COLS
+from ..defaults import GRAM_BLOCK_COLS, GRAM_TRIANGLE_MIN_COLS
 from ..observability import counter_inc
 from ..observability.device import compiled_kernel
 from ._precision import pdot

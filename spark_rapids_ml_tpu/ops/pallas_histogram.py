@@ -51,9 +51,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# tile defaults live in the knob-registry defaults module (docs/design.md
-# §6i; the analyzer's fence/hardcoded-tunable rule bans new literals in ops/)
-from ..autotune.defaults import (  # re-exported tile defaults
+# tile defaults live in the defaults module (defaults.py; the analyzer's
+# fence/hardcoded-tunable rule bans new literals in ops/)
+from ..defaults import (  # re-exported tile defaults
     PALLAS_HISTOGRAM_BLOCK_ROWS as BLOCK_ROWS,
     PALLAS_HISTOGRAM_GROUP_BLOCK_ROWS as GROUP_BLOCK_ROWS,
     PALLAS_HISTOGRAM_MAX_SEG_TILE as MAX_SEG_TILE,

@@ -66,7 +66,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..autotune.defaults import (
+from ..defaults import (
     LOGISTIC_EVAL_BLOCK_BYTES,
     LOGISTIC_EVAL_MAX_BLOCK_ROWS,
     LOGISTIC_EVAL_MIN_BLOCK_ROWS,

@@ -73,14 +73,12 @@ from jax.experimental import pallas as pl
 from ..observability.device import compiled_kernel
 from .selection import INVALID_D2
 
-# tile-geometry DEFAULTS live in the knob-registry defaults module
-# (autotune/defaults.py — the analyzer's fence/hardcoded-tunable rule bans new literals
-# in ops/): the query block bounds the (block, tile) distance tile in VMEM
-# (256*1024*4 = 1 MiB) next to one double-buffered X tile (1024*d*4). The
-# tuning table (docs/design.md §6i) can override geometry per (platform,
-# shape-bucket); tuned values still pass the VMEM-budget shrink below.
-# Tests pass explicit odd tiles to exercise ragged edges.
-from ..autotune.defaults import (  # re-exported; kmeans/tests import here
+# tile-geometry DEFAULTS live in the defaults module (defaults.py — the
+# analyzer's fence/hardcoded-tunable rule bans new literals in ops/): the query
+# block bounds the (block, tile) distance tile in VMEM (256*1024*4 = 1 MiB)
+# next to one double-buffered X tile (1024*d*4). Tests pass explicit odd tiles
+# to exercise ragged edges.
+from ..defaults import (  # re-exported; kmeans/tests import here
     DEFAULT_ASSIGN_BLOCK,
     DEFAULT_ITEM_TILE,
     DEFAULT_QUERY_BLOCK,
@@ -120,9 +118,7 @@ def _cost_estimate(flops: float, bytes_accessed: float) -> pl.CostEstimate:
 
 def topk_fits_vmem(q_block: int, item_tile: int, d: int, k: int) -> bool:
     """Can the running-pool scan place (q_block, item_tile) at this (d, k)?
-    ONE working-set formula — `_topk_geometry`'s shrink loop and the
-    autotuner's candidate filter (autotune/search.py) both ask this, so the
-    two can never drift and admit a geometry Mosaic cannot place."""
+    The working-set formula `_topk_geometry`'s shrink loop asks."""
     work = (
         q_block * (k + item_tile) * 4 * 4  # concat d2+ids + masked copies
         + q_block * d * 4 + item_tile * d * 4  # Q block + X tile
@@ -133,9 +129,8 @@ def topk_fits_vmem(q_block: int, item_tile: int, d: int, k: int) -> bool:
 
 def assign_block_fits_vmem(blk: int, d: int, k: int, n_split: int) -> bool:
     """Can the fused assignment place a blk-row block at this (d, k,
-    n_split)? Shared by `_assign_geometry` and the autotuner's
-    `pallas.assign_block` candidate filter — same no-drift rationale as
-    `topk_fits_vmem`."""
+    n_split)? Shared by `_assign_geometry` and the `use_fused_assign` gate,
+    so the two can never drift and admit a block Mosaic cannot place."""
     copies = max(1, n_split)  # bf16 splitting materializes n_split copies
     centers_b = k * d * 4 * copies
     tile_b = blk * d * 4 * copies + blk * k * 4 * 2  # X block + d2/onehot
@@ -151,21 +146,9 @@ def _topk_geometry(
     copies). Caller-pinned values pass through untouched (tests exercise
     ragged geometries); unpinned axes halve toward their floors until the
     budget holds — a floor-sized scan always fits for any k the search
-    family produces. Fully-unpinned geometry consults the tuning table first
-    (`pallas.topk_geometry`, docs/design.md §6i); tuned values are still
-    treated as unpinned, so a table entry written on different hardware can
-    never hand Mosaic an unplaceable compile."""
-    tuned_q = tuned_t = None
-    if q_block is None and item_tile is None:
-        from .. import autotune as _autotune
-
-        tuned = _autotune.lookup("pallas.topk_geometry", n=n, d=d, k=k)
-        if tuned is not None:
-            # clamp tuned values into the data like the defaults are
-            tuned_q = min(int(tuned[0]), max(nq, 1))
-            tuned_t = min(int(tuned[1]), max(n, 1))
-    qb = q_block or tuned_q or min(DEFAULT_QUERY_BLOCK, max(nq, 1))
-    t = item_tile or tuned_t or min(DEFAULT_ITEM_TILE, max(n, 1))
+    family produces."""
+    qb = q_block or min(DEFAULT_QUERY_BLOCK, max(nq, 1))
+    t = item_tile or min(DEFAULT_ITEM_TILE, max(n, 1))
 
     if q_block is None:
         while not topk_fits_vmem(qb, t, d, k) and qb > MIN_QUERY_BLOCK:
@@ -197,11 +180,7 @@ def _assign_geometry(d: int, k: int, n_split: int, n: int) -> Optional[int]:
     caller must keep the XLA path (which handles any k) rather than hand
     Mosaic a compile it cannot place."""
     floor = min(MIN_ASSIGN_BLOCK, max(n, 1))
-    from .. import autotune as _autotune
-
-    tuned = _autotune.lookup("pallas.assign_block", d=d, k=k)
-    start = int(tuned) if tuned is not None else DEFAULT_ASSIGN_BLOCK
-    blk = min(max(start, floor), max(n, 1))
+    blk = min(max(DEFAULT_ASSIGN_BLOCK, floor), max(n, 1))
     while True:
         if assign_block_fits_vmem(blk, d, k, n_split):
             return blk
@@ -360,11 +339,9 @@ def resolve_topk_geometry(
     nq: int, n: int, d: int, k: int,
     q_block: Optional[int] = None, item_tile: Optional[int] = None,
 ) -> Tuple[int, int]:
-    """HOST-side geometry resolution for the fused top-k scan: tuning table
-    (`pallas.topk_geometry`) + the VMEM-budget shrink. Traced code must not
-    call this (the table read would bake per-host — rank-divergent SPMD
-    programs on a pod); resolve in the host wrapper / shard_map factory and
-    hand the pins to `fused_topk_pinned`."""
+    """HOST-side geometry resolution for the fused top-k scan: the defaults
+    + the VMEM-budget shrink. Resolve in the host wrapper / shard_map factory
+    and hand the pins to `fused_topk_pinned`."""
     return _topk_geometry(int(nq), int(n), int(d), int(k), q_block, item_tile)
 
 
@@ -382,7 +359,7 @@ def fused_topk_pinned(
 ) -> Tuple[jax.Array, jax.Array]:
     """TRACE-PURE core of the fused smallest-k scan: geometry arrives pinned
     (resolve_topk_geometry in a host wrapper), precision arrives resolved —
-    no config read, no tuning-table read (tools/analysis purity/*). This is
+    no config read (tools/analysis purity/*). This is
     the form shard_map bodies call; same output contract as fused_topk."""
     n = X.shape[0]
     k = min(int(k), n)
@@ -409,7 +386,7 @@ def fused_topk(
     is bit-identical to the `select_topk(exact_full)` path (ids, distances,
     tie order, k > n_valid tails). bf16/int8 modes return the APPROXIMATE
     pool — callers owe the user a parity_rerank_sq pass (see fused_knn_select
-    for the paired form). HOST wrapper: resolves geometry (tuning table +
+    for the paired form). HOST wrapper: resolves geometry (defaults +
     VMEM shrink) and delegates to the trace-pure fused_topk_pinned."""
     n = X.shape[0]
     k = min(int(k), n)
@@ -568,24 +545,13 @@ def use_fused_assign(
         return False
     if s == "auto":
         if _sel._backend() != "tpu":
-            # auto off-TPU: XLA always — return before any probe so a CPU
-            # predict never pays (or counter-pollutes) a table consult
+            # auto off-TPU: XLA always
             return False
-        # min_k gate BEFORE the geometry probe: the probe can trigger a
-        # pallas.assign_block table consult (and, in online search mode, a
-        # whole measurement sweep) that a below-threshold k would discard
-        min_k = FUSED_ASSIGN_MIN_K
-        from .. import autotune as _autotune
-
-        tuned = _autotune.lookup("assign.fused_min_k", d=d)
-        if tuned is not None:
-            min_k = int(tuned)
-        if int(k) < min_k:
+        if int(k) < FUSED_ASSIGN_MIN_K:
             return False
     if d is not None and not assign_block_fits_vmem(
         # placeability = the FLOOR block fits (what _assign_geometry's
-        # shrink bottoms out at); asking the predicate directly keeps the
-        # gate free of a second pallas.assign_block table consult per call
+        # shrink bottoms out at)
         MIN_ASSIGN_BLOCK, int(d), int(k), _assign_n_split()
     ):
         return False

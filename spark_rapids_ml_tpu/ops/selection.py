@@ -85,13 +85,11 @@ def _backend() -> str:
 
 
 def _auto_tile(n: int, backend: str) -> int:
-    """Platform tile DEFAULT when no tuning-table entry covers the bucket:
-    on TPU small fixed tiles vectorize the per-tile select on the VPU; on CPU
-    each TopK custom call pays per-call overhead, so keep the tile count
-    small. The values live in autotune/defaults.py (the knob-registry
-    defaults module); measured per-bucket choices live in the tuning table,
-    whose entries carry their own `provenance` field (docs/design.md §6i)."""
-    from ..autotune.defaults import default_select_tile
+    """Platform tile DEFAULT when `knn.select_tile` is 0: on TPU small fixed
+    tiles vectorize the per-tile select on the VPU; on CPU each TopK custom
+    call pays per-call overhead, so keep the tile count small. The values
+    live in defaults.py."""
+    from ..defaults import default_select_tile
 
     return default_select_tile(n, backend)
 
@@ -100,33 +98,23 @@ def _fused_auto(n: int) -> bool:
     """Should `auto` hand a FUSABLE width-n scan to the fused pallas kernel?
     TPU only (off-TPU the kernel runs the Pallas interpreter — a correctness
     tool, not a fast path), and only once the scanned item width clears the
-    `pallas.min_items` threshold (tuning table, else `knn.pallas_min_items`;
-    small scans don't pay back the kernel's in-register selection work)."""
+    `knn.pallas_min_items` threshold (small scans don't pay back the kernel's
+    in-register selection work)."""
     if _backend() != "tpu":
         return False
-    from .. import autotune as _autotune
     from .. import config as _config
 
-    min_items = _autotune.lookup("pallas.min_items")
-    if min_items is None:
-        min_items = int(_config.get("knn.pallas_min_items"))
-    return n >= int(min_items)
+    return n >= int(_config.get("knn.pallas_min_items"))
 
 
 def resolve_fused_precision(precision: Optional[str] = None) -> str:
     """Resolve the fused scan's distance-accumulation mode
     (`knn.pallas_precision` unless the caller pinned one). Host-side — like
     `resolve`, so a config change can never be baked stale into a cached
-    trace. Resolution order: caller-pinned > config set()/env > tuning table
-    > default (the table may only steer this knob because every consuming
-    site pairs non-f32 modes with the parity_rerank_sq exactness invariant —
-    returned distances stay exact-f32 either way). Non-float32 modes REQUIRE
-    the caller to follow with that re-rank."""
-    from .. import autotune as _autotune
+    trace. Non-float32 modes REQUIRE the caller to follow with the
+    parity_rerank_sq re-rank (returned distances stay exact-f32 either way)."""
     from .. import config as _config
 
-    if precision is None:
-        precision = _autotune.lookup("pallas.precision")
     if precision is None:
         precision = str(_config.get("knn.pallas_precision"))
     if precision not in FUSED_PRECISIONS:
@@ -158,7 +146,7 @@ def resolve(
     matrix) and can therefore run the fused pallas distance+select scan
     (ops/pallas_select.py): under `auto` on TPU such a site picks
     `pallas_fused` once n >= knn.pallas_min_items, for k <= FUSED_TOPK_MAX_K
-    (the largest k Mosaic places — autotune/defaults.py). A NON-fusable site asked
+    (the largest k Mosaic places — defaults.py). A NON-fusable site asked
     for `pallas_fused` (explicitly or via a threaded resolved value) degrades
     to exact_full — there is nothing left to fuse once d2 exists, and
     exact_full preserves the fused scan's bit-exact contract."""
@@ -171,25 +159,13 @@ def resolve(
             f"knn.selection must be one of {STRATEGIES}, got '{strategy}'"
         )
     if strategy == "auto":
-        from ..autotune.defaults import FUSED_TOPK_MAX_K
+        from ..defaults import FUSED_TOPK_MAX_K
 
-        # the k bound comes BEFORE the width probe (a table consult): past it
-        # Mosaic refuses the kernel's unrolled extraction (defaults module)
+        # past the k bound Mosaic refuses the kernel's unrolled extraction
         if fusable and k <= FUSED_TOPK_MAX_K and _fused_auto(n):
             strategy = "pallas_fused"
         else:
-            # tuning table first (docs/design.md §6i): a measured per-bucket
-            # strategy beats the platform heuristic. A REAL set()/env pin on
-            # knn.selection never reaches here (strategy wasn't "auto"), and
-            # lookup() itself treats a pin to the literal sentinel "auto" as
-            # "choose for me" — the table slots between env and the default
-            from .. import autotune as _autotune
-
-            tuned = _autotune.lookup("selection.strategy", n=n, k=k)
-            if tuned is not None and (fusable or tuned != "pallas_fused"):
-                strategy = tuned
-            else:
-                strategy = "approx" if _backend() == "tpu" else "exact_tiled"
+            strategy = "approx" if _backend() == "tpu" else "exact_tiled"
     if strategy == "pallas_fused" and not fusable:
         strategy = "exact_full"
     # degradations: k-of-n selects with no real pool reduction run fused
@@ -203,12 +179,7 @@ def resolve(
         if tile is None:
             tile = int(_config.get("knn.select_tile") or 0)
         if tile <= 0:
-            # tuning table between config and the platform heuristic: a
-            # nonzero knn.select_tile (set()/env) took the branch above
-            from .. import autotune as _autotune
-
-            tuned = _autotune.lookup("selection.tile", n=n, k=k)
-            tile = int(tuned) if tuned is not None else _auto_tile(n, _backend())
+            tile = _auto_tile(n, _backend())
         if n <= tile:
             strategy = "exact_full"
     # knn.recall_target is read/validated ONLY when approx actually runs:
@@ -262,7 +233,7 @@ def select_topk(
 ) -> Tuple[jax.Array, jax.Array]:
     """Smallest-k along the last axis: returns (d2_topk, indices), distances
     ascending. TRACE-PURE by contract (tools/analysis purity/*): this
-    function reads no config and consults no tuning table — `strategy` must
+    function reads no config — `strategy` must
     arrive CONCRETE from a host-side `resolve()` call, so a cached trace can
     never bake a stale choice. Only the pure degradations live here: a
     k-of-n select with no real pool reduction (k >= n, n <= 4k, n within one

@@ -37,7 +37,7 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..autotune.defaults import FOREST_EDGE_BLOCK_COLS
+from ..defaults import FOREST_EDGE_BLOCK_COLS
 from ..observability import counter_inc, span
 from ..observability.device import compiled_kernel
 
@@ -307,7 +307,7 @@ def level_tile(width: int, d: int, nbins: int, s: int, n: int = 0) -> int:
     """Features a tile of a level of `width` nodes: as many as keep the tile's
     (width, F, nbins, s) float32 histogram, and the (n, F) int32 ids a form
     that is not grouped unpacks for it, within `FOREST_HIST_TILE_BYTES`."""
-    from ..autotune.defaults import FOREST_HIST_TILE_BYTES
+    from ..defaults import FOREST_HIST_TILE_BYTES
     from .pallas_histogram import WORDS_PER_STEP
 
     _, f_max = feature_plan(d)

@@ -530,37 +530,23 @@ def put_device_local(x: Any, site: Optional[str] = None,
     return active_partitioner().put_local(x, site=site, device=device)
 
 
-# --------------------------------------------------------------- knobs
+# --------------------------------------------------------------- config
 
-def resolve_feature_axis(n: Optional[int] = None, d: Optional[int] = None) -> int:
+def resolve_feature_axis() -> int:
     """Feature-axis width for SPMDPartitioner meshes. Host-resolution only
-    (a partitioner is built per fit, never inside a trace): config pin >
-    tuning table (knob `partition.feature_axis`, (n, d)-bucketed) > 1."""
-    from .. import autotune as _autotune
-
+    (a partitioner is built per fit, never inside a trace): config pin > 1."""
     cfg = int(_config.get("partition.feature_axis") or 0)
-    if cfg >= 1:
-        return cfg
-    tuned = _autotune.lookup("partition.feature_axis", n=n, d=d)
-    if tuned is not None and int(tuned) >= 1:
-        return int(tuned)
-    return 1
+    return cfg if cfg >= 1 else 1
 
 
-def resolve_batch_rows_per_process(n: Optional[int] = None,
-                                   d: Optional[int] = None) -> int:
+def resolve_batch_rows_per_process() -> int:
     """Per-process row-batch geometry for multi-host streamed ingest: each
     process stages this many LOCAL rows per streamed batch. Config pin >
-    tuning table > the single-process `stream_batch_rows` split across the
-    pod. Host-resolution only — the value feeds padding geometry, so
-    resolving it inside a trace would go stale."""
-    from .. import autotune as _autotune
-
+    the single-process `stream_batch_rows` split across the pod.
+    Host-resolution only — the value feeds padding geometry, so resolving it
+    inside a trace would go stale."""
     cfg = int(_config.get("partition.batch_rows_per_process") or 0)
     if cfg >= 1:
         return cfg
-    tuned = _autotune.lookup("partition.batch_rows_per_process", n=n, d=d)
-    if tuned is not None and int(tuned) >= 1:
-        return int(tuned)
     total = int(_config.get("stream_batch_rows"))
     return max(1, total // max(1, jax.process_count()))

@@ -50,20 +50,15 @@ def _isVectorAssembler(stage: Any) -> bool:
     return type(stage).__name__ == "VectorAssembler" and stage.hasParam("inputCols")
 
 
-def _resolve_fuse_min_rows(n: Optional[int] = None) -> int:
+def _resolve_fuse_min_rows() -> int:
     """`pipeline.fuse_min_rows` resolution: a non-zero config pin wins, then
-    the tuning table (per n-rows bucket), then the defaults-module geometry
-    (autotune/defaults.py::PIPELINE_FUSE_MIN_ROWS)."""
-    from . import autotune as _autotune
+    the defaults-module geometry (defaults.py::PIPELINE_FUSE_MIN_ROWS)."""
     from . import config as _config
-    from .autotune.defaults import PIPELINE_FUSE_MIN_ROWS
+    from .defaults import PIPELINE_FUSE_MIN_ROWS
 
     pinned = int(_config.get("pipeline.fuse_min_rows") or 0)
     if pinned > 0:
         return pinned
-    tuned = _autotune.lookup("pipeline.fuse_min_rows", n=n)
-    if tuned:
-        return int(tuned)
     return int(PIPELINE_FUSE_MIN_ROWS)
 
 
@@ -291,7 +286,7 @@ class Pipeline(Params):
             n_rows = len(dataset)
         except TypeError:
             n_rows = int(getattr(dataset, "num_rows", 0))
-        if n_rows < _resolve_fuse_min_rows(n=n_rows):
+        if n_rows < _resolve_fuse_min_rows():
             return None
         # degenerate single-class logistic fits route in-core; detect up front
         # so the staged path carries them instead of a mid-chain error

@@ -87,18 +87,10 @@ def bucket_rows(n: int, min_rows: Optional[int] = None,
                 max_rows: Optional[int] = None) -> int:
     """The power-of-two row bucket `n` pads to: smallest 2^i >= max(n,
     serving.bucket_min_rows), clamped to the bucket ceiling (the power of two
-    covering serving.max_batch_rows). The bucket floor is a tuning-table knob
-    (`serving.bucket_min_rows`, docs/design.md §6i) — resolved HERE, at
-    registration/submit time, never inside a trace — so a platform can widen
-    its pre-warmed bucket set by table entry; config set()/env still win."""
+    covering serving.max_batch_rows). The bucket floor resolves HERE, at
+    registration/submit time, never inside a trace."""
     if min_rows is None:
-        from .. import autotune as _autotune
-
-        tuned = _autotune.lookup("serving.bucket_min_rows")
-        min_rows = (
-            int(tuned) if tuned is not None
-            else int(_config.get("serving.bucket_min_rows"))
-        )
+        min_rows = int(_config.get("serving.bucket_min_rows"))
     if max_rows is None:
         max_rows = int(_config.get("serving.max_batch_rows"))
     n = max(int(n), max(int(min_rows), 1))

@@ -75,23 +75,13 @@ _HEDGE_MIN_SAMPLES = 20
 
 
 def resolve_replicas() -> int:
-    """Replica count for a new fleet: tuning table (knob `serving.replicas`)
-    unless config pins it; `0` (the default) means auto -> 1."""
-    from .. import autotune as _autotune
-
-    tuned = _autotune.lookup("serving.replicas")
-    if tuned is not None:
-        return max(1, int(tuned))
+    """Replica count for a new fleet: the `serving.replicas` config pin;
+    `0` (the default) means auto -> 1."""
     cfg = int(_config.get("serving.replicas") or 0)
     return cfg if cfg >= 1 else 1
 
 
 def _hedge_frac() -> float:
-    from .. import autotune as _autotune
-
-    tuned = _autotune.lookup("serving.hedge_after_p99_frac")
-    if tuned is not None:
-        return float(tuned)
     return float(_config.get("serving.hedge_after_p99_frac") or 0.0)
 
 

@@ -32,8 +32,7 @@ def get_logger(cls: Any, level: Union[int, str] = logging.INFO) -> logging.Logge
 def enable_compile_cache() -> str:
     """Point JAX's persistent compilation cache at a FIXED directory before the
     first compile, and return the directory in use. Entry points call this
-    (chip_smoke.py, `python -m spark_rapids_ml_tpu.autotune`); library
-    code never does.
+    (chip_smoke.py); library code never does.
 
     Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and nothing
     is set here. Otherwise the cache lives at `<checkout>/.jax_cache` (git-

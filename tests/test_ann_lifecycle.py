@@ -84,19 +84,19 @@ def test_strided_sample_exactly_clamped():
 
 
 def test_build_batch_rows_resolution():
-    from spark_rapids_ml_tpu.autotune.defaults import ANN_BUILD_BATCH_ROWS
+    from spark_rapids_ml_tpu.defaults import ANN_BUILD_BATCH_ROWS
 
-    assert resolve_build_batch_rows(1000, 8) == ANN_BUILD_BATCH_ROWS
+    assert resolve_build_batch_rows() == ANN_BUILD_BATCH_ROWS
     # an EXPLICITLY-configured streamed-fit geometry wins over the build
     # default (a deployment that sized batches keeps them)...
     config.set("stream_batch_rows", 512)
     try:
-        assert resolve_build_batch_rows(1000, 8) == 512
+        assert resolve_build_batch_rows() == 512
     finally:
         config.unset("stream_batch_rows")
     # ...and the dedicated knob's config pin beats everything
     config.set("ann.build_batch_rows", 123)
-    assert resolve_build_batch_rows(1000, 8) == 123
+    assert resolve_build_batch_rows() == 123
 
 
 # ------------------------------------------------- pipelined build parity
@@ -543,14 +543,3 @@ def test_registry_mutate_serializes_with_inflight_batches():
     finally:
         reg.close()
 
-
-# --------------------------------------------------------------- autotune
-
-
-def test_lifecycle_knobs_registered():
-    from spark_rapids_ml_tpu.autotune.knobs import KNOBS
-
-    for name in ("ann.build_batch_rows", "ann.list_bucket_rows",
-                 "ann.compact_tombstone_pct"):
-        assert name in KNOBS, name
-        assert KNOBS[name].config_key == name  # config pin always wins

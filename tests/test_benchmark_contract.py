@@ -37,7 +37,7 @@ ROWS, COLS, MAX_K, MAX_ITER = 512, 16, 4, 3
 # `pca_k3_d3000` leaves the Pallas Gram kernel for the XLA program past
 # `ops/pallas_xtwx.py::MAX_FUSED_COLS` = 512 columns, and that program
 # multiplies only the upper column blocks of the Gram matrix from
-# `autotune/defaults.py::GRAM_TRIANGLE_MIN_COLS` = 576 columns on, which the
+# `defaults.py::GRAM_TRIANGLE_MIN_COLS` = 576 columns on, which the
 # normal equations of `linreg_l2_d3000` share; a forest narrows a level to the
 # columns its nodes drew only where that matrix pads narrower than the whole
 # (`ops/trees.py::compact_gate`): at 64 columns and 8 a node, widths 1 to 4

@@ -88,12 +88,10 @@ def test_cache_helper_resolves_to_the_fixed_checkout_path(monkeypatch):
 
 
 def test_entry_points_call_the_cache_helper():
-    """chip_smoke.py and the autotune CLI each enable the cache; no other file
-    in the tree names a cache directory."""
-    for rel in ("chip_smoke.py",
-                os.path.join("spark_rapids_ml_tpu", "autotune", "__main__.py")):
-        with open(os.path.join(REPO, rel)) as f:
-            assert "enable_compile_cache()" in f.read(), rel
+    """chip_smoke.py enables the cache; no other file in the tree names a
+    cache directory."""
+    with open(os.path.join(REPO, "chip_smoke.py")) as f:
+        assert "enable_compile_cache()" in f.read()
     offenders = []
     for root, _, files in os.walk(os.path.join(REPO, "spark_rapids_ml_tpu")):
         for name in files:

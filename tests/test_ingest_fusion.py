@@ -213,7 +213,7 @@ def test_resolve_staging_pool_rows_config_pin_wins():
     config.set("ingest.staging_pool_rows", 123)
     assert ingest.resolve_staging_pool_rows() == 123
     config.unset("ingest.staging_pool_rows")
-    from spark_rapids_ml_tpu.autotune.defaults import INGEST_STAGING_POOL_ROWS
+    from spark_rapids_ml_tpu.defaults import INGEST_STAGING_POOL_ROWS
 
     assert ingest.resolve_staging_pool_rows() == INGEST_STAGING_POOL_ROWS
 

@@ -480,7 +480,7 @@ def test_resolve_pallas_fused_semantics(monkeypatch):
     monkeypatch.setattr(sel, "_backend", lambda: "tpu")
     assert sel.resolve(1 << 17, 10, "auto", fusable=True)[0] == "pallas_fused"
     # the auto gate is closed above the largest k Mosaic places on a v5e (the
-    # unrolled extraction overflows scoped VMEM at k=64 — autotune/defaults.py);
+    # unrolled extraction overflows scoped VMEM at k=64 — defaults.py);
     # an explicit request still reaches the kernel and fails loudly there
     assert sel.resolve(1 << 17, 32, "auto", fusable=True)[0] == "pallas_fused"
     assert sel.resolve(1 << 17, 33, "auto", fusable=True)[0] == "approx"

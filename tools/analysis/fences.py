@@ -161,12 +161,11 @@ register_rule(
     "fence/hardcoded-tunable",
     "hard-coded tunable tile/block/threshold constant in ops/",
     """
-Numeric tile/block/threshold DEFAULTS live in the knob-registry defaults
-module (spark_rapids_ml_tpu/autotune/defaults.py, docs/design.md §6i); their
-measured per-platform overrides live in tuning tables. A fresh literal in
-ops/ is a knob the autotuner can't see and a re-tuning chore on the next
-hardware target. Zero-valued sentinels (`BLOCK_ROWS = 0` = adaptive) stay
-legal.
+Numeric tile/block/threshold DEFAULTS live in the defaults module
+(spark_rapids_ml_tpu/defaults.py, docs/design.md §6i), each beside the probe
+measurement that set it. A fresh literal in ops/ is a re-tuning chore hidden
+from the next hardware target. Zero-valued sentinels (`BLOCK_ROWS = 0` =
+adaptive) stay legal.
 """,
 )
 
@@ -444,8 +443,7 @@ def _check_fences(ctx: AnalysisContext, mod: ModuleInfo) -> None:
                 "fence/hardcoded-tunable", mod, node.lineno,
                 f"hard-coded tunable '{names[0]} = {v}' in ops/ — numeric "
                 "tile/threshold defaults live in spark_rapids_ml_tpu/"
-                "autotune/defaults.py (knob registry, docs/design.md §6i); "
-                "import it or declare a knob",
+                "defaults.py (docs/design.md §6i); import it from there",
             )
 
     # host staging copies in ops/ outside the ingest plane

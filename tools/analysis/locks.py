@@ -1,6 +1,6 @@
 #
 # Lock-graph pass (docs/design.md §6j): the ~20 locks across the serving
-# registry / device cache / observability runs / autotune table planes are
+# registry / device cache / observability runs planes are
 # correct today by convention; this pass makes the two conventions checkable:
 #
 #   * locks/order-cycle — build a lock-ORDER graph (edge A->B when B is
@@ -71,7 +71,6 @@ _HOT_LOCK_PATTERNS = (
     "observability.registry.",
     "observability.runs.",
     "observability.device",
-    "autotune.table",
 )
 
 _BLOCKING_TIME = {"sleep"}

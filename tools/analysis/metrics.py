@@ -99,8 +99,7 @@ _EMIT_FUNCS: Dict[str, Set[str]] = {
 # the metric-name universe (no label schema, no doc-catalog obligation).
 _PHASE_FUNCS = ("progress", "note_rank_phase")
 
-# local import aliases of the emit helpers seen in-tree; the `_counter`
-# best-effort wrapper (autotune/knobs.py, table.py) forwards to counter_inc
+# local import aliases of the emit helpers seen in-tree
 _EMIT_ALIASES = {
     "obs_span": "span",
     "_obs_span": "span",
@@ -108,7 +107,6 @@ _EMIT_ALIASES = {
     "obs_counter_inc": "counter_inc",
     "obs_gauge_set": "gauge_set",
     "obs_observe": "observe",
-    "_counter": "counter_inc",
     "obs_progress": "progress",
 }
 
@@ -235,9 +233,8 @@ def _is_consumer(mod: ModuleInfo) -> bool:
 
 
 # dotted vocabularies that share the metric grammar but are NOT metrics:
-# config keys (config.py _DEFAULTS/_ENV_KEYS), autotune knob names
-# (Knob("...") declarations), and compiled-kernel names (they surface as
-# `device.compile{kernel=}` label VALUES and `device.kernels[].kernel`
+# config keys (config.py _DEFAULTS/_ENV_KEYS) and compiled-kernel names (they
+# surface as `device.compile{kernel=}` label VALUES and `device.kernels[].kernel`
 # records, both legitimately consumed by tests/smokes/docs)
 _FILEISH_SUFFIXES = (".py", ".sh", ".md", ".json", ".jsonl", ".txt", ".yaml")
 
@@ -263,7 +260,7 @@ def _harvest_vocab(ctx: AnalysisContext) -> Set[str]:
                 else node.func.attr if isinstance(node.func, ast.Attribute)
                 else ""
             )
-            if fname in ("Knob", "compiled_kernel") + _PHASE_FUNCS and node.args:
+            if fname in ("compiled_kernel",) + _PHASE_FUNCS and node.args:
                 for s in _literal_strs(node.args[0]):
                     vocab.add(s)
             # phase names threaded as keywords (streamed-fit loops pass

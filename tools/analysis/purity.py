@@ -48,17 +48,6 @@ config.py, which owns env resolution) and pass the value in.
 """,
 )
 register_rule(
-    "purity/autotune-read",
-    "autotune table lookup reachable from traced code",
-    """
-`autotune.lookup()` is a host-side resolution point by contract
-(autotune/knobs.py: "the resolution sites are the PR-5 host wrappers, so
-cached traces never bake a stale choice"). A lookup inside traced code pins
-the tuning-table value at first trace — retuning, mode changes, and config
-pins stop working for every cached signature. Hoist to the host wrapper.
-""",
-)
-register_rule(
     "purity/time-read",
     "wall-clock read reachable from traced code",
     """
@@ -93,13 +82,12 @@ Return the value instead, or move the mutation to the host wrapper.
 _SEED_PKG = "spark_rapids_ml_tpu"
 
 # host-plane boundary: reachability does NOT descend INTO these modules.
-# A traced function calling config.get / autotune.lookup is flagged AT THE
+# A traced function calling config.get is flagged AT THE
 # CALL SITE (that's the finding); walking into the host plane's own
 # implementation would re-report the same root cause against config.py's
 # internals (os.environ inside config.get) and drown the signal.
 _BOUNDARY_PREFIXES = (
     "spark_rapids_ml_tpu.config",
-    "spark_rapids_ml_tpu.autotune",
     "spark_rapids_ml_tpu.observability",
     "spark_rapids_ml_tpu.reliability",
     "spark_rapids_ml_tpu.profiling",
@@ -181,12 +169,6 @@ def _function_hazards(cg: CallGraph, fi: FunctionInfo,
             ):
                 out.append(_Hazard("purity/config-read", node.lineno,
                                    f"{base}.{func.attr}(...)"))
-            elif func.attr == "lookup" and (
-                (target or "").startswith("spark_rapids_ml_tpu.autotune")
-                or base in ("_autotune",)
-            ):
-                out.append(_Hazard("purity/autotune-read", node.lineno,
-                                   f"{base}.lookup(...)"))
             elif base is not None and target == "time" and func.attr in _TIME_FNS:
                 out.append(_Hazard("purity/time-read", node.lineno,
                                    f"{base}.{func.attr}()"))

@@ -1,7 +1,7 @@
 """Microbenchmark, outside any cell of BENCHMARK.json: what computing only the
 upper column blocks of the XLA Gram (`ops/linalg.py::_centered_gram`) costs
 and gives on the chip against the single matmul, by block width and column
-count. Where `autotune/defaults.py::GRAM_BLOCK_COLS` and
+count. Where `defaults.py::GRAM_BLOCK_COLS` and
 `GRAM_TRIANGLE_MIN_COLS` come from. One JSON line a reading (seconds a
 4,096-row part, the least of five calls of `weighted_covariance`; the worst
 entry's distance from the single matmul's in units of the largest entry;

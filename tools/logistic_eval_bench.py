@@ -2,7 +2,7 @@
 the binary logistic data term (value and gradient) costs on the chip as
 autodiff's two XLA passes over X and as the one-read Pallas sweep
 (`ops/pallas_logistic.py`), by samples a block. Where
-`autotune/defaults.py::LOGISTIC_EVAL_*` come from. One JSON line a reading:
+`defaults.py::LOGISTIC_EVAL_*` come from. One JSON line a reading:
 the table's placed layout; per form the milliseconds an evaluation (the
 difference of a 40- and a 10-evaluation compiled loop, the least of three
 calls each, every evaluation at the point the last one's gradient moved it to),
