@@ -40,6 +40,19 @@
 #     whatever its width, plus one work item a node tile.
 #   * segment_sum ("xla"): off the TPU.
 #
+# Operands. The MXU multiplies bfloat16 (and int8); a float32 operand of a
+# Pallas dot at the default precision is not multiplied in float32 (on a v5e a
+# regressor's statistics on float32 lanes read variance gains off by 2e-4 of
+# the root's variance, 50 times what three parts read, PERF.md section 6).
+# Whole-number statistics (a classifier's counts) are exact in int8 or
+# bfloat16 as they are. Real-valued ones (a regressor's [w, wy, wy^2]) ride as
+# "float32x3": `split_bf16x3` cuts each float32 into three bfloat16 parts whose
+# sum is the value to the bit, the parts go in as further statistics of the
+# same contraction (lanes the 0/1 indicator meets, which is exact in bfloat16),
+# every product is exact, the MXU sums in float32, and `fold_parts` adds each
+# statistic's three sums. One dot a feature, as for int8: the parts take lanes
+# (a third of the nodes a tile) and not passes.
+#
 
 from __future__ import annotations
 
@@ -217,7 +230,7 @@ def _nb_hist_kernel(
 def node_bin_histogram_pallas(
     Xb: jax.Array,  # (n, d) int32 bin ids in [0, nbins)
     node_id: jax.Array,  # (n,) int32 in [0, width)
-    values: jax.Array,  # (n, s) f32, zero rows contribute nothing
+    values: jax.Array,  # (n, s) f32 or bfloat16 parts, zero rows contribute nothing
     width: int,
     nbins: int,
     interpret: bool = False,
@@ -244,10 +257,14 @@ def node_bin_histogram_pallas(
     # must stay <=6 MiB — (a) alone explodes at shallow levels (w_tile=1 gives
     # budget 8192 -> d_tile=d -> 25 MiB of rhs at d=128, a hardware-only OOM
     # interpret-mode tests can never catch).
-    w_tile = min(width, 256)
-    c_tiles = _round_up(width, w_tile) // w_tile
     lane = nbins * s
     lane_pad = _round_up(lane, 128)
+    w_tile = min(width, 256)
+    while w_tile > 8 and 8 * w_tile * lane_pad * 4 > 4 * 1024 * 1024:
+        # (a) at the 8-feature floor below: 128 bins x 9 lanes (a regressor's
+        # statistics as three bfloat16 parts) would ask 9.4 MB at 256 nodes
+        w_tile //= 2
+    c_tiles = _round_up(width, w_tile) // w_tile
     if lane_pad > 128:
         # past one lane tile the (blk, lane) temporaries beside the rhs buffers
         # count too: 128 bins x 2 classes at blk=512 and 8 features a step asked
@@ -348,6 +365,38 @@ def node_tile(s: int) -> int:
     return w
 
 
+def split_bf16x3(values: jax.Array) -> jax.Array:
+    """(n, s) float32 -> (n, 3 s) bfloat16, the lanes [hi | mid | lo] with
+    hi + mid + lo == v to the bit for every finite float32 whose parts are
+    normal numbers (|v| of 2^-103 and over). Each part is the leading 8
+    significant bits of what the parts before it leave (a mask of the bits, so
+    no rounding XLA could fold away): hi takes bits 1-8 of the 24, mid the
+    next 8 after the remainder's leading zeros, and lo the at most 8 left.
+    Every part has the value's sign."""
+    def top(x):
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint32) & jnp.uint32(0xFFFF0000)
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+    v = values.astype(jnp.float32)
+    hi = top(v)
+    rest = v - hi  # exact: the bits hi left
+    mid = top(rest)
+    return jnp.concatenate([hi, mid, rest - mid], axis=1).astype(jnp.bfloat16)
+
+
+def lane_stats(operand: str, s: int) -> int:
+    """Statistics a kernel's lanes carry for `s` statistics of `operand`."""
+    return 3 * s if operand == "float32x3" else s
+
+
+def fold_parts(parts, s: int):
+    """The `s` statistics of a histogram given one array a lane statistic:
+    under `split_bf16x3` each is (hi + mid) + lo of its three part sums."""
+    if len(parts) == s:
+        return parts
+    return [(parts[i] + parts[s + i]) + parts[2 * s + i] for i in range(s)]
+
+
 def hist_gate(width: int, d: int, nbins: int, s: int, n: int,
               devices: int = 1) -> Tuple[bool, str]:
     """Whether a level of `width` nodes takes the grouped histogram, and which
@@ -372,7 +421,7 @@ def hist_gate(width: int, d: int, nbins: int, s: int, n: int,
 
 
 def group_rows(node_id: jax.Array, values: jax.Array, width: int,
-               operand_dtype=jnp.float32) -> Dict[str, jax.Array]:
+               operand="float32") -> Dict[str, jax.Array]:
     """What the grouped kernel needs of a level's rows, computed by XLA inside
     the tree's program: `order` (the argsort of the node ids, None while one
     node tile holds the whole level and nothing moves), `lhs` (n, lanes): each
@@ -380,8 +429,12 @@ def group_rows(node_id: jax.Array, values: jax.Array, width: int,
     the row's node tile, and the work list: (`blk`, `tile`) pairs in row order
     with `first` (the pair opens its tile: zero the accumulator) and `valid`
     (the list is padded to its static bound, row blocks + node tiles - 1),
-    and `visited` (tiles with no row are never written)."""
-    n, s = values.shape
+    and `visited` (tiles with no row are never written). `operand` is the
+    lanes' type, or "float32x3": each statistic as its `split_bf16x3` parts,
+    `lane_stats` of them a node."""
+    n = values.shape[0]
+    s = lane_stats(operand, values.shape[1])
+    dtype = jnp.bfloat16 if operand == "float32x3" else jnp.dtype(operand)
     w = node_tile(s)
     n_tiles = -(-width // w)
     lanes = _round_up(w * s, 128)
@@ -392,9 +445,11 @@ def group_rows(node_id: jax.Array, values: jax.Array, width: int,
     else:
         order = jnp.argsort(node_id)
         ng, vg = node_id[order], values[order]
+    if operand == "float32x3":
+        vg = split_bf16x3(vg)
     lhs = (  # statistic-major lanes: statistic i of tile node c at lane i * w + c
-        vg.astype(operand_dtype)[:, :, None]
-        * jax.nn.one_hot(ng % w, w, dtype=operand_dtype)[:, None, :]
+        vg.astype(dtype)[:, :, None]
+        * jax.nn.one_hot(ng % w, w, dtype=dtype)[:, None, :]
     ).reshape(n, s * w)
     lhs = jnp.pad(lhs, ((0, 0), (0, lanes - s * w)))
     trow = (ng // w).astype(jnp.int32)
@@ -469,9 +524,10 @@ def grouped_histogram_tile(pt: jax.Array, grp: Dict[str, jax.Array], tile_index,
     (tile_index + 1) * words); `grp`: `group_rows` of the level. Which feature
     a byte is, is the caller's packing (`ops/trees.py::tile_features`): the
     feature axis is (byte, word of the tile). Values that
-    are whole numbers up to 256 may ride as bfloat16 and up to 127 as int8
-    (`group_rows`'s `operand_dtype`): a block's sums are float32 (int32 under
-    int8), the accumulator float32."""
+    are whole numbers up to 256 may ride as bfloat16 and up to 127 as int8,
+    and real ones as their three bfloat16 parts with `s` counting the parts
+    (`group_rows`'s `operand`; `fold_parts` sums them): a block's sums are
+    float32 (int32 under int8), the accumulator float32."""
     n = pt.shape[1]
     w = node_tile(s)
     n_tiles = -(-width // w)

@@ -424,28 +424,31 @@ def _row_bin(Xb, packed: bool, row_feat) -> jax.Array:
     return (picked >> (8 * (row_feat // cols))) & 0xFF if packed else picked
 
 
-def _tile_histogram(form, Xb, packed, values, node_id, width, nbins, j, f, mesh, grouped):
+def _tile_histogram(form, Xb, packed, values, s, node_id, width, nbins, j, f, mesh,
+                    grouped):
     """Feature tile `j` (traced) of `f` features of the level's histogram, one
-    array a statistic: (f, width, nbins) each, or in the grouped form (f, A,
-    nbins, C) with node a * C + c at [:, a, :, c] and the bins CUMULATIVE (the
-    kernel's one-hot is "id <= bin": the split search wants the running sums and
-    nothing else). Each producer's own order: nothing of a tile's size is
-    transposed."""
+    array for each of the `s` statistics: (f, width, nbins) each, or in the
+    grouped form (f, A, nbins, C) with node a * C + c at [:, a, :, c] and the
+    bins CUMULATIVE (the kernel's one-hot is "id <= bin": the split search wants
+    the running sums and nothing else). Each producer's own order: nothing of a
+    tile's size is transposed. `values` and the grouped form's rows are the
+    kernel's lanes: where they hold `split_bf16x3` parts, the parts' sums are
+    folded back into `s` statistics here."""
     from .pallas_histogram import (
-        grouped_histogram_tile, node_bin_histogram, segment_histogram,
+        fold_parts, grouped_histogram_tile, node_bin_histogram, segment_histogram,
     )
 
-    s = values.shape[1]
     if form == "grouped":
-        pt, grp, interpret = grouped
-        return grouped_histogram_tile(pt, grp, j, f // 4, width, nbins, s, interpret)
+        pt, grp, lanes, interpret = grouped
+        return fold_parts(
+            grouped_histogram_tile(pt, grp, j, f // 4, width, nbins, lanes, interpret), s)
     xt = _tile_ids(Xb, packed, j, f)
     if form == "direct":
         h = node_bin_histogram(xt, node_id, values, width, nbins, True, mesh=mesh,
                                feature_major=True)
     else:
         h = segment_histogram(node_id[:, None] * nbins + xt, values, width * nbins)
-    return _parts(h.reshape(f, width, nbins, s))
+    return fold_parts(_parts(h.reshape(f, width, nbins, values.shape[1])), s)
 
 
 def _tile_best(parts, cumulative, T, allowed, feat, nbins, impurity, min_instances):
@@ -519,7 +522,7 @@ def _level_step(
     min_info_gain: float,
     mesh,
     form: str = "xla",
-    operand: str = "float32",
+    operand: str = "float32x3",
     packed: bool = False,
 ):
     """One tree level (width = 2**t): histogram and split search a feature tile
@@ -539,7 +542,7 @@ def _level_step(
     tie rule and split is the whole-width scan's. A union wider than that
     matrix (float ties in the draw admit more than k_features to a node) runs
     the level whole-width and adds one to the state's overflow count."""
-    from .pallas_histogram import _interpret, group_rows, node_tile
+    from .pallas_histogram import _interpret, group_rows, lane_stats, node_tile, split_bf16x3
 
     (feat_arr, thr_arr, leaf_arr, val_arr, gain_arr, wgt_arr, node_id, T, key,
      overflows) = state
@@ -563,13 +566,17 @@ def _level_step(
     grp = None
     n_nodes = width
     words = Xb
+    lanes = lane_stats(operand, s)  # statistics a kernel's lanes carry
+    kernel_values = values  # the direct form's operand
     if form == "grouped":
         assert packed, "the grouped kernel reads four one-byte ids a word"
-        c = node_tile(s)
+        c = node_tile(lanes)
         n_nodes = -(-width // c) * c
-        grp = group_rows(node_id, values, width, jnp.dtype(operand))
+        grp = group_rows(node_id, values, width, operand)
         if grp["order"] is not None:  # rows sorted by node
             words = jnp.take(Xb, grp["order"], axis=0)
+    elif form == "direct" and lanes > s:
+        kernel_values = split_bf16x3(values)
     T_pad = jnp.pad(T, ((0, n_nodes - width), (0, 0)))
 
     def scan(X, cols):
@@ -578,17 +585,17 @@ def _level_step(
         feature i is feature cols[i] ((F,) int32, d for a padding slot), or,
         cols None, every feature of `Xb`."""
         cols_pad = X.shape[0 if form == "grouped" else 1] * (4 if packed else 1)
-        f = level_tile(width, d if cols is None else cols.shape[0], nbins, s,
-                       0 if form == "grouped" else n)
+        f = level_tile(width, d if cols is None else cols.shape[0], nbins,
+                       s if form == "xla" else lanes, 0 if form == "grouped" else n)
         ok = allowed if cols is None else (
             jnp.take(allowed, jnp.minimum(cols, d - 1), axis=1) & (cols < d))
         # feature-major: a tile's rows are a row gather; no padded feature, no padded node
         ok = jnp.pad(ok, ((0, n_nodes - width), (0, cols_pad - ok.shape[1]))).T
-        grouped = None if grp is None else (X, grp, _interpret())
+        grouped = None if grp is None else (X, grp, lanes, _interpret())
 
         def tile_best(j):
-            h = _tile_histogram(form, X, packed, values, node_id, width, nbins, j, f, mesh,
-                                grouped)
+            h = _tile_histogram(form, X, packed, kernel_values, s, node_id, width, nbins, j,
+                                f, mesh, grouped)
             at = tile_features(packed, j, f, cols_pad)
             return _tile_best(h, form == "grouped", T_pad, jnp.take(ok, at, axis=0),
                               at if cols is None else cols[at], nbins, impurity,
@@ -690,7 +697,7 @@ def _build_tree_impl(
     mesh=None,
     level_timing=None,
     forms=None,
-    operand: str = "float32",
+    operand: str = "float32x3",
     packed: bool = False,
 ) -> Dict[str, jax.Array]:
     """Grow one tree; returns heap arrays of size 2^(max_depth+1):
@@ -875,7 +882,7 @@ def build_tree(
     use_pallas: bool = False,
     mesh=None,
     forms=None,
-    operand: str = "float32",
+    operand: str = "float32x3",
     packed: bool = False,
 ) -> Dict[str, jax.Array]:
     """Jitted tree growth (see _build_tree_impl). The jitted path NEVER times —
@@ -1002,15 +1009,17 @@ def tree_row_weights(seed: int, n: int, n_trees: int, subsampling_rate: float = 
 
 
 def _operand(unit_stats: bool, w_tree: np.ndarray) -> str:
-    """The grouped kernel's operand type for one tree: where every row statistic
+    """The histogram kernels' operands for one tree: where every row statistic
     is 0 or 1 (`unit_stats`) the weighted statistics are the tree's whole-number
     row weights, exact in int8 up to 127 (the MXU's fastest operands on a v5e)
-    and in bfloat16 up to 256; anything else rides in float32. Sums are float32
-    (int32 under int8) either way."""
+    and in bfloat16 up to 256; anything else (a regressor's real-valued
+    statistics, a weightCol) rides as "float32x3", three exact bfloat16 parts a
+    value (`ops/pallas_histogram.py::split_bf16x3`). Sums are float32 (int32
+    under int8) either way, and every one is the sum of the statistics as given."""
     if not unit_stats:
-        return "float32"
+        return "float32x3"
     top = float(w_tree.max(initial=0.0))
-    return "int8" if top <= 127 else "bfloat16" if top <= 256 else "float32"
+    return "int8" if top <= 127 else "bfloat16" if top <= 256 else "float32x3"
 
 
 def _grow_forest(
@@ -1046,6 +1055,7 @@ def _grow_forest(
     devices = mesh.devices.size if multi else 1
     gate_bins = max_bins if packed else 257  # ids not packed a byte each: no grouped form
     forms = level_forms(max_depth, Xb.shape[0], d, gate_bins, s, use_pallas, devices)
+    kernel = any(form != "xla" for form in forms)
     narrowed = 0  # levels a tree that histogram only the columns their nodes drew
     for t, form in enumerate(forms):
         grouped, reason = hist_gate(2**t, d, gate_bins, s, Xb.shape[0], devices)
@@ -1066,6 +1076,9 @@ def _grow_forest(
             tree_row_weights(seed, n, n_trees, subsampling_rate, bootstrap)
         ):
             w_j = jnp.asarray(w_tree) if shard_fn is None else shard_fn(w_tree)
+            operand = _operand(unit_stats, w_tree)
+            # what the kernels multiply; with no kernel (segment_sum) the float32 as given
+            counter_inc("forest.hist_operand", 1, operand=operand if kernel else "float32")
             trees.append(build_fn(
                 Xb,
                 raw_stats * w_j[:, None],
@@ -1080,7 +1093,7 @@ def _grow_forest(
                 use_pallas=use_pallas,
                 mesh=mesh if multi else None,
                 forms=forms,
-                operand=_operand(unit_stats, w_tree),
+                operand=operand,
                 packed=packed,
             ))
         jax.block_until_ready(trees[-1])

@@ -40,11 +40,13 @@ ROWS, COLS, MAX_K, MAX_ITER = 512, 16, 4, 3
 # `defaults.py::GRAM_TRIANGLE_MIN_COLS` = 576 columns on, which the
 # normal equations of `linreg_l2_d3000` share; a forest narrows a level to the
 # columns its nodes drew only where that matrix pads narrower than the whole
-# (`ops/trees.py::compact_gate`): at 64 columns and 8 a node, widths 1 to 4
+# (`ops/trees.py::compact_gate`): at 64 columns and 8 a node, widths 1 to 4,
+# and a regression forest's third of the columns (21) a node, width 1 alone
 TOY = {"kmeans_wide": {"rows": 256, "cols": 4096, "max_k": 128},
        "pca_k3_d3000": {"rows": 256, "cols": 640},
        "linreg_l2_d3000": {"rows": 256, "cols": 640},
-       "forest": {"num_trees": 2, "max_depth": 4, "cols": 64}}
+       "forest": {"num_trees": 2, "max_depth": 4, "cols": 64},
+       "forest_reg": {"num_trees": 2, "max_depth": 4, "cols": 64}}
 
 # (metric, cell) pairs whose counter reads 0 in that cell BY DESIGN: the
 # program counts the same name under another label there
@@ -143,11 +145,11 @@ def _build(cfg, chips):
     from spark_rapids_ml_tpu.classification import LogisticRegression, RandomForestClassifier
     from spark_rapids_ml_tpu.clustering import KMeans
     from spark_rapids_ml_tpu.feature import PCA
-    from spark_rapids_ml_tpu.regression import LinearRegression
+    from spark_rapids_ml_tpu.regression import LinearRegression, RandomForestRegressor
 
     families = {"kmeans": KMeans, "kmeans_wide": KMeans, "pca": PCA,
                 "logreg": LogisticRegression, "forest": RandomForestClassifier,
-                "linreg": LinearRegression}
+                "linreg": LinearRegression, "forest_reg": RandomForestRegressor}
     if cfg["estimator"] not in families:
         pytest.fail(f"configuration names estimator family {cfg['estimator']!r}: "
                     "tests/test_benchmark_contract.py does not know how to build it")
@@ -280,7 +282,8 @@ def _run_cell(cell_name):
         "programs": _programs_called(counters),
         "table_shape": X.shape, "has_label": "labelCol" in cfg["params"], "waits": list(waits),
         # a forest's row statistics and each tree's row weights go up too
-        "small_puts": 1 + estimator.getNumTrees() if cfg["estimator"] == "forest" else 0,
+        "small_puts": 1 + estimator.getNumTrees() if cfg["estimator"].startswith("forest")
+        else 0,
     }
 
 
@@ -544,3 +547,21 @@ def test_the_forest_narrows_the_levels_its_draws_cannot_fill(runs):
     assert _total(counters, "forest.hist_compact", {"engaged": "false", "reason": "width"}) == (
         n_trees)
     assert _total(counters, "forest.hist_compact", {}) == depth * n_trees
+
+
+def test_the_regression_forest_sums_three_exact_parts_a_statistic(runs):
+    """The toy regressor (64 columns, `auto`: 21 a node) takes the grouped
+    kernel at every level with its [w, w y, w y^2] as "float32x3" lanes, once
+    a tree (`fit_forest_hist_f32x3_per_op` reads numTrees), and narrows the
+    root alone, as the cell narrows its levels of one and two nodes."""
+    from spark_rapids_ml_tpu.ops import trees
+
+    run = runs("rf_reg_depth6_d3000.fit")
+    model, counters = run["model"], run["report_counters"]
+    n_trees, depth, d = model.getNumTrees(), TOY["forest_reg"]["max_depth"], run["table_shape"][1]
+    assert _total(counters, "forest.hist_operand", {}) == n_trees
+    assert _total(counters, "forest.hist_operand", {"operand": "float32x3"}) == n_trees
+    assert _total(counters, "forest.hist_path", {"path": "grouped"}) == depth * n_trees
+    k = trees.resolve_feature_subset("auto", d, False)
+    assert [trees.compact_gate(2**t, d, k)[0] for t in range(depth)] == [True] + [False] * 3
+    assert _total(counters, "forest.hist_compact", {"engaged": "true"}) == n_trees

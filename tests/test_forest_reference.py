@@ -23,15 +23,16 @@ from spark_rapids_ml_tpu.regression import RandomForestRegressor
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _load_ref():
+def _load_ref(name="forest_ref"):
     spec = importlib.util.spec_from_file_location(
-        "forest_ref", os.path.join(ROOT, "cellbench", "forest_ref.py"))
+        name, os.path.join(ROOT, "cellbench", name + ".py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
 
 ref = _load_ref()
+reg_ref = _load_ref("forest_reg_ref")
 
 
 def _table(n, d, seed):
@@ -111,6 +112,46 @@ def test_every_node_of_a_bootstrap_forest_matches_the_reference_routing():
     assert nodes > 600
 
 
+@pytest.mark.parametrize("form", ["xla", "grouped"])
+def test_every_node_of_a_regression_forest_matches_the_float64_variance_reference(
+        form, monkeypatch):
+    """A regressor at 2,048 x 24, three bootstrap trees of depth 4, a third of
+    the features a node, a real-valued label of mean 400 and spread 1,700
+    (`cellbench/forest_reg_ref.py`, float64): every node on every tree has the
+    reference's weight exactly, every split the float64 variance gain of the
+    recounted [w, w y, w y^2] to 1e-5 of the root's variance, and every node's
+    value the recounted mean to 1e-5 of the label's spread: float32 sums.
+    `grouped` runs the grouped kernel, interpreted, on "float32x3" lanes."""
+    if form == "grouped":
+        monkeypatch.setattr(ph, "_on_tpu", lambda: True)
+    rng = np.random.default_rng(48)
+    X = rng.normal(size=(2048, 24)).astype(np.float32)
+    y = 400.0 + 1700.0 * (X[:, 0] * X[:, 1] + 0.5 * X[:, 2] + 0.3 * rng.normal(size=2048))
+    seed = 2**31 + 48
+    est = RandomForestRegressor(numTrees=3, maxDepth=4, maxBins=32, seed=seed)
+    est.num_workers = 1
+    model = est.fit(pd.DataFrame({"features": list(X), "label": y}))
+    counters = model.fit_report_["metrics"]["counters"]
+    operand = "float32x3" if form == "grouped" else "float32"
+    assert counters[f"forest.hist_operand{{operand={operand}}}"] == 3
+    a = model.get_model_attributes()
+    y32 = y.astype(np.float32).astype(np.float64)  # the label as the fit casts it
+    for i, w in enumerate(ref.tree_weights(seed, len(y), 3)):
+        tree = _tree(a, i)
+        sums, reached = reg_ref.node_sums([ref.route(X, tree, t) for t in range(5)],
+                                          reg_ref.row_stats(y32, w), tree)
+        on_tree = np.flatnonzero(reached)
+        np.testing.assert_array_equal(tree["node_weight"][on_tree], sums[on_tree, 0])
+        np.testing.assert_allclose(tree["value"][on_tree, 0],
+                                   sums[on_tree, 1] / sums[on_tree, 0], rtol=0, atol=1e-5 * 1700)
+        inner = on_tree[(tree["feature"][on_tree] >= 0) & ~tree["is_leaf"][on_tree]]
+        root_var = reg_ref.impurity_w(sums[1]) / sums[1, 0]
+        np.testing.assert_allclose(tree["gain"][inner],
+                                   reg_ref.variance_gain(sums[inner], sums[2 * inner]),
+                                   rtol=0, atol=1e-5 * root_var)
+        assert (tree["feature"][2**3:2**4] >= 0).any()  # grown to the last level
+
+
 # ------------------------------------------------------ (c) grouped histogram
 
 
@@ -147,6 +188,53 @@ def test_grouped_histogram_equals_a_plain_histogram(width, n, d, nbins, s, opera
         np.add.at(want, (node, j, Xb[:, j]), vals)
     # the kernel's bins are running sums: entry b counts the ids <= b
     np.testing.assert_array_equal(got[:, np.argsort(feat)][:, :d], np.cumsum(want, axis=2))
+
+
+def test_the_classifiers_operands_are_the_parents_and_anything_else_is_exact():
+    """`_operand`: a classifier's whole-number statistics keep the parent's
+    int8 (weights up to 127) and bfloat16 (up to 256); real-valued ones, and
+    weights past 256, ride as three bfloat16 parts where the parent had a
+    float32 operand."""
+    w = np.array([0.0, 3.0, 127.0], np.float32)
+    assert trees._operand(True, w) == "int8"
+    assert trees._operand(True, np.append(w, 128.0)) == "bfloat16"
+    assert trees._operand(True, np.append(w, 256.0)) == "bfloat16"
+    assert trees._operand(True, np.append(w, 257.0)) == "float32x3"
+    assert trees._operand(True, np.zeros(0, np.float32)) == "int8"
+    assert trees._operand(False, w) == "float32x3"
+
+
+def _kernel_dots(operand, s):
+    """The dot_generals of the grouped kernel's body."""
+    n, width, nbins, d = 1024, 64, 32, 96
+    d_pad, _ = trees.feature_plan(d)
+    words = jnp.zeros((d_pad // 4, n), jnp.int32)
+    vals = jnp.ones((n, s), jnp.float32)
+    node = jnp.zeros((n,), jnp.int32)
+
+    def run(words, node, vals):
+        grp = ph.group_rows(node, vals, width, operand)
+        return ph.grouped_histogram_tile(words, grp, jnp.int32(0), ph.WORDS_PER_STEP, width,
+                                         nbins, ph.lane_stats(operand, s), interpret=True)
+
+    (call,) = [e for e in jax.make_jaxpr(run)(words, node, vals).jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    return [e for e in _eqns(call.params["jaxpr"]) if e.primitive.name == "dot_general"]
+
+
+@pytest.mark.parametrize("operand,s,lhs", [
+    ("int8", 2, jnp.int8), ("bfloat16", 2, jnp.bfloat16), ("float32x3", 3, jnp.bfloat16),
+])
+def test_the_grouped_kernel_takes_one_dot_a_feature(operand, s, lhs):
+    """Four features a packed word, `WORDS_PER_STEP` words a step: one
+    (bins, rows) x (rows, lanes) dot each, the classifier's int8 and bfloat16
+    lanes as before and the regressor's three parts a statistic in the same
+    one dot (they take lanes, not passes)."""
+    dots = _kernel_dots(operand, s)
+    assert len(dots) == 4 * ph.WORDS_PER_STEP
+    assert {e.invars[1].aval.dtype for e in dots} == {jnp.dtype(lhs)}
+    assert {e.params["preferred_element_type"] for e in dots} == {
+        jnp.dtype(jnp.int32 if operand == "int8" else jnp.float32)}
 
 
 def test_a_whole_tree_is_the_same_in_every_histogram_form():
@@ -592,4 +680,31 @@ def test_level_step_compiles_for_a_v5e_at_the_published_lanes(form, t, one_chip,
         S((d, nbins - 1), jnp.float32), t=t, nbins=nbins, impurity="gini", k_features=54,
         min_instances=1, min_info_gain=0.0, mesh=None, form=form, operand="bfloat16",
         packed=True).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("form,t", [("grouped", 5), ("direct", 4), ("direct", 10)])
+def test_a_regressors_level_compiles_for_a_v5e_on_three_part_lanes(form, t, one_chip,
+                                                                    monkeypatch):
+    """The regressor's [w, w y, w y^2] as "float32x3" (nine bfloat16 lanes a
+    node), 128 bins, 3000 columns, 1000 drawn a node: Mosaic takes both
+    kernels' blocks, the one-hot kernel's past 256 nodes too, where its node
+    tile narrows so that nine lanes a bin fit its output block."""
+    monkeypatch.setattr(ph, "_interpret", lambda: False)
+    n, d, nbins, s = 16384, 3000, 128, 3
+    d_pad, _ = trees.feature_plan(d)
+    slots = 2**11
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = (S((slots,), jnp.int32), S((slots,), jnp.float32), S((slots,), jnp.bool_),
+             S((slots, 1), jnp.float32), S((slots,), jnp.float32), S((slots,), jnp.float32),
+             S((n,), jnp.int32), S((2**t, s), jnp.float32), S((2,), jnp.uint32),
+             S((), jnp.int32))
+    compiled = trees._level_step_jit.lower(
+        state, S((n, d_pad // 4), jnp.int32), S((n, s), jnp.float32),
+        S((d, nbins - 1), jnp.float32), t=t, nbins=nbins, impurity="variance",
+        k_features=1000, min_instances=1, min_info_gain=0.0, mesh=None, form=form,
+        operand="float32x3", packed=True).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -167,3 +167,122 @@ def test_build_tree_pallas_sharded(n_devices):
     np.testing.assert_allclose(
         np.asarray(t_ref["value"]), np.asarray(t_pallas["value"]), rtol=1e-4, atol=1e-5
     )
+
+
+# ---------------------------------------------- real-valued statistics, exact
+
+
+def test_three_bfloat16_parts_are_the_float32_to_the_bit():
+    """`split_bf16x3`: every part is a bfloat16 as it stands, all share the
+    value's sign, and (hi + mid) + lo is the float32 bit for bit: random,
+    tiny (down to 2^-103), huge and negative values, and whole numbers (a zero
+    of either sign comes back as +0)."""
+    from spark_rapids_ml_tpu.ops.pallas_histogram import split_bf16x3
+
+    rng = np.random.default_rng(48)
+    v = np.concatenate([
+        rng.normal(size=4000) * 1e3,
+        rng.choice([-1.0, 1.0], size=500) * 2.0**-103 * rng.uniform(1, 1e4, size=500),
+        rng.normal(size=500) * 1e37,
+        -np.abs(rng.normal(size=500)) * 3e5,
+        rng.integers(0, 1 << 24, size=500),
+        [0.0, 1.0, 2.0**-103, -(2.0**127), np.float32(3.4e38)],
+    ]).astype(np.float32)
+    parts = split_bf16x3(jnp.asarray(v)[:, None])
+    assert parts.dtype == jnp.bfloat16 and parts.shape == (v.size, 3)
+    p = np.asarray(parts.astype(jnp.float32))
+    total = (p[:, 0] + p[:, 1]) + p[:, 2]
+    np.testing.assert_array_equal(total.view(np.uint32), v.view(np.uint32))
+    assert np.all((p == 0) | (np.sign(p) == np.sign(v)[:, None]))
+
+
+def _level_hist(vals, node, Xb, width, nbins, operand):
+    """The grouped kernel's level histogram (interpreted), folded to the
+    statistics: (width, d, nbins, s) running sums over bins."""
+    from spark_rapids_ml_tpu.ops import pallas_histogram as ph
+    from spark_rapids_ml_tpu.ops import trees
+
+    n, d = Xb.shape
+    s = vals.shape[1]
+    d_pad, _ = trees.feature_plan(d)
+
+    @jax.jit
+    def run(Xb, node, vals):
+        words = trees._pack_words(jnp.pad(Xb, ((0, 0), (0, d_pad - d))))
+        grp = ph.group_rows(node, vals, width, operand)
+        if grp["order"] is not None:
+            words = jnp.take(words, grp["order"], axis=0)
+        parts = ph.grouped_histogram_tile(words.T, grp, jnp.int32(0), d_pad // 4, width,
+                                          nbins, ph.lane_stats(operand, s), interpret=True)
+        return ph.fold_parts(parts, s)
+
+    h = np.stack([np.asarray(p) for p in run(Xb, node, vals)])  # (s, F, A, nbins, C)
+    feat = np.asarray(trees.tile_features(True, 0, d_pad, d_pad))
+    got = h.transpose(2, 4, 1, 3, 0).reshape(-1, d_pad, nbins, s)[:width]
+    return got[:, np.argsort(feat)][:, :d]
+
+
+@pytest.mark.parametrize("width", [1, 16, 64])
+def test_a_float32x3_histogram_is_the_float32_sum_of_real_statistics(width):
+    """A regressor's [w, w y, w y^2] with a label of mean 400 and spread 1700:
+    every bin of the "float32x3" histogram is the float64 sum to float32
+    accumulation (2^-21 of the bin's sum of |value|), where statistics rounded
+    to bfloat16 (the "bfloat16" lanes) miss by about 2^-9."""
+    rng = np.random.default_rng(width)
+    n, d, nbins = 3001, 12, 32
+    Xb = rng.integers(0, nbins, size=(n, d)).astype(np.uint8)
+    node = rng.integers(0, width, size=n).astype(np.int32)
+    y = 400.0 + 1700.0 * rng.normal(size=n)
+    w = rng.poisson(1.0, n)
+    vals = np.stack([w, w * y, w * y * y], axis=1).astype(np.float32)
+    exact = np.zeros((width, d, nbins, 3))
+    size = np.zeros((width, d, nbins, 3))
+    for j in range(d):
+        np.add.at(exact, (node, j, Xb[:, j]), vals.astype(np.float64))
+        np.add.at(size, (node, j, Xb[:, j]), np.abs(vals.astype(np.float64)))
+    exact, size = np.cumsum(exact, axis=2), np.cumsum(size, axis=2)
+    err = {op: (np.abs(_level_hist(vals, node, Xb, width, nbins, op) - exact)
+                / np.maximum(size, 1e-30)).max()
+           for op in ("float32x3", "bfloat16")}
+    assert err["float32x3"] <= 2.0**-21, err
+    assert err["bfloat16"] >= 2.0**-12, err
+
+
+def _best_variance_split(Xb, stats, nbins):
+    """(feature, bin) of the float64 best variance split of rows with these
+    [w, w y, w y^2] statistics, the first among equals."""
+    def impurity_w(t):
+        return t[..., 2] - t[..., 1] ** 2 / np.maximum(t[..., 0], 1e-300)
+
+    hist = np.stack([np.stack([np.bincount(Xb[:, j], weights=stats[:, k], minlength=nbins)
+                               for k in range(3)], -1) for j in range(Xb.shape[1])])
+    left = np.cumsum(hist, axis=1)[:, :-1]
+    total = hist[0].sum(axis=0)
+    gain = (impurity_w(total) - impurity_w(left) - impurity_w(total - left)) / total[0]
+    return np.unravel_index(int(np.argmax(gain)), gain.shape), float(gain.max())
+
+
+def test_where_bfloat16_statistics_pick_another_split_the_kernel_picks_the_exact_one():
+    """A centred label of spread 100: the float64 best split of these rows is
+    (feature 0, bin 14), 0.23 % ahead of the next; from each row's statistics
+    rounded to bfloat16 it is (4, 7). A depth-1 tree through the grouped
+    kernel (interpreted) with "float32x3" lanes takes the exact one, with its
+    gain to float32 (of the node's variance: the gain is 0.3 % of it)."""
+    from spark_rapids_ml_tpu.ops import trees
+
+    rng = np.random.default_rng(703)
+    n, d, nbins = 1024, 8, 16
+    Xb = rng.integers(0, nbins, size=(n, d)).astype(np.uint8)
+    y = rng.normal(size=n) * 100 + 0.05 * (Xb[:, 0] > 7)
+    vals = np.stack([np.ones(n), y, y * y], axis=1).astype(np.float32)
+    (exact, gain) = _best_variance_split(Xb, vals.astype(np.float64), nbins)
+    rounded = np.asarray(jnp.asarray(vals).astype(jnp.bfloat16).astype(jnp.float32))
+    assert exact == (0, 14) and _best_variance_split(Xb, rounded.astype(np.float64),
+                                                     nbins)[0] == (4, 7)
+    edges = jnp.asarray(np.tile(np.arange(nbins - 1, dtype=np.float32), (d, 1)))
+    tree = trees.build_tree(jnp.asarray(Xb), jnp.asarray(vals), edges, jax.random.PRNGKey(0),
+                            max_depth=1, nbins=nbins, impurity="variance", k_features=d,
+                            min_instances=1, min_info_gain=0.0, forms=("grouped",),
+                            operand="float32x3")
+    assert (int(tree["feature"][1]), int(tree["threshold"][1])) == exact
+    assert float(tree["gain"][1]) == pytest.approx(gain, abs=1e-6 * np.var(y))

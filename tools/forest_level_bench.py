@@ -1,10 +1,11 @@
 """Microbenchmark, outside any cell of BENCHMARK.json: what one level of the
 forest builder costs on the chip by width and by histogram form
 (`ops/trees.py::_level_step`: "direct", the one-hot kernel over every node of
-the level, against "grouped", rows sorted by node, with float32, bfloat16 and
-int8 operands), what the device binning costs, and what a whole tree costs and
-holds. What `ops/pallas_histogram.py::hist_gate` and `ops/trees.py::_operand`
-rest on. One JSON line a reading; refuses a CPU backend.
+the level, against "grouped", rows sorted by node, with "float32x3" (three
+bfloat16 parts a statistic), bfloat16 and int8 operands), what the device
+binning costs, and what a whole tree costs and holds. What
+`ops/pallas_histogram.py::hist_gate` and `ops/trees.py::_operand` rest on.
+One JSON line a reading; refuses a CPU backend.
 
     chiprun -- python -m tools.forest_level_bench [rows cols [part ...]]
 
@@ -37,7 +38,7 @@ from spark_rapids_ml_tpu.ops import trees
 
 NBINS, CLASSES, DEPTH = 128, 2, 13
 # (form, operand) -> the levels it is timed at
-LEVELS = {("direct", "float32"): (0, 4), ("grouped", "float32"): (4,),
+LEVELS = {("direct", "float32x3"): (0, 4), ("grouped", "float32x3"): (4,),
           ("grouped", "bfloat16"): (0, 4, 9, 12), ("grouped", "int8"): (0, 4, 6, 9, 11, 12)}
 
 
